@@ -3,8 +3,8 @@
 TPU numbers come from the roofline analysis) + allclose sanity.
 
 Each row's ``derived`` records the effective Pallas interpret flag the
-parity check ran under (``REPRO_PALLAS_INTERPRET``), so a trajectory
-point says whether the kernel side was the interpreter or Mosaic.
+parity check ran under (the interpreter on a CPU backend, Mosaic on a
+TPU — ``kernels/env.py``), so a trajectory point says which it was.
 """
 from __future__ import annotations
 

@@ -24,7 +24,7 @@ import os
 import subprocess
 import sys
 
-from benchmarks.common import row
+from benchmarks.common import refuse_on_tpu, row
 
 _CHILD = r"""
 import json, os, sys
@@ -122,6 +122,7 @@ def _child(nd: int, nm: int, out_d: int, in_d: int, N: int, tau: int,
 
 
 def run(quick: bool = False):
+    refuse_on_tpu("sharded2d_agg")
     # interpret-mode sizes: the dense-P contraction is O(out·in²) per
     # client per pass on a single socket, so stay at smoke scale — the
     # trajectory gates dispatch regressions, not TPU throughput

@@ -21,7 +21,7 @@ import os
 import subprocess
 import sys
 
-from benchmarks.common import row
+from benchmarks.common import refuse_on_tpu, row
 
 _CHILD = r"""
 import json, os, sys
@@ -92,6 +92,7 @@ def _child(n: int, out_d: int, in_d: int, N: int, tau: int) -> dict:
 
 
 def run(quick: bool = False):
+    refuse_on_tpu("sharded_agg")
     out_d, in_d, N, tau = ((1024, 256, 3, 2) if quick
                            else (4096, 256, 4, 2))
     devices = [1, 2] if quick else [1, 2, 4, 8]

@@ -41,6 +41,21 @@ def timed(fn, *args, **kw):
     return out, (time.time() - t0) * 1e6
 
 
+def refuse_on_tpu(suite: str) -> None:
+    """Raise where the default backend is a TPU.
+
+    For suites that spawn one child per forced host-device count: this
+    process already holds the chip, so a child that needs it fails or
+    hangs, and forced host devices time the CPU anyway.  The chip's
+    multi-device path runs in one process (``chip_smoke.py --chips 4``).
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{suite} forces host devices in child processes and cannot "
+            f"run beside a TPU; run it with JAX_PLATFORMS=cpu, or run "
+            f"`python chip_smoke.py --chips 4` for the chip's mesh path")
+
+
 def train_locals(spec, data, n_clients, beta, *, epochs=6,
                  same_init=False, seed=0, max_steps=0, proj_alpha=1.0,
                  max_samples=1536):

@@ -93,8 +93,8 @@ matches aggregating the participating subset alone (same init point).
 
 The QP and the padding logic (``repro.kernels.ops._pad_to``, zero
 padding is exact for all three passes) are shared between backends;
-``REPRO_PALLAS_INTERPRET`` selects interpret-mode kernel execution
-(this container) vs real TPU lowering.
+the kernels run in the Pallas interpreter on a CPU backend and lower
+to Mosaic on a TPU (``kernels.env``).
 
 Batched QP (``MAEchoConfig.qp_batched``, default on): each outer
 iteration runs in three phases — every leaf (and every scanned layer
@@ -603,13 +603,17 @@ def init_global(client_weights: list[Pytree], how: str,
 
 
 @partial(jax.jit, static_argnames=("cfg", "convention", "plan",
-                                   "mesh"))
+                                   "mesh"), donate_argnames=("V0",))
 def _maecho_jit(W0, V0, P, cfg: MAEchoConfig, convention: str,
                 plan: AggPlan, mesh=None, masks=None):
     """The pure executor: runs the τ-loop over the COMPILED plan —
     every per-leaf decision was already frozen into ``plan.leaves``
     (one :class:`LeafPlan` per flattened leaf, same order), so the
-    loop body below contains zero routing logic."""
+    loop body below contains zero routing logic.
+
+    ``V0`` is donated: the anchors are the largest state (N × model
+    fp32), and the V' output reuses their buffers.  Callers pass a
+    stack they own (``maecho_aggregate`` stacks it fresh)."""
     def outer(_, state):
         W, V = state
         flatW, treedef = jax.tree_util.tree_flatten(W)
@@ -709,6 +713,41 @@ def dispatch_summary(W0: Pytree, P: Pytree, levels_tree: Pytree,
     if chunked:
         counts["chunked"] = chunked
     return plan.per_leaf(), counts
+
+
+def coverage_report(W0, Pp, levels_tree, macfg, backend: str,
+                    mesh=None, convention: str = "io") -> dict:
+    """Print the per-backend leaf-coverage summary: the compiled
+    ``AggPlan``'s per-leaf routes (:func:`dispatch_summary` is a view
+    over the same plan the executor dispatches on), so a leaf
+    silently degraded to the oracle is visible instead of buried in a
+    trace-time warning.
+
+    Beyond route counts, every leaf gets a detail line with the
+    ``LeafPlan`` knobs that decide its memory/collective shape: the
+    mesh axes its Gram psums over, the effective sharding tile edge,
+    and the client-chunk size (``-`` where the knob is off), visible
+    before a long production compile (``launch.dryrun_agg``,
+    ``chip_smoke.py``)."""
+    per_leaf, counts = dispatch_summary(W0, Pp, levels_tree, macfg,
+                                        convention, backend, mesh)
+    # same memoized plan the executor dispatches on — per-leaf knobs
+    plan = compile_plan(W0, Pp, levels_tree, macfg, convention,
+                        backend, mesh)
+    total = len(per_leaf)
+    summary = ", ".join(f"{k}={v}" for k, v in sorted(counts.items()))
+    print(f"[coverage] backend={backend}: {total} leaves ({summary})")
+    for lp in plan.leaves:
+        axes = ",".join(lp.psum_axes) if lp.psum_axes else "-"
+        print(f"[coverage]   {lp.path}: route={lp.route} "
+              f"psum_axes={axes} tile={lp.block or '-'} "
+              f"chunk={lp.client_chunk or '-'}")
+    if backend != "oracle":
+        for path, lv, route in per_leaf:
+            if route == "oracle":
+                print(f"[coverage]   oracle fallback: {path}"
+                      f" (stack_levels={lv})")
+    return counts
 
 
 def _default_mesh(axis_name: str, in_axis_name: Optional[str] = None):

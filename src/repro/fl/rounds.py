@@ -56,8 +56,8 @@ class MultiRoundConfig:
     # "sharded" additionally splits eligible leaves' out-rows over the
     # mesh and "sharded2d" the residual 2-D (out × in) over both mesh
     # axis groups (run_multi_round's ``mesh`` argument).  The default
-    # stays "oracle" because interpret-mode kernel execution (this
-    # container) is simulation, not a speedup.
+    # stays "oracle": on a CPU backend the kernels run in the Pallas
+    # interpreter, which is simulation, not a speedup.
     maecho_backend: str = "oracle"  # oracle|kernel|auto|sharded|sharded2d
     proj_alpha: float = 1.0
     seed: int = 0

@@ -14,9 +14,10 @@ h = hkv * group + g matches the oracle's grouped reshape and the
 
 Two grid layouts share the math:
 
-* ``fold_batch=False`` — grid (B, Hkv, n_w), blocks (group, D) /
-  (bw, D).  The TPU shape: VMEM-sized blocks, 2-D MXU dots, one cache
-  pass per KV head regardless of the q:kv ratio.
+* ``fold_batch=False`` — grid (B, n_w), blocks (Hkv, group, D) /
+  (bw, Hkv, D) with a static loop over KV heads in the body.  The TPU
+  shape: VMEM-sized blocks, 2-D MXU dots per head, one pass over each
+  cache block regardless of the q:kv ratio.
 * ``fold_batch=True`` — grid (n_w,), whole-batch blocks with batched
   einsums in the body.  The interpreter shape: interpret mode lowers
   the grid to a ``lax.while_loop`` whose carry holds the *full* input
@@ -36,14 +37,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.env import interpret_default
+from repro.kernels.env import resolve
 
 NEG_INF = -1e30
 
 
 def _kernel_fine(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
-                 l_ref, *, scale: float, n_w: int):
-    j = pl.program_id(2)
+                 l_ref, *, scale: float, n_w: int, n_kv: int):
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -51,29 +52,32 @@ def _kernel_fine(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    valid = mask_ref[...] > 0                        # (bw,)
+    valid = mask_ref[...] > 0                        # (1, bw)
 
     # skip window blocks with no valid slot — a ring buffer filled to
     # S of W slots only pays ceil(S / bw) blocks
     @pl.when(jnp.any(valid))
     def _compute():
-        q = q_ref[...].astype(jnp.float32)           # (group, D)
-        k = k_ref[...].astype(jnp.float32)           # (bw, D)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot(q, k.T,
-                        preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        # explicit zero (not just exp(NEG_INF - m)): with m == NEG_INF
-        # (row empty so far) exp(s - m) would be exp(0) = 1 per slot
-        p = jnp.exp(s - m_new[:, None]) * valid[None, :]
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot(p, v,
-                                      preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+        k_all = k_ref[...].astype(jnp.float32)       # (bw, Hkv, D)
+        v_all = v_ref[...].astype(jnp.float32)
+        for h in range(n_kv):                        # one cache pass
+            q = q_ref[h].astype(jnp.float32)         # (group, D)
+            k = k_all[:, h, :]                       # (bw, D)
+            v = v_all[:, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)         # (group, bw)
+            m_prev = m_ref[h]                        # (group, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # explicit zero (not just exp(NEG_INF - m)): with m == NEG_INF
+            # (row empty so far) exp(s - m) would be exp(0) = 1 per slot
+            p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == n_w - 1)
     def _finalize():
@@ -81,8 +85,7 @@ def _kernel_fine(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
         # (finite), where the oracle's softmax-over-NEG_INF degrades to
         # mean(v) — callers never read attention at position < 0, so
         # only the no-NaN contract matters (pinned in tests)
-        o_ref[...] = (acc_ref[...] /
-                      jnp.maximum(l_ref[...][:, None], 1e-30)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
@@ -135,8 +138,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *, bw: int = 512,
     to the oracle).  The caches are consumed in their native serving
     layout — no transpose materialisation on the decode hot path.
     """
-    if interpret is None:
-        interpret = interpret_default()
+    interpret = resolve(interpret)
     if fold_batch is None:
         fold_batch = interpret
     B, one, Hq, D = q.shape
@@ -167,23 +169,25 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *, bw: int = 512,
             pltpu.VMEM((B, Hkv, group), jnp.float32),
         ]
     else:
-        kernel = functools.partial(_kernel_fine, scale=scale, n_w=n_w)
-        grid = (B, Hkv, n_w)
+        kernel = functools.partial(_kernel_fine, scale=scale, n_w=n_w,
+                                   n_kv=Hkv)
+        grid = (B, n_w)
+        # all KV heads per block: the trailing (Hkv, D) / (group, D)
+        # block dims are then whole array dims, which Mosaic accepts
+        # for any head count and head size
         in_specs = [
-            pl.BlockSpec((None, None, group, D),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, bw, None, D),
-                         lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((None, bw, None, D),
-                         lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((None, bw), lambda b, h, j: (b, j)),
+            pl.BlockSpec((None, Hkv, group, D), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((None, bw, Hkv, D), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((None, bw, Hkv, D), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((None, 1, bw), lambda b, j: (b, 0, j)),
         ]
-        out_spec = pl.BlockSpec((None, None, group, D),
-                                lambda b, h, j: (b, h, 0, 0))
+        mask = mask.reshape(B, 1, W)
+        out_spec = pl.BlockSpec((None, Hkv, group, D),
+                                lambda b, j: (b, 0, 0, 0))
         scratch = [
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
+            pltpu.VMEM((Hkv, group, D), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
         ]
 
     out = pl.pallas_call(

@@ -1,20 +1,23 @@
-"""Process-wide Pallas interpret-mode switch.
+"""Where Pallas kernels run: the one interpret-mode decision.
 
-``REPRO_PALLAS_INTERPRET`` (default "1": kernel bodies execute on CPU —
-this container has no TPU) is read at trace time; a TPU launch flips the
-one env var instead of editing call sites.  This lives in its own tiny
-module so the raw kernel modules (``flash_attention``,
-``decode_attention``) can resolve their ``interpret=None`` defaults
-without importing ``ops`` — which imports them.
+Kernel bodies run in the Pallas interpreter exactly when JAX's default
+backend is the CPU (which has no Mosaic lowering); on a TPU they lower
+to Mosaic.  Every ``interpret=None`` default in the kernel modules and
+in ``ops`` resolves here, so no switch can leave a chip run silently in
+the interpreter.  This lives in its own tiny module so the raw kernel
+modules can resolve their defaults without importing ``ops`` — which
+imports them.
 """
 from __future__ import annotations
 
-import os
-
-INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
+import jax
 
 
 def interpret_default() -> bool:
-    """True unless REPRO_PALLAS_INTERPRET is 0/false/no/off."""
-    val = os.environ.get(INTERPRET_ENV, "1").strip().lower()
-    return val not in ("0", "false", "no", "off")
+    """True exactly when the default backend is the CPU."""
+    return jax.default_backend() == "cpu"
+
+
+def resolve(interpret) -> bool:
+    """An explicit ``interpret`` flag, or the backend's default."""
+    return interpret_default() if interpret is None else bool(interpret)

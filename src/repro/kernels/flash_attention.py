@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.env import interpret_default
+from repro.kernels.env import resolve
 
 NEG_INF = -1e30
 
@@ -79,12 +79,35 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
 
     GQA is handled by an index_map trick: kv head = q head // group.
     Sequences must be multiples of the block sizes (caller pads).
-    ``interpret=None`` resolves through ``REPRO_PALLAS_INTERPRET`` like
-    every other kernel (a bare default of True would silently pin the
-    raw entry point to the interpreter even on a TPU launch).
+    ``interpret=None`` runs the Pallas interpreter on a CPU backend and
+    Mosaic on a TPU (``env.resolve``).  Differentiable: the backward
+    pass is the VJP of the jnp reference (``ref.flash_attention_ref``),
+    so a train step keeps the kernel forward.
     """
-    if interpret is None:
-        interpret = interpret_default()
+    return _flash(q, k, v, causal, bq, bk, resolve(interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, bq, bk, interpret):
+    return _flash_forward(q, k, v, causal, bq, bk, interpret)
+
+
+def _flash_fwd(q, k, v, causal, bq, bk, interpret):
+    return _flash_forward(q, k, v, causal, bq, bk, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, bq, bk, interpret, res, g):
+    from repro.kernels.ref import flash_attention_ref
+
+    _, vjp = jax.vjp(
+        lambda q, k, v: flash_attention_ref(q, k, v, causal=causal), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_forward(q, k, v, causal, bq, bk, interpret):
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     group = Hq // Hkv

@@ -12,8 +12,8 @@ accumulator.  Nothing of size out×in is ever written to HBM.
 Grid: (n_out, n_in, N, n_k).  The two inner axes build one client's
 residual tile (k is the GEMM reduction over the projector's rows); the
 finished tile is parked in the (N, bo, bi) ``rstore`` scratch, and once
-all clients' tiles for this (o, j) position exist, one batched
-double-contraction adds their pairwise products to the Gram
+all clients' tiles for this (o, j) position exist, one 2-D dot over
+the flattened tiles adds their pairwise products to the Gram
 accumulator.  Scratch persists across the whole grid; the Gram table
 is written exactly once, at the final step.
 
@@ -49,6 +49,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.env import resolve
+
+
+def _pair_gram(r):
+    """(N, N) pairwise inner products of the N (bo, bi) tiles in ``r``.
+
+    Flattened to (N, bo·bi) and contracted as ONE 2-D MXU dot: Mosaic
+    refuses a dot_general whose lhs contracts two axes at once."""
+    n = r.shape[0]
+    rf = r.reshape(n, -1)
+    return jax.lax.dot_general(rf, rf, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
 
 def _gram_tail(resid, out_ref, racc_ref, rstore_ref, gacc_ref,
                n_clients: int, n_k: int, off: int = 0):
@@ -81,10 +94,7 @@ def _gram_tail(resid, out_ref, racc_ref, rstore_ref, gacc_ref,
 
     @pl.when((i == n_clients - 1) & (k == n_k - 1))
     def _contract_pairs():
-        r = rstore_ref[...]                       # (N, bo, bi)
-        gacc_ref[...] += jax.lax.dot_general(
-            r, r, (((1, 2), (1, 2)), ((), ())),
-            preferred_element_type=jnp.float32)
+        gacc_ref[...] += _pair_gram(rstore_ref[...])
 
     @pl.when((o == n_out - 1) & (j == n_in - 1) &
              (i == n_clients - 1) & (k == n_k - 1))
@@ -116,7 +126,7 @@ def _gram_kernel_left(a_ref, ut_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "bk",
                                              "interpret"))
 def maecho_gram(W, V, P, *, bo: int = 128, bi: int = 128, bk: int = 128,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """W: (out, in); V: (N, out, in); P: (N, in, in) dense.
 
     Returns the fp32 (N, N) Gram matrix of projected residuals.
@@ -141,7 +151,7 @@ def maecho_gram(W, V, P, *, bo: int = 128, bi: int = 128, bk: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, P)
 
 
@@ -163,7 +173,7 @@ def compressed_residual(W, V, U, s):
 
 
 def maecho_gram_factored(W, V, U, s, *, bo: int = 128, bi: int = 128,
-                         bk: int = 128, interpret: bool = True):
+                         bk: int = 128, interpret: bool | None = None):
     """Factored projectors Pᵢ = Uᵢ·diag(sᵢ)·Uᵢᵀ.
 
     W: (out, in); V: (N, out, in); U: (N, in, k); s: (N, k).
@@ -178,7 +188,7 @@ def maecho_gram_factored(W, V, U, s, *, bo: int = 128, bi: int = 128,
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "bk",
                                              "interpret"))
 def maecho_gram_left(A, UT, *, bo: int = 128, bi: int = 128,
-                     bk: int = 128, interpret: bool = True):
+                     bk: int = 128, interpret: bool | None = None):
     """Gram from pre-factored residuals Rᵢ = Aᵢ @ UTᵢ.
 
     A: (N, out, k) compressed residual; UT: (N, k, in).  Callers that
@@ -203,7 +213,7 @@ def maecho_gram_left(A, UT, *, bo: int = 128, bi: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(A, UT)
 
 
@@ -224,7 +234,7 @@ def _gram_cross_kernel(a_ref, b_ref, out_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
-def maecho_gram_cross(Ra, Rb, *, bd: int = 512, interpret: bool = True):
+def maecho_gram_cross(Ra, Rb, *, bd: int = 512, interpret: bool | None = None):
     """Cross-Gram block between two client chunks' flat residuals.
 
     Ra: (ca, D); Rb: (cb, D) — flattened residual rows for chunks a and
@@ -247,7 +257,7 @@ def maecho_gram_cross(Ra, Rb, *, bd: int = 512, interpret: bool = True):
         out_specs=pl.BlockSpec((ca, cb), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((ca, cb), jnp.float32),
         scratch_shapes=[pltpu.VMEM((ca, cb), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(Ra, Rb)
 
 
@@ -263,10 +273,7 @@ def _gram_diag_kernel(w_ref, v_ref, p_ref, out_ref, gacc_ref,
     w = w_ref[...].astype(jnp.float32)                   # (bo, bi)
     v = v_ref[...].astype(jnp.float32)                   # (N, bo, bi)
     p = p_ref[...].astype(jnp.float32)                   # (N, 1, bi)
-    r = (w[None] - v) * p
-    gacc_ref[...] += jax.lax.dot_general(
-        r, r, (((1, 2), (1, 2)), ((), ())),
-        preferred_element_type=jnp.float32)
+    gacc_ref[...] += _pair_gram((w[None] - v) * p)
 
     @pl.when((o == n_out - 1) & (j == n_in - 1))
     def _finalize():
@@ -281,7 +288,7 @@ def _gram_diag_kernel(w_ref, v_ref, p_ref, out_ref, gacc_ref,
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "bk",
                                              "interpret"))
 def maecho_gram_stacked(W, V, P, *, bo: int = 128, bi: int = 128,
-                        bk: int = 128, interpret: bool = True):
+                        bk: int = 128, interpret: bool | None = None):
     """W: (L, out, in); V: (N, L, out, in); P: (N, L, in, in) dense.
 
     Returns the fp32 (L, N, N) per-layer Gram stack from ONE launch:
@@ -313,14 +320,14 @@ def maecho_gram_stacked(W, V, P, *, bo: int = 128, bi: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, P)
 
 
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "bk",
                                              "interpret"))
 def maecho_gram_left_stacked(A, UT, *, bo: int = 128, bi: int = 128,
-                             bk: int = 128, interpret: bool = True):
+                             bk: int = 128, interpret: bool | None = None):
     """Stacked Gram from pre-factored residuals Rₗᵢ = Aₗᵢ @ UTₗᵢ.
 
     A: (N, L, out, k) compressed residual; UT: (N, L, k, in).
@@ -349,13 +356,13 @@ def maecho_gram_left_stacked(A, UT, *, bo: int = 128, bi: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(A, UT)
 
 
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "interpret"))
 def maecho_gram_diag_stacked(W, V, p, *, bo: int = 128, bi: int = 128,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Stacked diagonal projectors.  W: (L, out, in);
     V: (N, L, out, in); p: (N, L, in).  Returns (L, N, N)."""
     L, out_d, in_d = W.shape
@@ -378,13 +385,13 @@ def maecho_gram_diag_stacked(W, V, p, *, bo: int = 128, bi: int = 128,
         out_specs=pl.BlockSpec((None, N, N), lambda l, o, j: (l, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((L, N, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p4)
 
 
 @functools.partial(jax.jit, static_argnames=("bo", "bi", "interpret"))
 def maecho_gram_diag(W, V, p, *, bo: int = 128, bi: int = 128,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Diagonal projectors.  W: (out, in); V: (N, out, in); p: (N, in)."""
     out_d, in_d = W.shape
     N = V.shape[0]
@@ -404,5 +411,5 @@ def maecho_gram_diag(W, V, p, *, bo: int = 128, bi: int = 128,
         out_specs=pl.BlockSpec((N, N), lambda o, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p3)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.env import resolve
+
 
 def _kernel(alpha_ref, w_ref, v_ref, p_ref, wout_ref, out_ref, acc_ref,
             *, eta: float, n_clients: int, n_k: int, off: int = 0):
@@ -56,11 +58,11 @@ def _kernel(alpha_ref, w_ref, v_ref, p_ref, wout_ref, out_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("eta", "bo", "bi", "bk",
                                              "interpret"))
 def maecho_update(W, V, P, alpha, *, eta: float = 1.0, bo: int = 128,
-                  bi: int = 128, bk: int = 128, interpret: bool = True):
+                  bi: int = 128, bk: int = 128, interpret: bool | None = None):
     """W: (out, in); V: (N, out, in); P: (N, in, in); alpha: (N,).
 
-    Returns W' = W + η·D with D from Eq. 7.  ``interpret=True`` runs the
-    kernel body on CPU (this container); on TPU pass ``False``.
+    Returns W' = W + η·D with D from Eq. 7.  ``interpret=None`` runs
+    the Pallas interpreter on a CPU backend and Mosaic on a TPU.
     """
     out_d, in_d = W.shape
     N = V.shape[0]
@@ -86,7 +88,7 @@ def maecho_update(W, V, P, alpha, *, eta: float = 1.0, bo: int = 128,
         out_specs=pl.BlockSpec((bo, bi), lambda o, j, i, k: (o, j)),
         out_shape=jax.ShapeDtypeStruct((out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(alpha, W, V, P, W)
 
 
@@ -113,7 +115,7 @@ def _left_kernel(alpha_ref, a_ref, ut_ref, wout_ref, out_ref, acc_ref,
 
 def maecho_update_factored(W, V, U, s, alpha, *, eta: float = 1.0,
                            bo: int = 128, bi: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Factored Pᵢ = Uᵢ·diag(sᵢ)·Uᵢᵀ.  U: (N, in, k); s: (N, k)."""
     from repro.kernels.maecho_gram import compressed_residual
 
@@ -127,7 +129,7 @@ def maecho_update_factored(W, V, U, s, alpha, *, eta: float = 1.0,
                                              "interpret"))
 def maecho_update_left(W, A, UT, alpha, *, eta: float = 1.0,
                        bo: int = 128, bi: int = 128, bk: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Eq. 7 from pre-factored residuals Rᵢ = Aᵢ @ UTᵢ (shareable with
     ``maecho_gram_left`` — one ``compressed_residual`` per iteration)."""
     out_d, in_d = W.shape
@@ -150,7 +152,7 @@ def maecho_update_left(W, A, UT, alpha, *, eta: float = 1.0,
         out_specs=pl.BlockSpec((bo, bi), lambda o, j, i, k: (o, j)),
         out_shape=jax.ShapeDtypeStruct((out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(alpha, A, UT, W)
 
 
@@ -162,7 +164,7 @@ def maecho_update_left(W, A, UT, alpha, *, eta: float = 1.0,
                                              "interpret"))
 def maecho_update_stacked(W, V, P, alpha, *, eta: float = 1.0,
                           bo: int = 128, bi: int = 128, bk: int = 128,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """W: (L, out, in); V: (N, L, out, in); P: (N, L, in, in);
     alpha: (L, N).  Returns the (L, out, in) Eq. 7 update from one
     launch — grid (L, n_out, n_in, N, n_k), layer axis outermost."""
@@ -192,7 +194,7 @@ def maecho_update_stacked(W, V, P, alpha, *, eta: float = 1.0,
                                lambda l, o, j, i, k: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(alpha, W, V, P, W)
 
 
@@ -200,7 +202,7 @@ def maecho_update_stacked(W, V, P, alpha, *, eta: float = 1.0,
                                              "interpret"))
 def maecho_update_left_stacked(W, A, UT, alpha, *, eta: float = 1.0,
                                bo: int = 128, bi: int = 128,
-                               bk: int = 128, interpret: bool = True):
+                               bk: int = 128, interpret: bool | None = None):
     """Stacked Eq. 7 from pre-factored residuals Rₗᵢ = Aₗᵢ @ UTₗᵢ
     (A shared with ``maecho_gram_left_stacked`` — one
     ``compressed_residual`` per leaf per iteration).
@@ -230,7 +232,7 @@ def maecho_update_left_stacked(W, A, UT, alpha, *, eta: float = 1.0,
                                lambda l, o, j, i, k: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(alpha, A, UT, W)
 
 
@@ -238,7 +240,7 @@ def maecho_update_left_stacked(W, A, UT, alpha, *, eta: float = 1.0,
                                              "interpret"))
 def maecho_update_diag_stacked(W, V, p, alpha, *, eta: float = 1.0,
                                bo: int = 128, bi: int = 128,
-                               interpret: bool = True):
+                               interpret: bool | None = None):
     """Stacked diagonal projectors.  W: (L, out, in);
     V: (N, L, out, in); p: (N, L, in); alpha: (L, N)."""
     L, out_d, in_d = W.shape
@@ -263,7 +265,7 @@ def maecho_update_diag_stacked(W, V, p, alpha, *, eta: float = 1.0,
         ],
         out_specs=pl.BlockSpec((None, bo, bi), lambda l, o, j: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p4, a4)
 
 
@@ -280,7 +282,7 @@ def _diag_kernel(w_ref, v_ref, p_ref, alpha_ref, out_ref, *, eta: float):
                                              "interpret"))
 def maecho_update_diag(W, V, p, alpha, *, eta: float = 1.0,
                        bo: int = 128, bi: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Diagonal projectors.  p: (N, in); alpha: (N,)."""
     out_d, in_d = W.shape
     N = V.shape[0]
@@ -301,5 +303,5 @@ def maecho_update_diag(W, V, p, alpha, *, eta: float = 1.0,
         ],
         out_specs=pl.BlockSpec((bo, bi), lambda o, j: (o, j)),
         out_shape=jax.ShapeDtypeStruct((out_d, in_d), W.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p3, a3)

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.env import resolve
+
 
 def _apply_norm(u, eps: float):
     """Row-normalise u (bo, full-row) exactly like the jnp oracle."""
@@ -88,7 +90,7 @@ def _v_kernel_left(b_ref, ut_ref, wj_ref, vj_ref, out_ref,
                                              "interpret"))
 def maecho_v_update(W, V, P, *, frac: float, norm: bool = False,
                     eps: float = 1e-12, bo: int = 128, bi: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool | None = None):
     """W: (out, in) updated global; V: (N, out, in); P: (N, in, in).
 
     Returns V' per Eq. 11.  ``frac`` is μ/(1+μ).  With ``norm=True``
@@ -117,7 +119,7 @@ def maecho_v_update(W, V, P, *, frac: float, norm: bool = False,
         out_specs=pl.BlockSpec((None, bo, bi), lambda i, o, j, k: (i, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, P, W, V)
 
 
@@ -127,7 +129,7 @@ def maecho_v_update(W, V, P, *, frac: float, norm: bool = False,
 def maecho_v_update_factored(W, V, U, s, *, frac: float,
                              norm: bool = False, eps: float = 1e-12,
                              bo: int = 128, bi: int = 128, bk: int = 128,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Factored Pᵢ = Uᵢ·diag(sᵢ)·Uᵢᵀ.  U: (N, in, k); s: (N, k)."""
     from repro.kernels.maecho_gram import compressed_residual
 
@@ -155,7 +157,7 @@ def maecho_v_update_factored(W, V, U, s, *, frac: float,
         out_specs=pl.BlockSpec((None, bo, bi), lambda i, o, j, k: (i, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(B, UT, W, V)
 
 
@@ -169,7 +171,7 @@ def maecho_v_update_factored(W, V, U, s, *, frac: float,
 def maecho_v_update_stacked(W, V, P, *, frac: float, norm: bool = False,
                             eps: float = 1e-12, bo: int = 128,
                             bi: int = 128, bk: int = 128,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """W: (L, out, in) updated global; V: (N, L, out, in);
     P: (N, L, in, in).  Returns the (N, L, out, in) Eq. 11 anchors
     from one launch.  ``norm=True`` needs bi = in_d, as per-layer."""
@@ -202,7 +204,7 @@ def maecho_v_update_stacked(W, V, P, *, frac: float, norm: bool = False,
                                lambda l, i, o, j, k: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, P, W, V)
 
 
@@ -213,7 +215,7 @@ def maecho_v_update_factored_stacked(W, V, U, s, *, frac: float,
                                      norm: bool = False,
                                      eps: float = 1e-12, bo: int = 128,
                                      bi: int = 128, bk: int = 128,
-                                     interpret: bool = True):
+                                     interpret: bool | None = None):
     """Stacked factored Pₗᵢ = Uₗᵢ·diag(sₗᵢ)·Uₗᵢᵀ.
     U: (N, L, in, k); s: (N, L, k)."""
     from repro.kernels.maecho_gram import compressed_residual
@@ -247,7 +249,7 @@ def maecho_v_update_factored_stacked(W, V, U, s, *, frac: float,
                                lambda l, i, o, j, k: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(B, UT, W, V)
 
 
@@ -256,7 +258,7 @@ def maecho_v_update_factored_stacked(W, V, U, s, *, frac: float,
 def maecho_v_update_diag_stacked(W, V, p, *, frac: float,
                                  norm: bool = False, eps: float = 1e-12,
                                  bo: int = 128, bi: int = 128,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Stacked diagonal projectors.  p: (N, L, in)."""
     L, out_d, in_d = W.shape
     N = V.shape[0]
@@ -282,7 +284,7 @@ def maecho_v_update_diag_stacked(W, V, p, *, frac: float,
         out_specs=pl.BlockSpec((None, None, bo, bi),
                                lambda l, i, o, j: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p4)
 
 
@@ -300,7 +302,7 @@ def _v_diag_kernel(w_ref, v_ref, p_ref, out_ref, *, frac, norm, eps):
                                              "bo", "bi", "interpret"))
 def maecho_v_update_diag(W, V, p, *, frac: float, norm: bool = False,
                          eps: float = 1e-12, bo: int = 128,
-                         bi: int = 128, interpret: bool = True):
+                         bi: int = 128, interpret: bool | None = None):
     """Diagonal projectors.  p: (N, in)."""
     out_d, in_d = W.shape
     N = V.shape[0]
@@ -322,5 +324,5 @@ def maecho_v_update_diag(W, V, p, *, frac: float, norm: bool = False,
         ],
         out_specs=pl.BlockSpec((None, bo, bi), lambda i, o, j: (i, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(W, V, p3)

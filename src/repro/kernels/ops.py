@@ -1,11 +1,9 @@
 """Jit'd public wrappers + dispatch layer for the Pallas kernels.
 
-Interpret mode: every wrapper takes ``interpret=None`` which resolves
-through the ``REPRO_PALLAS_INTERPRET`` env var (default "1": kernel
-bodies execute on CPU — this container has no TPU).  A TPU launch
-flips the one switch (``REPRO_PALLAS_INTERPRET=0``) instead of editing
-call sites.  The value is read at trace time, so set it before the
-first jitted call of the process.
+Interpret mode: every kernel takes ``interpret=None``, which resolves
+through ``env.resolve`` at trace time — the Pallas interpreter when
+JAX's default backend is the CPU, Mosaic on a TPU.  There is no switch
+to set: a chip run always lowers the real kernels.
 
 The ``maecho_*_auto`` wrappers are the backend used by
 ``core.maecho``'s fused streaming pipeline: they normalise the
@@ -22,7 +20,6 @@ import warnings
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.kernels import env as _env
@@ -80,10 +77,6 @@ def fallback_warn(msg: str) -> None:
     if msg not in _warned_fallbacks:
         _warned_fallbacks.add(msg)
         warnings.warn(msg, RuntimeWarning, stacklevel=3)
-
-
-def _resolve(interpret):
-    return interpret_default() if interpret is None else bool(interpret)
 
 
 def _pad_to(x, mult, axis):
@@ -151,99 +144,23 @@ def _pad_factored_stacked(U, s, block):
 
 
 # --------------------------------------------------------------------------
-# thin kernel wrappers (env-var interpret resolution)
+# the raw kernels under their public names (each resolves interpret=None
+# through env.resolve itself)
 # --------------------------------------------------------------------------
-def maecho_update(W, V, P, alpha, *, eta: float = 1.0, bo: int = 128,
-                  bi: int = 128, bk: int = 128, interpret=None):
-    return _mu.maecho_update(W, V, P, alpha, eta=eta, bo=bo, bi=bi,
-                             bk=bk, interpret=_resolve(interpret))
-
-
-def maecho_update_factored(W, V, U, s, alpha, *, eta: float = 1.0,
-                           bo: int = 128, bi: int = 128, bk: int = 128,
-                           interpret=None):
-    return _mu.maecho_update_factored(W, V, U, s, alpha, eta=eta, bo=bo,
-                                      bi=bi, bk=bk,
-                                      interpret=_resolve(interpret))
-
-
-def maecho_update_diag(W, V, p, alpha, *, eta: float = 1.0,
-                       bo: int = 128, bi: int = 128, interpret=None):
-    return _mu.maecho_update_diag(W, V, p, alpha, eta=eta, bo=bo, bi=bi,
-                                  interpret=_resolve(interpret))
-
-
-def maecho_gram(W, V, P, *, bo: int = 128, bi: int = 128, bk: int = 128,
-                interpret=None):
-    return _mg.maecho_gram(W, V, P, bo=bo, bi=bi, bk=bk,
-                           interpret=_resolve(interpret))
-
-
-def maecho_gram_factored(W, V, U, s, *, bo: int = 128, bi: int = 128,
-                         bk: int = 128, interpret=None):
-    return _mg.maecho_gram_factored(W, V, U, s, bo=bo, bi=bi, bk=bk,
-                                    interpret=_resolve(interpret))
-
-
-def maecho_gram_diag(W, V, p, *, bo: int = 128, bi: int = 128,
-                     interpret=None):
-    return _mg.maecho_gram_diag(W, V, p, bo=bo, bi=bi,
-                                interpret=_resolve(interpret))
-
-
-def maecho_v_update(W, V, P, *, frac: float, norm: bool = False,
-                    eps: float = 1e-12, bo: int = 128, bi: int = 128,
-                    bk: int = 128, interpret=None):
-    return _mv.maecho_v_update(W, V, P, frac=frac, norm=norm, eps=eps,
-                               bo=bo, bi=bi, bk=bk,
-                               interpret=_resolve(interpret))
-
-
-def maecho_v_update_factored(W, V, U, s, *, frac: float,
-                             norm: bool = False, eps: float = 1e-12,
-                             bo: int = 128, bi: int = 128, bk: int = 128,
-                             interpret=None):
-    return _mv.maecho_v_update_factored(W, V, U, s, frac=frac, norm=norm,
-                                        eps=eps, bo=bo, bi=bi, bk=bk,
-                                        interpret=_resolve(interpret))
-
-
-def maecho_v_update_diag(W, V, p, *, frac: float, norm: bool = False,
-                         eps: float = 1e-12, bo: int = 128,
-                         bi: int = 128, interpret=None):
-    return _mv.maecho_v_update_diag(W, V, p, frac=frac, norm=norm,
-                                    eps=eps, bo=bo, bi=bi,
-                                    interpret=_resolve(interpret))
-
-
-def maecho_gram_cross(Ra, Rb, *, bd: int = 512, interpret=None):
-    return _mg.maecho_gram_cross(Ra, Rb, bd=bd,
-                                 interpret=_resolve(interpret))
-
-
-def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
-                    bk: int = 256, interpret=None):
-    return _fa.flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
-                               interpret=_resolve(interpret))
-
-
-def decode_attention(q, k_cache, v_cache, valid_mask, *, bw: int = 512,
-                     interpret=None, fold_batch=None):
-    return _da.decode_attention(q, k_cache, v_cache, valid_mask, bw=bw,
-                                interpret=_resolve(interpret),
-                                fold_batch=fold_batch)
-
-
-def rank_downdate(Q, U, A, *, bo: int = 256, bj: int = 256,
-                  interpret=None):
-    return _ru.rank_downdate(Q, U, A, bo=bo, bj=bj,
-                             interpret=_resolve(interpret))
-
-
-def block_rls_update(Q, Xb, alpha: float = 1.0, *, bo: int = 256,
-                     interpret=None):
-    return _ru.block_rls_update(Q, Xb, alpha, bo=bo,
-                                interpret=_resolve(interpret))
+maecho_update = _mu.maecho_update
+maecho_update_factored = _mu.maecho_update_factored
+maecho_update_diag = _mu.maecho_update_diag
+maecho_gram = _mg.maecho_gram
+maecho_gram_factored = _mg.maecho_gram_factored
+maecho_gram_diag = _mg.maecho_gram_diag
+maecho_gram_cross = _mg.maecho_gram_cross
+maecho_v_update = _mv.maecho_v_update
+maecho_v_update_factored = _mv.maecho_v_update_factored
+maecho_v_update_diag = _mv.maecho_v_update_diag
+flash_attention = _fa.flash_attention
+decode_attention = _da.decode_attention
+rank_downdate = _ru.rank_downdate
+block_rls_update = _ru.block_rls_update
 
 
 # --------------------------------------------------------------------------
@@ -375,7 +292,7 @@ def maecho_streaming_gram(W, V, P, *, block: int = DEFAULT_BLOCK,
         A = compressed_residual(Wp, Vp, Up, sp)
         UT = jnp.swapaxes(Up, 1, 2).astype(jnp.float32)
         G = _mg.maecho_gram_left(A, UT, bo=block, bi=block, bk=block,
-                                 interpret=_resolve(interpret))
+                                 interpret=interpret)
         return G, (kind, Wp, Vp, (Up, sp, A, UT), out_d, in_d)
     if kind == "full":
         G = maecho_gram(Wp, Vp, Pk, bo=block, bi=block, bk=block,
@@ -408,7 +325,7 @@ def maecho_streaming_apply(alpha, ctx, *, eta: float = 1.0,
         Up, sp, A, UT = Pk
         Wn = _mu.maecho_update_left(Wp, A, UT, alpha, eta=eta,
                                     bo=block, bi=block, bk=block,
-                                    interpret=_resolve(interpret))
+                                    interpret=interpret)
         Vn = maecho_v_update_factored(Wn, Vp, Up, sp, frac=frac,
                                       norm=norm, eps=eps, bo=block,
                                       bi=bi, bk=block,
@@ -517,16 +434,16 @@ def maecho_streaming_gram_stacked(W, V, P, *, block: int = DEFAULT_BLOCK,
         UT = jnp.swapaxes(Up, 2, 3).astype(jnp.float32)
         G = _mg.maecho_gram_left_stacked(A, UT, bo=block, bi=block,
                                          bk=block,
-                                         interpret=_resolve(interpret))
+                                         interpret=interpret)
         return G, (kind, Wp, Vp, (Up, sp, A, UT), out_d, in_d)
     if kind == "full":
         G = _mg.maecho_gram_stacked(Wp, Vp, Pk, bo=block, bi=block,
                                     bk=block,
-                                    interpret=_resolve(interpret))
+                                    interpret=interpret)
     else:
         G = _mg.maecho_gram_diag_stacked(Wp, Vp, Pk, bo=block,
                                          bi=block,
-                                         interpret=_resolve(interpret))
+                                         interpret=interpret)
     return G, (kind, Wp, Vp, Pk, out_d, in_d)
 
 
@@ -540,7 +457,6 @@ def maecho_streaming_apply_stacked(alpha, ctx, *, eta: float = 1.0,
     ``ctx`` comes from :func:`maecho_streaming_gram_stacked` for the
     same leaf.  Returns ``(W', V')`` cropped to the original shape."""
     kind, Wp, Vp, Pk, out_d, in_d = ctx
-    itp = _resolve(interpret)
     if kind == "ref":
         W_new = jax.vmap(
             lambda w, v, p, a: ref.maecho_update_ref_any(w, v, p, a,
@@ -557,26 +473,26 @@ def maecho_streaming_apply_stacked(alpha, ctx, *, eta: float = 1.0,
         Up, sp, A, UT = Pk
         Wn = _mu.maecho_update_left_stacked(Wp, A, UT, alpha, eta=eta,
                                             bo=block, bi=block,
-                                            bk=block, interpret=itp)
+                                            bk=block, interpret=interpret)
         Vn = _mv.maecho_v_update_factored_stacked(
             Wn, Vp, Up, sp, frac=frac, norm=norm, eps=eps, bo=block,
-            bi=bi, bk=block, interpret=itp)
+            bi=bi, bk=block, interpret=interpret)
     elif kind == "full":
         Wn = _mu.maecho_update_stacked(Wp, Vp, Pk, alpha, eta=eta,
                                        bo=block, bi=block, bk=block,
-                                       interpret=itp)
+                                       interpret=interpret)
         Vn = _mv.maecho_v_update_stacked(Wn, Vp, Pk, frac=frac,
                                          norm=norm, eps=eps, bo=block,
                                          bi=bi, bk=block,
-                                         interpret=itp)
+                                         interpret=interpret)
     else:
         Wn = _mu.maecho_update_diag_stacked(Wp, Vp, Pk, alpha, eta=eta,
                                             bo=block, bi=block,
-                                            interpret=itp)
+                                            interpret=interpret)
         Vn = _mv.maecho_v_update_diag_stacked(Wn, Vp, Pk, frac=frac,
                                               norm=norm, eps=eps,
                                               bo=block, bi=bi,
-                                              interpret=itp)
+                                              interpret=interpret)
     return Wn[:, :out_d, :in_d], Vn[:, :, :out_d, :in_d]
 
 
@@ -649,7 +565,6 @@ def maecho_sharded_gram(W, V, P, *, mesh, axis="data",
     asz = axis_size_of(mesh, axis)
     out_d, in_d = W.shape
     kind = _proj_kind(P)
-    itp = _resolve(interpret)
     Wp, _ = _pad_to(_pad_to(W, block * asz, 0)[0], block, 1)
     Vp, _ = _pad_to(_pad_to(V, block * asz, 1)[0], block, 2)
     row = PartitionSpec(names, None)           # W rows
@@ -662,33 +577,33 @@ def maecho_sharded_gram(W, V, P, *, mesh, axis="data",
         def body_f(Wl, Vl, U, s):
             A = _mg.compressed_residual(Wl, Vl, U, s)
             UT = jnp.swapaxes(U, 1, 2).astype(jnp.float32)
-            Gl = _mg.maecho_gram_left(A, UT, interpret=itp)
+            Gl = _mg.maecho_gram_left(A, UT, interpret=interpret)
             return jax.lax.psum(Gl, names), A
 
-        G, A = shard_map(body_f, mesh=mesh,
+        G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep3, rep2),
                          out_specs=(rep2, crow),
-                         check_rep=False)(Wp, Vp, Up, sp)
+                         check_vma=False)(Wp, Vp, Up, sp)
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         Pk = _pad_to(_pad_to(P, block, 1)[0], block, 2)[0]
 
         def body_d(Wl, Vl, Pl):
             return jax.lax.psum(
-                _mg.maecho_gram(Wl, Vl, Pl, interpret=itp), names)
+                _mg.maecho_gram(Wl, Vl, Pl, interpret=interpret), names)
 
-        G = shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep3),
-                      out_specs=rep2, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep3),
+                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
     else:                                   # scalar / diag
         p = _as_diag(P, in_d) if kind == "scalar" else P
         Pk = _pad_to(p, block, 1)[0]
 
         def body_g(Wl, Vl, pl):
             return jax.lax.psum(
-                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=itp), names)
+                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=interpret), names)
 
-        G = shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep2),
-                      out_specs=rep2, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep2),
+                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
     return G, (kind, Wp, Vp, Pk, out_d, in_d)
 
 
@@ -708,7 +623,6 @@ def maecho_sharded_apply(alpha, ctx, *, mesh, axis="data",
     """
     kind, Wp, Vp, Pk, out_d, in_d = ctx
     names = _axis_names(axis)
-    itp = _resolve(interpret)
     bi = Wp.shape[1] if norm else block
     row = PartitionSpec(names, None)
     crow = PartitionSpec(None, names, None)
@@ -721,40 +635,40 @@ def maecho_sharded_apply(alpha, ctx, *, mesh, axis="data",
         def body_f(a, Wl, Vl, U, s, Al):
             UT = jnp.swapaxes(U, 1, 2).astype(jnp.float32)
             Wn = _mu.maecho_update_left(Wl, Al, UT, a, eta=eta,
-                                        interpret=itp)
+                                        interpret=interpret)
             Vn = _mv.maecho_v_update_factored(
                 Wn, Vl, U, s, frac=frac, norm=norm, eps=eps, bi=bi,
-                interpret=itp)
+                interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_f, mesh=mesh,
             in_specs=(rep1, row, crow, rep3, rep2, crow),
-            out_specs=(row, crow), check_rep=False)(
+            out_specs=(row, crow), check_vma=False)(
             alpha, Wp, Vp, Up, sp, A)
     elif kind == "full":
         def body_d(a, Wl, Vl, Pl):
             Wn = _mu.maecho_update(Wl, Vl, Pl, a, eta=eta,
-                                   interpret=itp)
+                                   interpret=interpret)
             Vn = _mv.maecho_v_update(Wn, Vl, Pl, frac=frac, norm=norm,
-                                     eps=eps, bi=bi, interpret=itp)
+                                     eps=eps, bi=bi, interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_d, mesh=mesh, in_specs=(rep1, row, crow, rep3),
-            out_specs=(row, crow), check_rep=False)(alpha, Wp, Vp, Pk)
+            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
     else:                                   # scalar / diag
         def body_g(a, Wl, Vl, pl):
             Wn = _mu.maecho_update_diag(Wl, Vl, pl, a, eta=eta,
-                                        interpret=itp)
+                                        interpret=interpret)
             Vn = _mv.maecho_v_update_diag(Wn, Vl, pl, frac=frac,
                                           norm=norm, eps=eps, bi=bi,
-                                          interpret=itp)
+                                          interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_g, mesh=mesh, in_specs=(rep1, row, crow, rep2),
-            out_specs=(row, crow), check_rep=False)(alpha, Wp, Vp, Pk)
+            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
     return Wn[:out_d, :in_d], Vn[:, :out_d, :in_d]
 
 
@@ -776,7 +690,6 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
     asz = axis_size_of(mesh, axis)
     L, out_d, in_d = W.shape
     kind = _proj_kind_stacked(P)
-    itp = _resolve(interpret)
     Wp = _pad_to(_pad_to(W, block * asz, 1)[0], block, 2)[0]
     Vp = _pad_to(_pad_to(V, block * asz, 2)[0], block, 3)[0]
     row = PartitionSpec(None, names, None)          # W rows (axis 1)
@@ -789,24 +702,24 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
         def body_f(Wl, Vl, U, s):
             A = _mg.compressed_residual(Wl, Vl, U, s)
             UT = jnp.swapaxes(U, 2, 3).astype(jnp.float32)
-            Gl = _mg.maecho_gram_left_stacked(A, UT, interpret=itp)
+            Gl = _mg.maecho_gram_left_stacked(A, UT, interpret=interpret)
             return jax.lax.psum(Gl, names), A
 
-        G, A = shard_map(body_f, mesh=mesh,
+        G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep4, rep3),
                          out_specs=(rep3, crow),
-                         check_rep=False)(Wp, Vp, Up, sp)
+                         check_vma=False)(Wp, Vp, Up, sp)
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         Pk = _pad_to(_pad_to(P, block, 2)[0], block, 3)[0]
 
         def body_d(Wl, Vl, Pl):
             return jax.lax.psum(
-                _mg.maecho_gram_stacked(Wl, Vl, Pl, interpret=itp),
+                _mg.maecho_gram_stacked(Wl, Vl, Pl, interpret=interpret),
                 names)
 
-        G = shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep4),
-                      out_specs=rep3, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep4),
+                      out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
     else:                                   # scalar / diag
         p = (jnp.broadcast_to(P[:, :, None], P.shape + (in_d,))
              if kind == "scalar" else P)
@@ -815,10 +728,10 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
         def body_g(Wl, Vl, pl_):
             return jax.lax.psum(
                 _mg.maecho_gram_diag_stacked(Wl, Vl, pl_,
-                                             interpret=itp), names)
+                                             interpret=interpret), names)
 
-        G = shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep3),
-                      out_specs=rep3, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep3),
+                      out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
     return G, (kind, Wp, Vp, Pk, out_d, in_d)
 
 
@@ -835,7 +748,6 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
     Returns ``(W', V')`` cropped to the original shape."""
     kind, Wp, Vp, Pk, out_d, in_d = ctx
     names = _axis_names(axis)
-    itp = _resolve(interpret)
     bi = Wp.shape[2] if norm else block
     row = PartitionSpec(None, names, None)
     crow = PartitionSpec(None, None, names, None)
@@ -848,41 +760,41 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
         def body_f(a, Wl, Vl, U, s, Al):
             UT = jnp.swapaxes(U, 2, 3).astype(jnp.float32)
             Wn = _mu.maecho_update_left_stacked(Wl, Al, UT, a, eta=eta,
-                                                interpret=itp)
+                                                interpret=interpret)
             Vn = _mv.maecho_v_update_factored_stacked(
                 Wn, Vl, U, s, frac=frac, norm=norm, eps=eps, bi=bi,
-                interpret=itp)
+                interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_f, mesh=mesh,
             in_specs=(rep2, row, crow, rep4, rep3, crow),
-            out_specs=(row, crow), check_rep=False)(
+            out_specs=(row, crow), check_vma=False)(
             alpha, Wp, Vp, Up, sp, A)
     elif kind == "full":
         def body_d(a, Wl, Vl, Pl):
             Wn = _mu.maecho_update_stacked(Wl, Vl, Pl, a, eta=eta,
-                                           interpret=itp)
+                                           interpret=interpret)
             Vn = _mv.maecho_v_update_stacked(Wn, Vl, Pl, frac=frac,
                                              norm=norm, eps=eps, bi=bi,
-                                             interpret=itp)
+                                             interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_d, mesh=mesh, in_specs=(rep2, row, crow, rep4),
-            out_specs=(row, crow), check_rep=False)(alpha, Wp, Vp, Pk)
+            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
     else:                                   # scalar / diag
         def body_g(a, Wl, Vl, pl_):
             Wn = _mu.maecho_update_diag_stacked(Wl, Vl, pl_, a, eta=eta,
-                                                interpret=itp)
+                                                interpret=interpret)
             Vn = _mv.maecho_v_update_diag_stacked(
                 Wn, Vl, pl_, frac=frac, norm=norm, eps=eps, bi=bi,
-                interpret=itp)
+                interpret=interpret)
             return Wn, Vn
 
-        Wn, Vn = shard_map(
+        Wn, Vn = jax.shard_map(
             body_g, mesh=mesh, in_specs=(rep2, row, crow, rep3),
-            out_specs=(row, crow), check_rep=False)(alpha, Wp, Vp, Pk)
+            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
     return Wn[:, :out_d, :in_d], Vn[:, :, :out_d, :in_d]
 
 
@@ -922,7 +834,6 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
     isz = axis_size_of(mesh, axis_in)
     out_d, in_d = W.shape
     kind = _proj_kind(P)
-    itp = _resolve(interpret)
     Wp = _pad_to(_pad_to(W, block * osz, 0)[0], block * isz, 1)[0]
     Vp = _pad_to(_pad_to(V, block * osz, 1)[0], block * isz, 2)[0]
     row = PartitionSpec(no, None)
@@ -938,13 +849,13 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
             # A (full in-contraction, replicated over axis_in);
             # the gram contracts A against only the owned UT columns
             A = _mg.compressed_residual(Wl, Vl, U, s)
-            Gl = _mg.maecho_gram_left(A, UTl, interpret=itp)
+            Gl = _mg.maecho_gram_left(A, UTl, interpret=interpret)
             return jax.lax.psum(Gl, allnames), A
 
-        G, A = shard_map(body_f, mesh=mesh,
+        G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep3, rep2, col3),
                          out_specs=(rep2, crow),
-                         check_rep=False)(Wp, Vp, Up, sp, UTs)
+                         check_vma=False)(Wp, Vp, Up, sp, UTs)
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         in_p = Wp.shape[1]
@@ -956,11 +867,11 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
             # columns the right — maecho_gram_left streams the tiles
             A = (Wl[None] - Vl).astype(jnp.float32)
             Gl = _mg.maecho_gram_left(A, Pl.astype(jnp.float32),
-                                      interpret=itp)
+                                      interpret=interpret)
             return jax.lax.psum(Gl, allnames)
 
-        G = shard_map(body_d, mesh=mesh, in_specs=(row, crow, col3),
-                      out_specs=rep2, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, col3),
+                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
     else:                                   # scalar / diag
         p = _as_diag(P, in_d) if kind == "scalar" else P
         Pk = _pad_to(p, block * isz, 1)[0]
@@ -968,14 +879,14 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
         def body_g(Wl, Vl, pl):
             # elementwise kind: 2-D-slicing the operands is exact
             return jax.lax.psum(
-                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=itp),
+                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=interpret),
                 allnames)
 
-        G = shard_map(body_g, mesh=mesh,
+        G = jax.shard_map(body_g, mesh=mesh,
                       in_specs=(PartitionSpec(no, ni),
                                 PartitionSpec(None, no, ni),
                                 PartitionSpec(None, ni)),
-                      out_specs=rep2, check_rep=False)(Wp, Vp, Pk)
+                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
     return G, (kind, Wp, Vp, Pk, out_d, in_d)
 
 
@@ -1017,7 +928,6 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
     isz = axis_size_of(mesh, axis_in)
     L, out_d, in_d = W.shape
     kind = _proj_kind_stacked(P)
-    itp = _resolve(interpret)
     Wp = _pad_to(_pad_to(W, block * osz, 1)[0], block * isz, 2)[0]
     Vp = _pad_to(_pad_to(V, block * osz, 2)[0], block * isz, 3)[0]
     row = PartitionSpec(None, no, None)
@@ -1031,13 +941,13 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
 
         def body_f(Wl, Vl, U, s, UTl):
             A = _mg.compressed_residual(Wl, Vl, U, s)
-            Gl = _mg.maecho_gram_left_stacked(A, UTl, interpret=itp)
+            Gl = _mg.maecho_gram_left_stacked(A, UTl, interpret=interpret)
             return jax.lax.psum(Gl, allnames), A
 
-        G, A = shard_map(body_f, mesh=mesh,
+        G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep4, rep3, col4),
                          out_specs=(rep3, crow),
-                         check_rep=False)(Wp, Vp, Up, sp, UTs)
+                         check_vma=False)(Wp, Vp, Up, sp, UTs)
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         in_p = Wp.shape[2]
@@ -1048,11 +958,11 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
             # Pl (N, L, in_p, in_sh) carries the owned output columns
             A = (Wl[None] - Vl).astype(jnp.float32)
             Gl = _mg.maecho_gram_left_stacked(
-                A, Pl.astype(jnp.float32), interpret=itp)
+                A, Pl.astype(jnp.float32), interpret=interpret)
             return jax.lax.psum(Gl, allnames)
 
-        G = shard_map(body_d, mesh=mesh, in_specs=(row, crow, col4),
-                      out_specs=rep3, check_rep=False)(Wp, Vp, Pk)
+        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, col4),
+                      out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
     else:                                   # scalar / diag
         p = (jnp.broadcast_to(P[:, :, None], P.shape + (in_d,))
              if kind == "scalar" else P)
@@ -1061,13 +971,13 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
         def body_g(Wl, Vl, pl_):
             return jax.lax.psum(
                 _mg.maecho_gram_diag_stacked(Wl, Vl, pl_,
-                                             interpret=itp), allnames)
+                                             interpret=interpret), allnames)
 
-        G = shard_map(body_g, mesh=mesh,
+        G = jax.shard_map(body_g, mesh=mesh,
                       in_specs=(PartitionSpec(None, no, ni),
                                 PartitionSpec(None, None, no, ni),
                                 PartitionSpec(None, None, ni)),
-                      out_specs=rep3, check_rep=False)(Wp, Vp, Pk)
+                      out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
     return G, (kind, Wp, Vp, Pk, out_d, in_d)
 
 
@@ -1275,14 +1185,14 @@ def _chunked_apply_core(alpha, W, Vp, Pp, kind: str, chunk: int, N: int,
     return W_new, Vout[:N]
 
 
-def _cross_pair(bd: int, itp: bool):
+def _cross_pair(bd: int, interpret):
     """Pair contraction through the Pallas ``maecho_gram_cross``
     streamer (kernel-route leaves): flat rows are zero-padded to a
     ``bd`` multiple — zero feature columns add zero to every dot."""
     def pair(Ra, Rb):
         return _mg.maecho_gram_cross(_pad_to(Ra, bd, 1)[0],
                                      _pad_to(Rb, bd, 1)[0],
-                                     bd=bd, interpret=itp)
+                                     bd=bd, interpret=interpret)
     return pair
 
 
@@ -1302,7 +1212,7 @@ def maecho_streaming_gram_chunked(W, V, P, *, chunk: int,
     N = V.shape[0]
     kind = _proj_kind(P)
     Vp, Pp = _pad_clients(W, V, P, chunk, kind)
-    pair = (_cross_pair(bd, _resolve(interpret)) if use_kernel
+    pair = (_cross_pair(bd, interpret) if use_kernel
             else _pair_jnp(False))
     G = _chunked_gram_core(W, Vp, Pp, kind, chunk, False, pair)
     return G[:N, :N], ("chunk", kind, W, Vp, Pp, N, chunk)
@@ -1401,8 +1311,8 @@ def maecho_sharded_gram_chunked(W, V, P, *, mesh, axis="data",
                                 stacked, pair)
         return jax.lax.psum(Gl, names)
 
-    G = shard_map(body, mesh=mesh, in_specs=(wspec, vspec) + pspecs,
-                  out_specs=gspec, check_rep=False)(Wp, Vp, *pargs)
+    G = jax.shard_map(body, mesh=mesh, in_specs=(wspec, vspec) + pspecs,
+                  out_specs=gspec, check_vma=False)(Wp, Vp, *pargs)
     return (G[..., :N, :N],
             ("shc", kind, Wp, Vp, Pp, N, chunk, out_d, in_d))
 
@@ -1445,10 +1355,10 @@ def maecho_sharded_apply_chunked(alpha, ctx, *, mesh, axis="data",
                                    N, stacked, eta=eta, frac=frac,
                                    norm=norm, eps=eps)
 
-    Wn, Vn = shard_map(body, mesh=mesh,
+    Wn, Vn = jax.shard_map(body, mesh=mesh,
                        in_specs=(rep(alpha), wspec, vspec) + pspecs,
                        out_specs=(wspec, vspec),
-                       check_rep=False)(alpha, Wp, Vp, *pargs)
+                       check_vma=False)(alpha, Wp, Vp, *pargs)
     if stacked:
         return Wn[:, :out_d, :in_d], Vn[:, :, :out_d, :in_d]
     return Wn[:out_d, :in_d], Vn[:, :out_d, :in_d]

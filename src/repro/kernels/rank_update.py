@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.env import resolve
+
 
 def _kernel(q_ref, u_ref, a_ref, uj_ref, out_ref):
     u_i = u_ref[...].astype(jnp.float32)          # (bo, b)
@@ -38,7 +40,7 @@ def _kernel(q_ref, u_ref, a_ref, uj_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bo", "bj", "interpret"))
 def rank_downdate(Q, U, A, *, bo: int = 256, bj: int = 256,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Q − U A Uᵀ.  Q: (d, d); U: (d, b); A: (b, b) small."""
     d, b = U.shape
     bo = min(bo, d)
@@ -56,12 +58,12 @@ def rank_downdate(Q, U, A, *, bo: int = 256, bj: int = 256,
         ],
         out_specs=pl.BlockSpec((bo, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d, d), Q.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(Q, U, A, U)
 
 
-def block_rls_update(Q, Xb, alpha: float = 1.0, *, interpret: bool = True,
-                     bo: int = 256):
+def block_rls_update(Q, Xb, alpha: float = 1.0, *,
+                     interpret: bool | None = None, bo: int = 256):
     """One full block-RLS step using the fused downdate kernel.
 
     Equivalent to ``repro.core.projections.block_update`` (the oracle).

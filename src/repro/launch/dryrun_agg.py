@@ -49,7 +49,8 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
-from repro.core.maecho import MAEchoConfig, _maecho_jit  # noqa: E402
+from repro.core.maecho import (MAEchoConfig, _maecho_jit,  # noqa: E402
+                               coverage_report)
 from repro.core.plan import compile_plan, validate_backend  # noqa: E402
 from repro.fl.llm_adapter import stack_levels_fn  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
@@ -57,43 +58,6 @@ from repro.models.zoo import get_model  # noqa: E402
 from repro.roofline import analysis as rl  # noqa: E402
 from repro.sharding.rules import make_rules  # noqa: E402
 from repro.utils import trees  # noqa: E402
-
-
-def coverage_report(W0, Pp, levels_tree, macfg, backend: str,
-                    mesh=None, convention: str = "io") -> dict:
-    """Print the per-backend leaf-coverage summary: the compiled
-    ``AggPlan``'s per-leaf routes (``core.maecho.dispatch_summary`` is
-    a view over the same plan the executor dispatches on), so a leaf
-    silently degraded to the oracle is visible instead of buried in a
-    trace-time warning.
-
-    Beyond route counts, every leaf gets a detail line with the
-    ``LeafPlan`` knobs that decide its memory/collective shape: the
-    mesh axes its Gram psums over, the effective sharding tile edge,
-    and the client-chunk size (``-`` where the knob is off) — the
-    dryrun is the one place those are visible before a 30-min
-    production compile."""
-    from repro.core.maecho import dispatch_summary
-
-    per_leaf, counts = dispatch_summary(W0, Pp, levels_tree, macfg,
-                                        convention, backend, mesh)
-    # same memoized plan the executor dispatches on — per-leaf knobs
-    plan = compile_plan(W0, Pp, levels_tree, macfg, convention,
-                        backend, mesh)
-    total = len(per_leaf)
-    summary = ", ".join(f"{k}={v}" for k, v in sorted(counts.items()))
-    print(f"[coverage] backend={backend}: {total} leaves ({summary})")
-    for lp in plan.leaves:
-        axes = ",".join(lp.psum_axes) if lp.psum_axes else "-"
-        print(f"[coverage]   {lp.path}: route={lp.route} "
-              f"psum_axes={axes} tile={lp.block or '-'} "
-              f"chunk={lp.client_chunk or '-'}")
-    if backend != "oracle":
-        for path, lv, route in per_leaf:
-            if route == "oracle":
-                print(f"[coverage]   oracle fallback: {path}"
-                      f" (stack_levels={lv})")
-    return counts
 
 
 def build_agg(arch: str, n_clients: int, mesh, tau: int,

@@ -40,6 +40,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.kernels.ops import DEFAULT_BLOCK
 from repro.models.zoo import get_model
+from repro.utils.compile_cache import use_compile_cache
 
 # families with a dense-style {"k","v"} ring-buffer cache (leading
 # layer axis, batch axis 1) — the ones the slot loop can admit into
@@ -130,7 +131,12 @@ def run_fixed(cfg, model, params, prompts, gen: int):
     for t in range(gen - 1):
         pos = pos0 + t
         wl = live_bucket(pos + 1, window) if ring else None
-        token, cache = serve_step(params, cache, token, jnp.int32(pos),
+        # slot families take per-row positions, as run_arrival passes
+        # them, so both loops run one compiled step: on a TPU a scalar-
+        # position cache write rounds differently from a per-row one
+        position = (jnp.full((B,), pos, jnp.int32)
+                    if cfg.family in SLOT_FAMILIES else jnp.int32(pos))
+        token, cache = serve_step(params, cache, token, position,
                                   w_live=wl)
         out_tokens.append(token)
     jax.block_until_ready(token)
@@ -221,6 +227,7 @@ def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--requests", type=int, default=8)

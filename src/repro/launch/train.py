@@ -21,9 +21,11 @@ from repro.data.synthetic import lm_token_batches
 from repro.models.config import InputShape
 from repro.models.zoo import get_model
 from repro.optim import adamw, cosine_schedule, sgd
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--steps", type=int, default=200)

@@ -80,13 +80,20 @@ def init_params(cfg: ModelConfig, rng):
 def _qkv(lp, x, cfg: ModelConfig):
     B, S, _ = x.shape
     hd = cfg.hd()
-    q = x @ lp["wq"].astype(cfg.cdtype)
-    k = x @ lp["wk"].astype(cfg.cdtype)
-    v = x @ lp["wv"].astype(cfg.cdtype)
-    if cfg.qkv_bias:
-        q = q + lp["bq"].astype(cfg.cdtype)
-        k = k + lp["bk"].astype(cfg.cdtype)
-        v = v + lp["bv"].astype(cfg.cdtype)
+
+    def proj(w, b):
+        if not cfg.qkv_bias:
+            return x @ lp[w].astype(cfg.cdtype)
+        # the bias is added to the fp32 product and rounded once: with
+        # a bf16 product plus a bf16 bias the TPU compiler drops the
+        # inner rounding in some fusions and not in others, by batch
+        # shape, and a batch-1 prefill then writes other K/V than the
+        # same prompt in a bigger batch
+        y = jnp.matmul(x, lp[w].astype(cfg.cdtype),
+                       preferred_element_type=jnp.float32)
+        return (y + lp[b].astype(jnp.float32)).astype(cfg.cdtype)
+
+    q, k, v = proj("wq", "bq"), proj("wk", "bk"), proj("wv", "bv")
     q = q.reshape(B, S, cfg.n_heads, hd)
     k = k.reshape(B, S, cfg.n_kv_heads, hd)
     v = v.reshape(B, S, cfg.n_kv_heads, hd)
@@ -235,10 +242,22 @@ def prefill(cfg: ModelConfig, params, batch, mlp_fn=None):
 
     body_ = jax.checkpoint(body) if cfg.remat else body
     x, cache = jax.lax.scan(body_, x, params["layers"], unroll=cfg.n_layers if cfg.unroll_layers else 1)
-    x = L.rms_norm(x[:, -1:], params["ln_f"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.cdtype)
-    return x @ head, cache
+    return _serve_logits(cfg, params, x[:, -1:]), cache
+
+
+def _serve_logits(cfg: ModelConfig, params, x):
+    """fp32 logits of the final norm and head, for greedy serving.
+
+    In bf16 a large vocabulary ties at the top often, and on a TPU
+    the head of a batch-1 prefill rounds differently from the same row
+    in a bigger batch, so admission and fixed-batch serving could pick
+    different tokens.  Kept in fp32 at full matmul precision, the two
+    agree to fp32 rounding; the head is read once per token either way.
+    """
+    x = L.rms_norm(x.astype(jnp.float32), params["ln_f"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.matmul(x, head.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 # ----- decode -------------------------------------------------------------
@@ -271,7 +290,4 @@ def decode_step(cfg: ModelConfig, params, cache, token, position,
         return h, layer_cache
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache), unroll=cfg.n_layers if cfg.unroll_layers else 1)
-    x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.cdtype)
-    return x @ head, new_cache
+    return _serve_logits(cfg, params, x), new_cache

@@ -110,3 +110,36 @@ def test_kernel_used_inside_algorithm_one():
     W_kernel = ops.maecho_update(W, V, P, alpha, eta=0.5)
     np.testing.assert_allclose(np.asarray(W1), np.asarray(W_kernel),
                                atol=1e-3)
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+def test_interpret_resolves_from_backend(monkeypatch, backend, want):
+    """Kernels interpret exactly when the default backend is the CPU;
+    an explicit flag always wins."""
+    from repro.kernels import env
+
+    monkeypatch.setattr(env.jax, "default_backend", lambda: backend)
+    assert env.interpret_default() is want
+    assert env.resolve(None) is want
+    assert env.resolve(True) is True and env.resolve(False) is False
+
+
+def test_flash_attention_grad_matches_reference():
+    """The kernel's custom VJP is the reference's: a train step can keep
+    the kernel forward."""
+    k = jax.random.PRNGKey(11)
+    q = jax.random.normal(k, (2, 128, 4, 64))
+    kk = jax.random.normal(jax.random.fold_in(k, 1), (2, 128, 2, 64))
+    v = jax.random.normal(jax.random.fold_in(k, 2), (2, 128, 2, 64))
+    w = jax.random.normal(jax.random.fold_in(k, 3), (2, 128, 4, 64))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda *a: ops.flash_attention(
+        *a, causal=True, bq=128, bk=128)), argnums=(0, 1, 2))(q, kk, v)
+    want = jax.grad(loss(lambda *a: ref.flash_attention_ref(
+        *a, causal=True)), argnums=(0, 1, 2))(q, kk, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)

@@ -46,6 +46,54 @@ def test_pad_kv_to_window_pads_only_ring_leaves():
     np.testing.assert_array_equal(np.asarray(out["k"][:, :, 16:]), 0.0)
 
 
+def test_serve_logits_are_fp32():
+    """Greedy serving reads fp32 logits from prefill and decode: in bf16
+    a large vocabulary ties at the top, and on a TPU the last bit
+    depends on the batch shape (batch-1 admission vs fixed batch)."""
+    from repro.configs import get_smoke_config
+    from repro.models.zoo import get_model
+
+    cfg = get_smoke_config("qwen2-0.5b").replace(compute_dtype="bfloat16")
+    model = get_model(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    B, P, W = 2, 12, 128
+    logits, _ = jax.eval_shape(
+        model.prefill, params,
+        {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)})
+    step, _ = jax.eval_shape(
+        model.decode_step, params, model.cache_specs(B, W),
+        jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32))
+    assert logits.dtype == step.dtype == jnp.float32
+    assert logits.shape == step.shape == (B, 1, cfg.vocab)
+
+
+def test_qkv_bias_rounds_once():
+    """q/k/v with bias = one rounding of the fp32 product plus bias.  A
+    bf16 product plus a bf16 bias rounds twice, and on a TPU whether the
+    inner rounding survives fusion depends on the batch shape."""
+    from repro.configs import get_smoke_config
+    from repro.models import dense
+
+    cfg = get_smoke_config("qwen2-0.5b").replace(compute_dtype="bfloat16")
+    assert cfg.qkv_bias
+    d, hd = cfg.d_model, cfg.hd()
+    ks = jax.random.split(jax.random.PRNGKey(3), 7)
+    lp = {n: jax.random.normal(k, (d, h * hd))
+          for n, k, h in zip(("wq", "wk", "wv"), ks[:3],
+                             (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads))}
+    lp.update({"b" + n[1]: jax.random.normal(k, (lp[n].shape[1],))
+               for n, k in zip(("wq", "wk", "wv"), ks[3:6])})
+    x = jax.random.normal(ks[6], (2, 5, d)).astype(cfg.cdtype)
+    for got, w in zip(dense._qkv(lp, x, cfg), ("wq", "wk", "wv")):
+        want = (x.astype(jnp.float32)
+                @ lp[w].astype(cfg.cdtype).astype(jnp.float32)
+                + lp["b" + w[1]]).astype(cfg.cdtype)
+        np.testing.assert_array_equal(
+            np.asarray(got.reshape(want.shape), np.float32),
+            np.asarray(want, np.float32))
+
+
 @pytest.mark.slow
 def test_arrival_matches_fixed_batch_tokens():
     """Per-request tokens from the slot loop == the fixed-batch run,

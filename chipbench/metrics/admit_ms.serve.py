@@ -1,0 +1,17 @@
+"""Host milliseconds the traced serving call spends admitting requests:
+the summed durations of the program's ``serve.admit`` spans (batch-1
+prefill, slot insert, first-token readback) inside the traced call.
+Nothing where the program records no such span."""
+
+
+def read(ctx):
+    try:
+        from repro.utils import spans
+    except ImportError:
+        return None
+    t0, t1 = next((c[0], c[1]) for c in ctx["calls"] if c[2])
+    recs = [r for r in spans.records(int(t0 * 1e9), int(t1 * 1e9))
+            if r.name == "serve.admit"]
+    if not recs:
+        return None
+    return sum(r.end_ns - r.start_ns for r in recs) / 1e6

@@ -128,7 +128,7 @@ import jax.numpy as jnp
 from repro.core import plan as plan_mod
 from repro.core import qp as qp_mod
 from repro.core.plan import AggPlan, LeafPlan, compile_plan
-from repro.utils import trees
+from repro.utils import spans, trees
 
 Pytree = Any
 
@@ -555,6 +555,14 @@ def _leaf_apply(W, V, P, ctx, alpha, lp: LeafPlan, cfg: MAEchoConfig,
     return _leaf_apply_kernel(alpha, ctx, cfg, convention, lp.block)
 
 
+def _scope(phase: str, lp: Optional[LeafPlan] = None):
+    """``maecho.<phase>``, then the leaf's path, on JAX's name stack
+    (``maecho.gram/layers.wq``): the op names by which a profile or a
+    compile dump groups the executor's work."""
+    name = f"maecho.{phase}"
+    return jax.named_scope(name if lp is None else f"{name}/{lp.path}")
+
+
 def _leaf_sequential(W, V, P, lp: LeafPlan, cfg: MAEchoConfig,
                      convention: str, mesh=None, mask=None):
     """One Algorithm-1 iteration for a single leaf on the sequential-QP
@@ -562,14 +570,17 @@ def _leaf_sequential(W, V, P, lp: LeafPlan, cfg: MAEchoConfig,
     layer for stacked leaves, matching the paper's per-layer loop) →
     apply.  The participation mask is shared by every scanned layer.
     Returns (W', V')."""
-    G, ctx = _leaf_gram(W, V, P, lp, cfg, convention, mesh)
-    if lp.levels > 0:
-        Gf = G.reshape((-1,) + G.shape[-2:])
-        alpha = jax.vmap(lambda g: _qp_alpha(g, cfg, mask))(Gf)
-        alpha = alpha.reshape(G.shape[:-2] + alpha.shape[-1:])
-    else:
-        alpha = _qp_alpha(G, cfg, mask)
-    return _leaf_apply(W, V, P, ctx, alpha, lp, cfg, convention, mesh)
+    with _scope("gram", lp):
+        G, ctx = _leaf_gram(W, V, P, lp, cfg, convention, mesh)
+    with _scope("qp", lp):
+        if lp.levels > 0:
+            Gf = G.reshape((-1,) + G.shape[-2:])
+            alpha = jax.vmap(lambda g: _qp_alpha(g, cfg, mask))(Gf)
+            alpha = alpha.reshape(G.shape[:-2] + alpha.shape[-1:])
+        else:
+            alpha = _qp_alpha(G, cfg, mask)
+    with _scope("apply", lp):
+        return _leaf_apply(W, V, P, ctx, alpha, lp, cfg, convention, mesh)
 
 
 # --------------------------------------------------------------------------
@@ -629,36 +640,40 @@ def _maecho_jit(W0, V0, P, cfg: MAEchoConfig, convention: str,
             # concat here (its padding serves the ragged case).
             grams, ctxs = [], []
             for w, v, p, lp in zip(flatW, flatV, flatP, plan.leaves):
-                g, ctx = _leaf_gram(w, v, p, lp, cfg, convention, mesh)
+                with _scope("gram", lp):
+                    g, ctx = _leaf_gram(w, v, p, lp, cfg, convention,
+                                        mesh)
                 grams.append(g)
                 ctxs.append(ctx)
-            Gstack, n_valid = qp_mod.stack_grams(grams)
             # Phase 2: ONE vmapped PGD solve for the whole batch —
             # with ragged participation, each leaf's client mask
             # (broadcast over its scanned layers) rides the solver's
             # validity masking instead of the prefix n_valid.
-            if masks is None:
-                alphas = qp_mod.solve_qp_batched(
-                    Gstack, cfg.C, cfg.qp_iters, n_valid,
-                    row_block=cfg.client_chunk)
-            else:
-                rows = [jnp.broadcast_to(m, (math.prod(g.shape[:-2]),)
-                                         + m.shape)
-                        for g, m in zip(grams, flatM)]
-                alphas = qp_mod.solve_qp_batched(
-                    Gstack, cfg.C, cfg.qp_iters,
-                    mask=jnp.concatenate(rows, 0),
-                    row_block=cfg.client_chunk)
+            with _scope("qp"):
+                Gstack, n_valid = qp_mod.stack_grams(grams)
+                if masks is None:
+                    alphas = qp_mod.solve_qp_batched(
+                        Gstack, cfg.C, cfg.qp_iters, n_valid,
+                        row_block=cfg.client_chunk)
+                else:
+                    rows = [jnp.broadcast_to(
+                                m, (math.prod(g.shape[:-2]),) + m.shape)
+                            for g, m in zip(grams, flatM)]
+                    alphas = qp_mod.solve_qp_batched(
+                        Gstack, cfg.C, cfg.qp_iters,
+                        mask=jnp.concatenate(rows, 0),
+                        row_block=cfg.client_chunk)
             # Phase 3: … scattered back through each leaf's Eq. 7/11.
             out, ofs = [], 0
             for w, v, p, lp, ctx, g in zip(flatW, flatV, flatP,
                                            plan.leaves, ctxs, grams):
                 cnt = math.prod(g.shape[:-2])
-                a = alphas[ofs:ofs + cnt].reshape(
-                    g.shape[:-2] + alphas.shape[-1:])
+                with _scope("apply", lp):
+                    a = alphas[ofs:ofs + cnt].reshape(
+                        g.shape[:-2] + alphas.shape[-1:])
+                    out.append(_leaf_apply(w, v, p, ctx, a, lp, cfg,
+                                           convention, mesh))
                 ofs += cnt
-                out.append(_leaf_apply(w, v, p, ctx, a, lp, cfg,
-                                       convention, mesh))
         else:
             out = [_leaf_sequential(w, v, p, lp, cfg, convention,
                                     mesh, m)
@@ -668,10 +683,11 @@ def _maecho_jit(W0, V0, P, cfg: MAEchoConfig, convention: str,
             # non-participants contribute nothing (α = 0 via the QP
             # mask) and their anchors stay put — the run matches
             # aggregating the participating subset alone
-            out = [(w2, jnp.where(
-                        m.reshape((-1,) + (1,) * (v1.ndim - 1)),
-                        v2, v1))
-                   for (w2, v2), v1, m in zip(out, flatV, flatM)]
+            with _scope("apply"):
+                out = [(w2, jnp.where(
+                            m.reshape((-1,) + (1,) * (v1.ndim - 1)),
+                            v2, v1))
+                       for (w2, v2), v1, m in zip(out, flatV, flatM)]
         W = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
         V = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
         return W, V
@@ -845,68 +861,77 @@ def maecho_aggregate(
                     aggregating the subset alone.  At least one client
                     must be masked in per leaf.
     """
-    plan_mod.validate_backend(backend)
-    if backend == "sharded" and mesh is None:
-        mesh = _default_mesh(cfg.mesh_axis)
-    if backend == "sharded2d" and mesh is None:
-        mesh = _default_mesh(cfg.mesh_axis, cfg.mesh_in_axis)
-    if backend not in ("sharded", "sharded2d"):
-        mesh = None                 # keep the jit cache key canonical
-    if projections is None:
-        projections = default_projections(client_weights)
-    W0 = (init_point if init_point is not None
-          else init_global(client_weights, cfg.init, rng))
-    masks = (None if client_mask is None else
-             _normalize_client_mask(client_mask, W0,
-                                    len(client_weights)))
-    if stack_levels is None:
-        levels_tree = trees.tree_map(lambda _: 0, W0)
-    elif callable(stack_levels):
-        levels_tree = trees.map_with_path(
-            lambda path, _: stack_levels(path), W0)
-    else:
-        levels_tree = stack_levels
-    levels = tuple(jax.tree_util.tree_leaves(levels_tree))
-    V0 = trees.tree_map(lambda *xs: jnp.stack(xs, 0), *client_weights)
-    P = trees.tree_map(lambda *xs: jnp.stack(xs, 0), *projections)
-    # Multi-level stacks collapse to ONE flat scan axis before dispatch
-    # (pure reshape — the QP treats every scanned layer independently,
-    # so per-layer semantics are unchanged): the stacked kernel grid
-    # wants a single layer axis, and nested vmaps over the oracle both
-    # cost an extra batch dim and trip XLA:CPU's simplifier on dense
-    # projector contractions.  Outputs are reshaped back below.
-    treedef = jax.tree_util.tree_structure(W0)
-    multi = any(lv > 1 for lv in levels)
-    if multi:
-        leads = tuple(w.shape[:lv] for w, lv in
-                      zip(jax.tree_util.tree_leaves(W0), levels))
-        fW, fV, fP = [], [], []
-        for w, v, p, lv in zip(jax.tree_util.tree_leaves(W0),
-                               treedef.flatten_up_to(V0),
-                               treedef.flatten_up_to(P), levels):
-            if lv > 1:
-                w, v, p, _ = _flatten_stack(w, v, p, lv)
-            fW.append(w)
-            fV.append(v)
-            fP.append(p)
-        W0 = jax.tree_util.tree_unflatten(treedef, fW)
-        V0 = jax.tree_util.tree_unflatten(treedef, fV)
-        P = jax.tree_util.tree_unflatten(treedef, fP)
-    run_levels = tuple(min(lv, 1) for lv in levels) if multi else levels
-    # the compile-once step: routing for every leaf is frozen here
-    # (memoized — repeated aggregations over the same model reuse the
-    # identical plan object AND therefore the executor's jit cache)
-    plan = compile_plan(
-        W0, P, jax.tree_util.tree_unflatten(treedef, list(run_levels)),
-        cfg, convention, backend, mesh)
-    W, V = _maecho_jit(W0, V0, P, cfg, convention, plan, mesh, masks)
-    if multi:
-        W = jax.tree_util.tree_unflatten(treedef, [
-            w.reshape(lead + w.shape[1:]) if lv > 1 else w
-            for w, lead, lv in zip(jax.tree_util.tree_leaves(W),
-                                   leads, levels)])
-        V = jax.tree_util.tree_unflatten(treedef, [
-            v.reshape(v.shape[:1] + lead + v.shape[2:]) if lv > 1 else v
-            for v, lead, lv in zip(treedef.flatten_up_to(V),
-                                   leads, levels)])
-    return (W, V) if return_anchors else W
+    with spans.span("maecho.aggregate"):
+        plan_mod.validate_backend(backend)
+        if backend == "sharded" and mesh is None:
+            mesh = _default_mesh(cfg.mesh_axis)
+        if backend == "sharded2d" and mesh is None:
+            mesh = _default_mesh(cfg.mesh_axis, cfg.mesh_in_axis)
+        if backend not in ("sharded", "sharded2d"):
+            mesh = None                 # keep the jit cache key canonical
+        with spans.span("maecho.place"):
+            if projections is None:
+                projections = default_projections(client_weights)
+            W0 = (init_point if init_point is not None
+                  else init_global(client_weights, cfg.init, rng))
+            masks = (None if client_mask is None else
+                     _normalize_client_mask(client_mask, W0,
+                                            len(client_weights)))
+            if stack_levels is None:
+                levels_tree = trees.tree_map(lambda _: 0, W0)
+            elif callable(stack_levels):
+                levels_tree = trees.map_with_path(
+                    lambda path, _: stack_levels(path), W0)
+            else:
+                levels_tree = stack_levels
+            levels = tuple(jax.tree_util.tree_leaves(levels_tree))
+            V0 = trees.tree_map(lambda *xs: jnp.stack(xs, 0),
+                                *client_weights)
+            P = trees.tree_map(lambda *xs: jnp.stack(xs, 0), *projections)
+            # Multi-level stacks collapse to ONE flat scan axis before
+            # dispatch (pure reshape — the QP treats every scanned layer
+            # independently, so per-layer semantics are unchanged): the
+            # stacked kernel grid wants a single layer axis, and nested vmaps
+            # over the oracle both cost an extra batch dim and trip XLA:CPU's
+            # simplifier on dense projector contractions.  Outputs are
+            # reshaped back below.
+            treedef = jax.tree_util.tree_structure(W0)
+            multi = any(lv > 1 for lv in levels)
+            if multi:
+                leads = tuple(w.shape[:lv] for w, lv in
+                              zip(jax.tree_util.tree_leaves(W0), levels))
+                fW, fV, fP = [], [], []
+                for w, v, p, lv in zip(jax.tree_util.tree_leaves(W0),
+                                       treedef.flatten_up_to(V0),
+                                       treedef.flatten_up_to(P), levels):
+                    if lv > 1:
+                        w, v, p, _ = _flatten_stack(w, v, p, lv)
+                    fW.append(w)
+                    fV.append(v)
+                    fP.append(p)
+                W0 = jax.tree_util.tree_unflatten(treedef, fW)
+                V0 = jax.tree_util.tree_unflatten(treedef, fV)
+                P = jax.tree_util.tree_unflatten(treedef, fP)
+            # the copies onto the device run after their enqueue returns;
+            # the executor cannot start before they land, so waiting here
+            # costs only its dispatch, and the span times the placement
+            jax.block_until_ready((W0, V0, P))
+        run_levels = tuple(min(lv, 1) for lv in levels) if multi else levels
+        # the compile-once step: routing for every leaf is frozen here
+        # (memoized — repeated aggregations over the same model reuse the
+        # identical plan object AND therefore the executor's jit cache)
+        plan = compile_plan(
+            W0, P, jax.tree_util.tree_unflatten(treedef, list(run_levels)),
+            cfg, convention, backend, mesh)
+        with spans.span("maecho.execute"):
+            W, V = _maecho_jit(W0, V0, P, cfg, convention, plan, mesh, masks)
+        if multi:
+            W = jax.tree_util.tree_unflatten(treedef, [
+                w.reshape(lead + w.shape[1:]) if lv > 1 else w
+                for w, lead, lv in zip(jax.tree_util.tree_leaves(W),
+                                       leads, levels)])
+            V = jax.tree_util.tree_unflatten(treedef, [
+                v.reshape(v.shape[:1] + lead + v.shape[2:]) if lv > 1 else v
+                for v, lead, lv in zip(treedef.flatten_up_to(V),
+                                       leads, levels)])
+        return (W, V) if return_anchors else W

@@ -192,6 +192,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *, bw: int = 512,
 
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,

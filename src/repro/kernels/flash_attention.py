@@ -125,6 +125,7 @@ def _flash_forward(q, k, v, causal, bq, bk, interpret):
                                n_k=n_k, causal=causal)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, Hq, n_q, n_k),
         in_specs=[
             pl.BlockSpec((None, None, bq, D),

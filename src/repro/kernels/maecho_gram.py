@@ -140,6 +140,7 @@ def maecho_gram(W, V, P, *, bo: int = 128, bi: int = 128, bk: int = 128,
     kernel = functools.partial(_gram_kernel_dense, n_clients=N, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram",
         grid=(n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec((bo, bk), lambda o, j, i, k: (o, k)),          # W
@@ -203,6 +204,7 @@ def maecho_gram_left(A, UT, *, bo: int = 128, bi: int = 128,
     kernel = functools.partial(_gram_kernel_left, n_clients=N, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram_left",
         grid=(n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec((None, bo, bk), lambda o, j, i, k: (i, o, k)),  # A
@@ -251,6 +253,7 @@ def maecho_gram_cross(Ra, Rb, *, bd: int = 512, interpret: bool | None = None):
     assert D % bd == 0, "caller pads the flat feature axis to bd"
     return pl.pallas_call(
         _gram_cross_kernel,
+        name="maecho_gram_cross",
         grid=(D // bd,),
         in_specs=[pl.BlockSpec((ca, bd), lambda k: (0, k)),
                   pl.BlockSpec((cb, bd), lambda k: (0, k))],
@@ -305,6 +308,7 @@ def maecho_gram_stacked(W, V, P, *, bo: int = 128, bi: int = 128,
                                off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram_stacked",
         grid=(L, n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec((None, bo, bk),
@@ -343,6 +347,7 @@ def maecho_gram_left_stacked(A, UT, *, bo: int = 128, bi: int = 128,
                                off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram_left_stacked",
         grid=(L, n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec((None, None, bo, bk),
@@ -374,6 +379,7 @@ def maecho_gram_diag_stacked(W, V, p, *, bo: int = 128, bi: int = 128,
     kernel = functools.partial(_gram_diag_kernel, n_clients=N, off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram_diag_stacked",
         grid=(L, out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((None, bo, bi), lambda l, o, j: (l, o, j)),   # W
@@ -402,6 +408,7 @@ def maecho_gram_diag(W, V, p, *, bo: int = 128, bi: int = 128,
     kernel = functools.partial(_gram_diag_kernel, n_clients=N)
     return pl.pallas_call(
         kernel,
+        name="maecho_gram_diag",
         grid=(out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((bo, bi), lambda o, j: (o, j)),           # W

@@ -77,6 +77,7 @@ def maecho_update(W, V, P, alpha, *, eta: float = 1.0, bo: int = 128,
     kernel = functools.partial(_kernel, eta=eta, n_clients=N, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
@@ -142,6 +143,7 @@ def maecho_update_left(W, A, UT, alpha, *, eta: float = 1.0,
                                n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_update_left",
         grid=(n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                   # alpha
@@ -178,6 +180,7 @@ def maecho_update_stacked(W, V, P, alpha, *, eta: float = 1.0,
                                off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_update_stacked",
         grid=(L, n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
@@ -218,6 +221,7 @@ def maecho_update_left_stacked(W, A, UT, alpha, *, eta: float = 1.0,
                                n_k=n_k, off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_update_left_stacked",
         grid=(L, n_out, n_in, N, n_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
@@ -253,6 +257,7 @@ def maecho_update_diag_stacked(W, V, p, alpha, *, eta: float = 1.0,
     kernel = functools.partial(_diag_kernel, eta=eta)
     return pl.pallas_call(
         kernel,
+        name="maecho_update_diag_stacked",
         grid=(L, out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((None, bo, bi), lambda l, o, j: (l, o, j)),   # W
@@ -294,6 +299,7 @@ def maecho_update_diag(W, V, p, alpha, *, eta: float = 1.0,
     kernel = functools.partial(_diag_kernel, eta=eta)
     return pl.pallas_call(
         kernel,
+        name="maecho_update_diag",
         grid=(out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((bo, bi), lambda o, j: (o, j)),            # W

@@ -108,6 +108,7 @@ def maecho_v_update(W, V, P, *, frac: float, norm: bool = False,
                                eps=eps, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update",
         grid=(N, n_out, n_in, n_k),
         in_specs=[
             pl.BlockSpec((bo, bk), lambda i, o, j, k: (o, k)),       # W (red.)
@@ -147,6 +148,7 @@ def maecho_v_update_factored(W, V, U, s, *, frac: float,
                                eps=eps, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update_factored",
         grid=(N, n_out, n_in, n_k),
         in_specs=[
             pl.BlockSpec((None, bo, bk), lambda i, o, j, k: (i, o, k)),  # B
@@ -187,6 +189,7 @@ def maecho_v_update_stacked(W, V, P, *, frac: float, norm: bool = False,
                                eps=eps, n_k=n_k, off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update_stacked",
         grid=(L, N, n_out, n_in, n_k),
         in_specs=[
             pl.BlockSpec((None, bo, bk),
@@ -234,6 +237,7 @@ def maecho_v_update_factored_stacked(W, V, U, s, *, frac: float,
                                eps=eps, n_k=n_k, off=1)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update_factored_stacked",
         grid=(L, N, n_out, n_in, n_k),
         in_specs=[
             pl.BlockSpec((None, None, bo, bk),
@@ -272,6 +276,7 @@ def maecho_v_update_diag_stacked(W, V, p, *, frac: float,
                                eps=eps)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update_diag_stacked",
         grid=(L, N, out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((None, bo, bi),
@@ -316,6 +321,7 @@ def maecho_v_update_diag(W, V, p, *, frac: float, norm: bool = False,
                                eps=eps)
     return pl.pallas_call(
         kernel,
+        name="maecho_v_update_diag",
         grid=(N, out_d // bo, in_d // bi),
         in_specs=[
             pl.BlockSpec((bo, bi), lambda i, o, j: (o, j)),          # W

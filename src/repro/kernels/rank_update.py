@@ -49,6 +49,7 @@ def rank_downdate(Q, U, A, *, bo: int = 256, bj: int = 256,
     grid = (d // bo, d // bj)
     return pl.pallas_call(
         _kernel,
+        name="rank_downdate",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bo, bj), lambda i, j: (i, j)),   # Q tile

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.kernels.ops import DEFAULT_BLOCK
 from repro.models.zoo import get_model
+from repro.utils import spans
 from repro.utils.compile_cache import use_compile_cache
 
 # families with a dense-style {"k","v"} ring-buffer cache (leading
@@ -159,6 +161,16 @@ def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
     whose request finished (budget spent or EOS) idle harmlessly until
     re-admission overwrites their rows.  Returns
     ``(outputs: list[list[int]] per request, stats)``.
+
+    The call, each admission and each decode step are program spans
+    (``serve.run_arrival``, ``serve.admit``, ``serve.step`` with its
+    ``serve.sync`` readback), and ``stats`` carries their times on
+    ``time.perf_counter_ns``: per request ``arrive_ns`` (the start of
+    the loop pass it arrived in), ``admit_ns`` and ``first_token_ns``
+    (its admission's start and end: the first token is then on the
+    host), ``first_step`` and ``last_step`` (the decode steps that gave
+    its second and last token, -1 where none did), and ``step_end_ns``
+    per decode step.  :func:`latencies` reads them.
     """
     if cfg.family not in SLOT_FAMILIES:
         raise ValueError(
@@ -166,13 +178,22 @@ def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
             f"family {cfg.family!r} is not in {SLOT_FAMILIES}")
     R, P = prompts.shape
     _, pos0_req, window = _prefill_batch(cfg, prompts[:1], gen)
+    step_fn = model.make_serve_step()
 
-    prefill1 = jax.jit(model.prefill)
-    serve_step = jax.jit(model.make_serve_step(),
-                         static_argnames=("w_live",))
+    # the bodies run while JAX traces them, so the counter counts traces
+    @jax.jit
+    def prefill1(params, batch):
+        spans.count("serve.trace", fn="prefill1")
+        return model.prefill(params, batch)
+
+    @partial(jax.jit, static_argnames=("w_live",))
+    def serve_step(params, cache, token, position, w_live):
+        spans.count("serve.trace", fn="serve_step", w_live=w_live)
+        return step_fn(params, cache, token, position, w_live=w_live)
 
     @jax.jit
     def insert(big, small, slot):
+        spans.count("serve.trace", fn="insert")
         return jax.tree_util.tree_map(
             lambda b, s: jax.lax.dynamic_update_slice_in_dim(
                 b, s.astype(b.dtype), slot, axis=1), big, small)
@@ -183,47 +204,84 @@ def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
     rid_of = [-1] * slots
     remaining = [0] * slots
     outputs: list[list[int]] = [[] for _ in range(R)]
-    next_req, step, decode_steps = 0, 0, 0
+    arrive_ns, admit_ns, first_token_ns = [0] * R, [0] * R, [0] * R
+    first_step, last_step = [-1] * R, [-1] * R
+    step_end_ns: list[int] = []
+    next_req, arrived, step = 0, 0, 0
 
-    t0 = time.time()
-    while next_req < R or any(remaining):
-        for s in range(slots):
-            if (remaining[s] == 0 and next_req < R
-                    and next_req * arrival_every <= step):
-                r, next_req = next_req, next_req + 1
-                batch, _, _ = _prefill_batch(cfg, prompts[r:r + 1], gen)
-                logits, pc = prefill1(params, batch)
-                cache = insert(cache, pad_kv_to_window(pc, window),
-                               jnp.int32(s))
-                first = int(jnp.argmax(logits[0, -1]))
-                outputs[r].append(first)
-                token = token.at[s, 0].set(first)
-                positions[s] = pos0_req
-                rid_of[s], remaining[s] = r, gen - 1
-                if eos_id is not None and first == eos_id:
-                    remaining[s] = 0
-        if not any(remaining):
+    with spans.span("serve.run_arrival", requests=R, slots=slots) as call:
+        last_end = call.start_ns
+        while next_req < R or any(remaining):
+            while arrived < R and arrived * arrival_every <= step:
+                arrive_ns[arrived] = last_end
+                arrived += 1
+            for s in range(slots):
+                if (remaining[s] == 0 and next_req < R
+                        and next_req * arrival_every <= step):
+                    r, next_req = next_req, next_req + 1
+                    with spans.span("serve.admit", rid=r, slot=s) as adm:
+                        batch, _, _ = _prefill_batch(cfg, prompts[r:r + 1],
+                                                     gen)
+                        logits, pc = prefill1(params, batch)
+                        cache = insert(cache, pad_kv_to_window(pc, window),
+                                       jnp.int32(s))
+                        first = int(jnp.argmax(logits[0, -1]))
+                        outputs[r].append(first)
+                        token = token.at[s, 0].set(first)
+                        positions[s] = pos0_req
+                        rid_of[s], remaining[s] = r, gen - 1
+                        if eos_id is not None and first == eos_id:
+                            remaining[s] = 0
+                    admit_ns[r], first_token_ns[r] = adm.start_ns, adm.end_ns
+                    last_end = adm.end_ns
+            if not any(remaining):
+                step += 1
+                continue
+            i = len(step_end_ns)
+            with spans.span("serve.step", step=i,
+                            live=sum(n > 0 for n in remaining)) as sp:
+                wl = live_bucket(int(positions.max()) + 1, window)
+                token, cache = serve_step(
+                    params, cache, token,
+                    jnp.asarray(positions, jnp.int32), w_live=wl)
+                with spans.span("serve.sync"):
+                    tok_host = np.asarray(token[:, 0])
+                for s in range(slots):
+                    if remaining[s] > 0:
+                        r = rid_of[s]
+                        outputs[r].append(int(tok_host[s]))
+                        if first_step[r] < 0:
+                            first_step[r] = i
+                        last_step[r] = i
+                        positions[s] += 1
+                        remaining[s] -= 1
+                        if eos_id is not None and tok_host[s] == eos_id:
+                            remaining[s] = 0
+            step_end_ns.append(sp.end_ns)
+            last_end = sp.end_ns
             step += 1
-            continue
-        wl = live_bucket(int(positions.max()) + 1, window)
-        token, cache = serve_step(
-            params, cache, token,
-            jnp.asarray(positions, jnp.int32), w_live=wl)
-        tok_host = np.asarray(token[:, 0])
-        for s in range(slots):
-            if remaining[s] > 0:
-                outputs[rid_of[s]].append(int(tok_host[s]))
-                positions[s] += 1
-                remaining[s] -= 1
-                if eos_id is not None and tok_host[s] == eos_id:
-                    remaining[s] = 0
-        step += 1
-        decode_steps += 1
-    t_total = time.time() - t0
+    t_total = (call.end_ns - call.start_ns) / 1e9
     n_tok = sum(len(o) for o in outputs)
-    stats = {"t_total": t_total, "decode_steps": decode_steps,
-             "tok_s": n_tok / max(t_total, 1e-9), "window": window}
+    stats = {"t_total": t_total, "decode_steps": len(step_end_ns),
+             "tok_s": n_tok / max(t_total, 1e-9), "window": window,
+             "arrive_ns": arrive_ns, "admit_ns": admit_ns,
+             "first_token_ns": first_token_ns, "first_step": first_step,
+             "last_step": last_step, "step_end_ns": step_end_ns}
     return outputs, stats
+
+
+def latencies(stats) -> dict:
+    """Time to first token (from arrival) and the gaps between a
+    request's successive tokens, in ms, from :func:`run_arrival`'s
+    stats: ``{"ttft_ms": [...], "itl_ms": [...]}``."""
+    ends = np.asarray(stats["step_end_ns"], np.int64)
+    ttft, itl = [], []
+    for a, f, i, j in zip(stats["arrive_ns"], stats["first_token_ns"],
+                          stats["first_step"], stats["last_step"]):
+        ttft.append((f - a) / 1e6)
+        if i >= 0:
+            itl.extend(np.diff(np.concatenate([[f], ends[i:j + 1]])) / 1e6)
+    return {"ttft_ms": ttft, "itl_ms": itl}
 
 
 def main() -> None:
@@ -275,6 +333,13 @@ def main() -> None:
         print(f"continuous batching: {stats['decode_steps']} decode "
               f"steps, {stats['t_total']:.2f}s "
               f"({stats['tok_s']:.1f} tok/s aggregate)")
+        lat = latencies(stats)
+        for name, xs in (("TTFT", lat["ttft_ms"]),
+                         ("inter-token", lat["itl_ms"])):
+            if xs:
+                p50, p95 = np.percentile(xs, [50, 95])
+                print(f"{name}: p50 {p50:.2f} ms, p95 {p95:.2f} ms "
+                      f"over {len(xs)}")
         print("sample:", outs[0][:16])
         if args.check_parity:
             fixed, _ = run_fixed(cfg, model, params, prompts, args.gen)

@@ -18,7 +18,8 @@ entry points:
    ``layers.w_gate`` are checked against ``backend="oracle"``;
 3. serve — four requests are served from the merged model through
    ``launch.serve.run_fixed`` and ``run_arrival``, which must agree
-   token for token.
+   token for token; ``run_arrival``'s serve step must write its cache
+   in place (its ``serve.kv_write`` counter).
 
 Four chips::
 
@@ -122,6 +123,7 @@ def main_path(cfg, seed: int) -> dict:
     from repro.launch.serve import run_arrival, run_fixed
     from repro.models.zoo import get_model
     from repro.optim import adamw
+    from repro.utils import spans
 
     dev = jax.devices()[0]
     model = get_model(cfg)
@@ -211,8 +213,14 @@ def main_path(cfg, seed: int) -> dict:
                                       size=(N_REQUESTS, PROMPT_LEN)),
                           jnp.int32)
     fixed, fstats = run_fixed(cfg, model, merged, prompts, GEN)
+    t_arrival = time.perf_counter_ns()
     outs, _ = run_arrival(cfg, model, merged, prompts, GEN,
                           slots=N_REQUESTS)
+    writes = sorted({r.attrs["path"] for r in spans.records(t_arrival)
+                     if r.name == "serve.kv_write"})
+    if writes != ["in_place"]:
+        raise RuntimeError(f"run_arrival's serve step wrote its cache by "
+                           f"{writes}, not in place")
     fixed = np.asarray(fixed)
     if fixed.shape != (N_REQUESTS, GEN):
         raise RuntimeError(f"run_fixed tokens shape {fixed.shape}")
@@ -226,7 +234,7 @@ def main_path(cfg, seed: int) -> dict:
                            f"(request: first differing step) {first_diff}")
     _say(f"[serve] {N_REQUESTS} requests x {PROMPT_LEN} prompt + {GEN} "
          f"generated, window {fstats['window']}; run_arrival matches "
-         f"run_fixed; cold-run {time.time() - t0:.1f}s (compile "
+         f"run_fixed, its cache written in place; cold-run {time.time() - t0:.1f}s (compile "
          f"included); peak_bytes_in_use "
          f"{_device_bytes(dev, 'peak_bytes_in_use')}")
     return {"parity": parity}

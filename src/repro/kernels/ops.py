@@ -51,7 +51,7 @@ __all__ = [
     "sharded_ok", "axis_size_of",
     "fallback_warn", "flash_attention_auto", "interpret_default",
     "decode_attention", "decode_attention_auto", "decode_window_block",
-    "live_window", "DEFAULT_BLOCK",
+    "live_window", "cache_write", "DEFAULT_BLOCK",
 ]
 
 # one tile edge: the auto wrappers fall back to the jnp oracles below
@@ -159,6 +159,7 @@ maecho_v_update_factored = _mv.maecho_v_update_factored
 maecho_v_update_diag = _mv.maecho_v_update_diag
 flash_attention = _fa.flash_attention
 decode_attention = _da.decode_attention
+cache_write = _da.cache_write
 rank_downdate = _ru.rank_downdate
 block_rls_update = _ru.block_rls_update
 
@@ -1424,32 +1425,36 @@ def live_window(w_live: int, W: int) -> int:
 
 
 def decode_attention_auto(q, k_cache, v_cache, valid_mask, *,
-                          interpret=None, w_live: int | None = None):
+                          interpret=None, w_live: int | None = None,
+                          layer=None):
     """Single-token KV-cache attention: Pallas window kernel when the
     window divides a block, dense jnp oracle otherwise (warn-once —
     the serving loop rounds its window to a block multiple precisely
     so this path stays hot).
 
+    Caches are (B, W, Hkv, D), or the stacked (L, B, W, Hkv, D) cache
+    read at ``layer`` (an int32 scalar), which the kernel indexes in
+    place.
+
     ``w_live`` (static python int) is the serving loop's bucketed
-    upper bound on written ring-buffer slots: the cache/mask are
-    cropped to it before the kernel, so a mostly-empty window pays
-    only its live blocks in bytes touched, not just blocks skipped.
-    Wraparound (any position ≥ W) must pass ``w_live=None`` / ``>= W``
-    — the serve loop's bucket hits W exactly then.
+    upper bound on written ring-buffer slots: the mask is cropped to
+    it, and the kernel's grid covers only those window blocks, so a
+    mostly-empty window pays only its live blocks in bytes touched,
+    not just blocks skipped.  Wraparound (any position ≥ W) must pass
+    ``w_live=None`` / ``>= W`` — the serve loop's bucket hits W exactly
+    then.
     """
-    W = k_cache.shape[1]
+    W = k_cache.shape[-3]
     if w_live is not None:
-        wl = live_window(w_live, W)
-        if wl < W:
-            k_cache = k_cache[:, :wl]
-            v_cache = v_cache[:, :wl]
-            valid_mask = valid_mask[:, :wl]
-            W = wl
+        W = live_window(w_live, W)
+        valid_mask = valid_mask[:, :W]
     bw = decode_window_block(W)
     if bw is None:
         fallback_warn(
             f"decode window W={W} is not a {DEFAULT_BLOCK}-multiple: "
             f"running the dense jnp decode oracle")
+        if layer is not None:
+            k_cache, v_cache = k_cache[layer], v_cache[layer]
         return ref.decode_attention_ref(q, k_cache, v_cache, valid_mask)
-    return decode_attention(q, k_cache, v_cache, valid_mask, bw=bw,
+    return decode_attention(q, k_cache, v_cache, valid_mask, layer, bw=bw,
                             interpret=interpret)

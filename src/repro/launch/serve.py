@@ -125,8 +125,11 @@ def run_fixed(cfg, model, params, prompts, gen: int):
     jax.block_until_ready(cache)
     t_prefill = time.time() - t0
 
+    # the cache is donated, as run_arrival donates it: both loops run
+    # one compiled step, which writes the cache in place
     serve_step = jax.jit(model.make_serve_step(),
-                         static_argnames=("w_live",))
+                         static_argnames=("w_live",),
+                         donate_argnames=("cache",))
     token = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     out_tokens = [token]
     t0 = time.time()
@@ -147,6 +150,35 @@ def run_fixed(cfg, model, params, prompts, gen: int):
              "tok_s": B * (gen - 1) / max(t_decode, 1e-9),
              "window": window}
     return jnp.concatenate(out_tokens, axis=1), stats
+
+
+def slot_fns(model):
+    """The slot loop's jitted functions: ``prefill1(params, batch)``,
+    ``insert(big, small, slot)`` and ``serve_step(params, cache, token,
+    position, w_live)``.  ``insert`` and ``serve_step`` donate the
+    cache, so each writes it in place.  Each body counts
+    ``serve.trace`` when JAX traces it."""
+    step_fn = model.make_serve_step()
+
+    # the bodies run while JAX traces them, so the counter counts traces
+    @jax.jit
+    def prefill1(params, batch):
+        spans.count("serve.trace", fn="prefill1")
+        return model.prefill(params, batch)
+
+    @partial(jax.jit, static_argnames=("w_live",), donate_argnames=("cache",))
+    def serve_step(params, cache, token, position, w_live):
+        spans.count("serve.trace", fn="serve_step", w_live=w_live)
+        return step_fn(params, cache, token, position, w_live=w_live)
+
+    @partial(jax.jit, donate_argnames=("big",))
+    def insert(big, small, slot):
+        spans.count("serve.trace", fn="insert")
+        return jax.tree_util.tree_map(
+            lambda b, s: jax.lax.dynamic_update_slice_in_dim(
+                b, s.astype(b.dtype), slot, axis=1), big, small)
+
+    return prefill1, insert, serve_step
 
 
 def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
@@ -178,25 +210,7 @@ def run_arrival(cfg, model, params, prompts, gen: int, slots: int,
             f"family {cfg.family!r} is not in {SLOT_FAMILIES}")
     R, P = prompts.shape
     _, pos0_req, window = _prefill_batch(cfg, prompts[:1], gen)
-    step_fn = model.make_serve_step()
-
-    # the bodies run while JAX traces them, so the counter counts traces
-    @jax.jit
-    def prefill1(params, batch):
-        spans.count("serve.trace", fn="prefill1")
-        return model.prefill(params, batch)
-
-    @partial(jax.jit, static_argnames=("w_live",))
-    def serve_step(params, cache, token, position, w_live):
-        spans.count("serve.trace", fn="serve_step", w_live=w_live)
-        return step_fn(params, cache, token, position, w_live=w_live)
-
-    @jax.jit
-    def insert(big, small, slot):
-        spans.count("serve.trace", fn="insert")
-        return jax.tree_util.tree_map(
-            lambda b, s: jax.lax.dynamic_update_slice_in_dim(
-                b, s.astype(b.dtype), slot, axis=1), big, small)
+    prefill1, insert, serve_step = slot_fns(model)
 
     cache = model.init_cache(slots, window)
     token = jnp.zeros((slots, 1), jnp.int32)

@@ -123,13 +123,15 @@ def attn_block(lp, x, positions, cfg: ModelConfig, *, causal=True):
 
 
 def attn_block_decode(lp, x, cache, position, cfg: ModelConfig, *,
-                      w_live: int | None = None):
+                      w_live: int | None = None, layer=None):
     """One-token self attention against a ring-buffer KV cache.
 
-    cache: {"k": (B, W, Hkv, hd), "v": ...}; position: scalar int32
-    (lockstep fixed batch) or (B,) int32 per-slot positions (the
-    continuous-batching serve loop).  ``w_live`` is the loop's static
-    live-slot bound for the cropped decode fast path.
+    cache: {"k": (B, W, Hkv, hd), "v": ...}, or with ``layer`` (an int32
+    scalar) the stacked {"k": (L, B, W, Hkv, hd), ...} cache, written
+    and read at that layer in place; position: scalar int32 (lockstep
+    fixed batch) or (B,) int32 per-slot positions (the continuous-
+    batching serve loop).  ``w_live`` is the loop's static live-slot
+    bound for the cropped decode fast path.
     """
     B, S, _ = x.shape  # S == 1
     q, k, v = _qkv(lp, x, cfg)
@@ -138,9 +140,11 @@ def attn_block_decode(lp, x, cache, position, cfg: ModelConfig, *,
            else position[:, None])
     q = L.apply_rope(q, pos, cfg.rope_theta)
     k = L.apply_rope(k, pos, cfg.rope_theta)
-    cache, valid = L.update_kv_cache(cache, k, v, position)
+    cache, valid = L.update_kv_cache(cache, k, v, position, layer=layer,
+                                     backend=cfg.attn_backend)
     o = L.decode_attention(q, cache["k"], cache["v"], valid,
-                           backend=cfg.attn_backend, w_live=w_live)
+                           backend=cfg.attn_backend, w_live=w_live,
+                           layer=layer)
     y = o.reshape(B, 1, cfg.n_heads * cfg.hd()) @ lp["wo"].astype(cfg.cdtype)
     return y, cache
 
@@ -276,18 +280,42 @@ def decode_step(cfg: ModelConfig, params, cache, token, position,
 
     Returns (logits (B, 1, V), new_cache).  ``w_live`` is the serving
     loop's static live-slot bound (see ``layers.decode_attention``).
+
+    With per-slot positions, and no sharding context, the stacked cache
+    rides the layer scan as carry: each layer writes its B new positions
+    into it in place and the decode kernel reads it at the layer index,
+    so a step whose cache is donated touches no other cache byte.  A
+    scalar position or a sharded trace scans the cache per layer
+    (``xs``/``ys``), where the §Perf H2 cache pinning applies.
     """
+    from repro.sharding import ctx as shard_ctx
+
     x = params["embed"].astype(cfg.cdtype)[token]
+    position = jnp.asarray(position, jnp.int32)
+    fn = mlp_fn or (lambda lp, y: mlp_block(lp, y, cfg))
+    unroll = cfg.n_layers if cfg.unroll_layers else 1
 
-    def body(x, scanned):
-        lp, layer_cache = scanned
-        a, layer_cache = attn_block_decode(
-            lp, L.rms_norm(x, lp["ln1"], cfg.norm_eps), layer_cache,
-            position, cfg, w_live=w_live)
+    def block(lp, x, cache, l):
+        a, cache = attn_block_decode(
+            lp, L.rms_norm(x, lp["ln1"], cfg.norm_eps), cache, position,
+            cfg, w_live=w_live, layer=l)
         h = x + a
-        fn = mlp_fn or (lambda lp, y: mlp_block(lp, y, cfg))
         h = h + fn(lp, L.rms_norm(h, lp["ln2"], cfg.norm_eps))
-        return h, layer_cache
+        return h, cache
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache), unroll=cfg.n_layers if cfg.unroll_layers else 1)
+    if position.ndim == 1 and not shard_ctx.active():
+        def carried(carry, scanned):
+            (x, cache), (lp, l) = carry, scanned
+            return block(lp, x, cache, l), None
+
+        (x, new_cache), _ = jax.lax.scan(
+            carried, (x, cache),
+            (params["layers"], jnp.arange(cfg.n_layers)), unroll=unroll)
+    else:
+        def per_layer(x, scanned):
+            lp, layer_cache = scanned
+            return block(lp, x, layer_cache, None)
+
+        x, new_cache = jax.lax.scan(per_layer, x, (params["layers"], cache),
+                                    unroll=unroll)
     return _serve_logits(cfg, params, x), new_cache

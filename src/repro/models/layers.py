@@ -14,6 +14,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.utils import spans
+
 NEG_INF = -1e30
 
 
@@ -211,18 +213,23 @@ def prefill_attention(q, k, v, *, causal: bool = True, q_chunk: int = 512,
 
 
 def decode_attention(q, k_cache, v_cache, valid_mask, *,
-                     backend: str = "auto", w_live: int | None = None):
+                     backend: str = "auto", w_live: int | None = None,
+                     layer=None):
     """Single-token attention against a (possibly ring-buffer) KV cache,
     with backend dispatch.
 
-    q: (B, 1, Hq, D); caches: (B, W, Hkv, D); valid_mask: (B, W) bool.
+    q: (B, 1, Hq, D); caches: (B, W, Hkv, D), or the stacked
+    (L, B, W, Hkv, D) cache read at ``layer`` (an int32 scalar);
+    valid_mask: (B, W) bool.
     ``backend`` (``ModelConfig.attn_backend``): "oracle" forces the
     dense full-window einsum; "kernel" forces the Pallas window kernel
     whenever W divides a block (warn-once fallback otherwise); "auto"
     takes the kernel when the window is blocked AND spans at least two
     blocks, where skipping invalid window blocks pays for the launch.
     Sharded decode (shard_ctx active) always runs the oracle — its
-    GSPMD cache pinning is tuned there (§Perf H2).
+    GSPMD cache pinning is tuned there (§Perf H2).  The kernel reads
+    the stacked cache at its layer in place; the oracle reads the
+    layer's slice.
 
     ``w_live`` is the serving loop's static upper bound on written
     ring-buffer slots (see ``ops.decode_attention_auto``): the kernel
@@ -235,7 +242,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
     if backend != "oracle" and not shard_ctx.active():
         from repro.kernels import ops
 
-        W = k_cache.shape[1]
+        W = k_cache.shape[-3]
         blocked = W % ops.DEFAULT_BLOCK == 0
         # "auto" under the CPU interpreter needs the crop to win (the
         # grid scan re-copies the carried cache every step); compiled
@@ -244,11 +251,14 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
             not ops.interpret_default() or w_live is not None)
         if blocked and (backend == "kernel" or wins):
             return ops.decode_attention_auto(q, k_cache, v_cache,
-                                             valid_mask, w_live=w_live)
+                                             valid_mask, w_live=w_live,
+                                             layer=layer)
         if backend == "kernel":
             ops.fallback_warn(
                 f"decode window W={W} is not a {ops.DEFAULT_BLOCK}-"
                 f"multiple: running the dense jnp decode oracle")
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     return decode_attention_oracle(q, k_cache, v_cache, valid_mask)
 
 
@@ -293,47 +303,81 @@ def init_kv_cache(batch: int, window: int, n_kv: int, head_dim: int, dtype):
     }
 
 
-def update_kv_cache(cache, k_new, v_new, position):
+def update_kv_cache(cache, k_new, v_new, position, layer=None, *,
+                    backend: str = "auto"):
     """Insert one token per row at ``position % window`` (ring buffer).
 
     k_new/v_new: (B, 1, Hkv, D); position: scalar int32 (every row at
     the same absolute position — the lockstep fixed-batch loop) or (B,)
     int32 per-row positions (the continuous-batching slot loop, where
-    each slot decodes at its own depth).  Returns
+    each slot decodes at its own depth).  The cache is one layer's
+    (B, W, Hkv, D) leaves, or with ``layer`` (an int32 scalar, per-row
+    positions only) the stacked (L, B, W, Hkv, D) leaves that the decode
+    scan carries: then the B new positions ``(layer, row, position % W)``
+    are written and nothing else, in place in the carried (and donated)
+    buffer.  ``backend`` picks that write as ``prefill_attention`` picks
+    its kernel: the Pallas ``ops.cache_write``, which reads and writes
+    the cache in the layout the device holds it in, or an XLA scatter
+    (the CPU's, and the "oracle" backend's).  Returns
     (cache, valid_mask (B, W)).
+
+    Counts ``serve.kv_write`` at trace time, ``path="in_place"`` for
+    the stacked write and ``"select"`` for a write into one layer's
+    leaves (the one-hot select of per-row positions, or the dynamic
+    update of a scalar one), which the layer scan then stacks anew.
     """
+    from repro.kernels import ops
     from repro.sharding import ctx as shard_ctx
 
-    B, W = cache["k"].shape[0], cache["k"].shape[1]
+    B, W = cache["k"].shape[-4], cache["k"].shape[-3]
     position = jnp.asarray(position, jnp.int32)
+    if layer is not None:
+        if position.ndim != 1:
+            raise ValueError("a layer-indexed cache write takes per-row "
+                             f"positions, got shape {position.shape}")
+        spans.count("serve.kv_write", path="in_place")
+        slots = jnp.mod(position, W)
+        if backend == "kernel" or (backend == "auto"
+                                   and not ops.interpret_default()):
+            k, v = ops.cache_write(cache["k"], cache["v"], k_new[:, 0],
+                                   v_new[:, 0], layer, slots)
+        else:
+            rows = jnp.arange(B)
+            k, v = (cache[n].at[layer, rows, slots].set(
+                        new[:, 0], unique_indices=True,
+                        indices_are_sorted=True)
+                    for n, new in (("k", k_new), ("v", v_new)))
+        return {"k": k, "v": v}, _valid_slots(position[:, None], W)
+    spans.count("serve.kv_write", path="select")
     # pin cache sharding across the update (EXPERIMENTS.md §Perf H2:
     # GSPMD otherwise fully rematerialises the cache — 1.1 GB AG/layer)
     k_new = shard_ctx.constrain_cache(k_new, "k")
     v_new = shard_ctx.constrain_cache(v_new, "v")
     kc = shard_ctx.constrain_cache(cache["k"], "k")
     vc = shard_ctx.constrain_cache(cache["v"], "v")
-    idx = jnp.arange(W)
     if position.ndim == 0:
         slot = jnp.mod(position, W)
         k = jax.lax.dynamic_update_slice_in_dim(kc, k_new, slot, axis=1)
         v = jax.lax.dynamic_update_slice_in_dim(vc, v_new, slot, axis=1)
         pos = position[None]                                  # (1,) rows
     else:
-        # per-row slots: one-hot where-write (a batched DUS would lower
-        # to a gather/scatter pair; the select keeps the cache in place)
-        hit = idx[None, :] == jnp.mod(position, W)[:, None]   # (B, W)
+        # per-row slots: one-hot where-write over the layer's leaves
+        hit = jnp.arange(W)[None, :] == jnp.mod(position, W)[:, None]
         k = jnp.where(hit[:, :, None, None], k_new, kc)
         v = jnp.where(hit[:, :, None, None], v_new, vc)
         pos = position
     k = shard_ctx.constrain_cache(k, "k")
     v = shard_ctx.constrain_cache(v, "v")
-    # slot i holds absolute position p with p % W == i and p <= position;
-    # valid iff that p > position - W  (within window) and p >= 0.
-    pos = pos[:, None]
-    last_abs = pos - jnp.mod(pos - idx[None, :], W)  # latest abs pos per slot
-    valid = (last_abs >= 0) & (last_abs > pos - W)
-    valid = jnp.broadcast_to(valid, (B, W))
+    valid = jnp.broadcast_to(_valid_slots(pos[:, None], W), (B, W))
     return {"k": k, "v": v}, valid
+
+
+def _valid_slots(pos, W: int):
+    """(rows, W) validity of ring slots after writing position ``pos``
+    (rows, 1): slot i holds absolute position p with p % W == i and
+    p <= pos; valid iff that p > pos - W (within window) and p >= 0."""
+    last_abs = pos - jnp.mod(pos - jnp.arange(W)[None, :], W)
+    return (last_abs >= 0) & (last_abs > pos - W)
 
 
 # --------------------------------------------------------------------------

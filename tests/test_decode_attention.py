@@ -4,8 +4,10 @@ Property parity over the shared case space in ``tests/strategies.py``
 (MHA/GQA/MQA shapes, fills past the ring-buffer wraparound point) plus
 hand-picked regressions: the two Pallas grid layouts, the static
 live-window crop, all-invalid masks, real ``update_kv_cache``-driven
-wraparound, vector-vs-scalar cache updates, and the prefill backend
-dispatch (kernel parity + forced-kernel warn-once fallback).
+wraparound, vector-vs-scalar cache updates, the layer-indexed read and
+in-place write of the stacked cache the decode scan carries, and the
+prefill backend dispatch (kernel parity + forced-kernel warn-once
+fallback).
 """
 import jax
 import jax.numpy as jnp
@@ -162,6 +164,99 @@ def test_vector_and_scalar_cache_updates_agree():
         for leaf in ("k", "v"):
             np.testing.assert_array_equal(np.asarray(c_s[leaf]),
                                           np.asarray(c_v[leaf]))
+
+
+# --------------------------------------------------------------------------
+# the stacked (L, B, W, Hkv, D) cache: layer-indexed read, in-place write
+# --------------------------------------------------------------------------
+# D = 64 is read through the window-minor view, D = 128 directly
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("fold_batch", [True, False])
+@pytest.mark.parametrize("w_live", [None, 200])
+def test_layer_indexed_kernel_matches_layer_slice(D, fold_batch, w_live):
+    """The kernel on the stacked cache at layer l is the kernel on that
+    layer's slice, bit for bit; with a live-window crop, the shorter
+    grid over the whole cache is the kernel on the cropped slice."""
+    n_l, B, W, Hq, Hkv = 3, 2, 512, 8, 2
+    k = jax.random.PRNGKey(D)
+    q = jax.random.normal(k, (B, 1, Hq, D)).astype(jnp.bfloat16)
+    kc = jax.random.normal(jax.random.fold_in(k, 1),
+                           (n_l, B, W, Hkv, D)).astype(jnp.bfloat16)
+    vc = jax.random.normal(jax.random.fold_in(k, 2),
+                           (n_l, B, W, Hkv, D)).astype(jnp.bfloat16)
+    fill = jnp.array([[150], [190]])                 # rows at their depths
+    valid = jnp.arange(W)[None, :] < fill
+    wl = W if w_live is None else ops.live_window(w_live, W)
+    for layer in range(n_l):
+        got = ops.decode_attention(q, kc, vc, valid[:, :wl],
+                                   jnp.int32(layer), bw=128,
+                                   fold_batch=fold_batch)
+        want = ops.decode_attention(q, kc[layer, :, :wl],
+                                    vc[layer, :, :wl], valid[:, :wl],
+                                    bw=128, fold_batch=fold_batch)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        auto = ops.decode_attention_auto(q, kc, vc, valid, w_live=w_live,
+                                         layer=jnp.int32(layer))
+        sliced = ops.decode_attention_auto(q, kc[layer], vc[layer], valid,
+                                           w_live=w_live)
+        np.testing.assert_array_equal(np.asarray(auto), np.asarray(sliced))
+
+
+def _slot_positions():
+    """Per-row positions over a few steps of a W = 128 ring: rows at
+    different depths, rows past the wraparound point, and row 2
+    re-admitted (its position back to the prompt's end) after it
+    finished."""
+    W = 128
+    start = np.array([3, 40, W + 90, 2 * W - 2])
+    steps = [start + t for t in range(4)]
+    readmit = steps[-1].copy()
+    readmit[2] = 5
+    return W, steps + [readmit, readmit + 1]
+
+
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("backend", ["kernel", "oracle"])
+def test_in_place_write_matches_select(D, backend):
+    """The layer-indexed write of the stacked cache (the Pallas write
+    under "kernel", XLA's scatter under "oracle") leaves exactly the
+    cache and mask that the one-hot select leaves in that layer's
+    slice, and every other layer untouched."""
+    W, steps = _slot_positions()
+    n_l, B, Hkv = 3, len(steps[0]), 2
+    k = jax.random.PRNGKey(D)
+    stacked = {n: jax.random.normal(jax.random.fold_in(k, i),
+                                    (n_l, B, W, Hkv, D)).astype(jnp.bfloat16)
+               for i, n in enumerate("kv")}
+    for t, pos in enumerate(steps):
+        pos = jnp.asarray(pos, jnp.int32)
+        for layer in range(n_l):
+            kk = jax.random.fold_in(k, 100 * t + layer)
+            k_new, v_new = (jax.random.normal(jax.random.fold_in(kk, i),
+                                              (B, 1, Hkv, D))
+                            .astype(jnp.bfloat16) for i in range(2))
+            want, m_want = L.update_kv_cache(
+                {n: stacked[n][layer] for n in "kv"}, k_new, v_new, pos)
+            got, m_got = L.update_kv_cache(stacked, k_new, v_new, pos,
+                                           layer=jnp.int32(layer),
+                                           backend=backend)
+            np.testing.assert_array_equal(np.asarray(m_got),
+                                          np.asarray(m_want))
+            for n in "kv":
+                np.testing.assert_array_equal(np.asarray(got[n][layer]),
+                                              np.asarray(want[n]))
+                others = np.array([i for i in range(n_l) if i != layer])
+                np.testing.assert_array_equal(
+                    np.asarray(got[n][others]),
+                    np.asarray(stacked[n][others]))
+            stacked = got
+
+
+def test_in_place_write_takes_per_row_positions():
+    cache = {n: jnp.zeros((2, 3, 128, 2, 64)) for n in "kv"}
+    new = jnp.ones((3, 1, 2, 64))
+    with pytest.raises(ValueError, match="per-row"):
+        L.update_kv_cache(cache, new, new, jnp.int32(4), layer=jnp.int32(0))
 
 
 # --------------------------------------------------------------------------

@@ -1,9 +1,14 @@
-"""Serving loop: window helpers + continuous-batching token parity.
+"""Serving loop: window helpers, the decode step, the donated cache, and
+continuous-batching token parity.
 
 The continuous-batching loop (``launch/serve.py --arrival``) must emit
 exactly the tokens the lockstep fixed-batch loop emits per request —
 admission order, slot reuse, batch-1 prefill insertion and the
-bucketed live-window crop must all be invisible to the outputs.
+bucketed live-window crop must all be invisible to the outputs.  The
+decode step with per-slot positions carries the stacked cache through
+its layer scan and writes it in place: it must compute exactly what the
+per-layer formulation (each layer's slice scanned in and out, written
+by a one-hot select) computes.
 """
 import jax
 import jax.numpy as jnp
@@ -117,3 +122,125 @@ def test_arrival_matches_fixed_batch_tokens():
         assert len(outs[r]) == gen
         np.testing.assert_array_equal(
             np.asarray(fixed[r]), np.asarray(outs[r], np.int32))
+
+
+# --------------------------------------------------------------------------
+# the decode step: the carried, in-place cache against the per-layer form
+# --------------------------------------------------------------------------
+def _per_layer_decode_step(cfg, params, cache, token, position, mlp_fn,
+                           w_live=None):
+    """Each layer's cache slice scanned as xs/ys and written by the
+    one-hot select of ``update_kv_cache``."""
+    from repro.models import dense
+    from repro.models import layers as L
+
+    x = params["embed"].astype(cfg.cdtype)[token]
+
+    def body(x, scanned):
+        lp, layer_cache = scanned
+        a, layer_cache = dense.attn_block_decode(
+            lp, L.rms_norm(x, lp["ln1"], cfg.norm_eps), layer_cache,
+            position, cfg, w_live=w_live)
+        h = x + a
+        h = h + mlp_fn(lp, L.rms_norm(h, lp["ln2"], cfg.norm_eps))
+        return h, layer_cache
+
+    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
+    return dense._serve_logits(cfg, params, x), cache
+
+
+def _random_cache(model, B, W, seed):
+    leaves, tree = jax.tree.flatten(model.init_cache(B, W))
+    k = jax.random.PRNGKey(seed)
+    return tree.unflatten(
+        [jax.random.normal(jax.random.fold_in(k, i), c.shape).astype(c.dtype)
+         for i, c in enumerate(leaves)])
+
+
+@pytest.mark.parametrize("backend", ["auto", "kernel"])
+@pytest.mark.parametrize("arch", ["qwen2_0_5b", "qwen2_moe_a2_7b",
+                                  "phi3_vision_4_2b"])
+def test_decode_step_matches_per_layer_formulation(arch, backend):
+    """dense, moe and vlm: logits and cache of the carried decode step
+    equal the per-layer formulation's exactly, with rows at different
+    depths and one past the wraparound point."""
+    from repro.configs import get_smoke_config
+    from repro.models import dense, moe
+    from repro.models.zoo import get_model
+
+    cfg = get_smoke_config(arch).replace(attn_backend=backend)
+    model = get_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    B, W = 3, 256
+    cache = _random_cache(model, B, W, 1)
+    token = jnp.asarray([[7], [11], [13]], jnp.int32)
+    position = jnp.asarray([5, W + 30, 200], jnp.int32)
+    mlp_fn = ((lambda lp, y: moe.moe_block(lp, y, cfg)[0])
+              if cfg.family == "moe" else
+              (lambda lp, y: dense.mlp_block(lp, y, cfg)))
+    want = _per_layer_decode_step(cfg, params, cache, token, position,
+                                  mlp_fn, w_live=W)
+    got = model.decode_step(params, cache, token, position, w_live=W)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("arch", ["zamba2_2_7b", "whisper_tiny"])
+def test_hybrid_and_encdec_decode_keep_the_per_layer_cache(arch):
+    """hybrid (its own group scan over dense.attn_block_decode) and
+    encdec (update_kv_cache at a scalar position) keep one layer's
+    4-D cache: the kernel and the oracle read it alike, and the cache
+    they write is the same."""
+    from repro.configs import get_smoke_config
+    from repro.models.zoo import get_model
+
+    outs = {}
+    for backend in ("oracle", "kernel"):
+        cfg = get_smoke_config(arch).replace(attn_backend=backend)
+        model = get_model(cfg)
+        params = model.init_params(jax.random.PRNGKey(0))
+        cache = _random_cache(model, 2, 256, 2)
+        outs[backend] = model.decode_step(
+            params, cache, jnp.ones((2, 1), jnp.int32), jnp.int32(9))
+    (lo, co), (lk, ck) = outs["oracle"], outs["kernel"]
+    np.testing.assert_allclose(np.asarray(lk), np.asarray(lo),
+                               atol=1e-4, rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(ck), jax.tree.leaves(co)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_slot_fns_donate_the_cache():
+    """The slot loop's serve step and insert alias the cache to their
+    output, and the serve step holds no second cache: compiled at smoke
+    widths, alias bytes cover the cache and temp bytes stay below it.
+    (On XLA:CPU the oracle's layer read materialises that layer's
+    slice; the v5e compile in test_tpu_compile.py, with the Pallas read
+    and write, pins the serve step's temp bytes below one layer's
+    K+V.)"""
+    from repro.configs import get_smoke_config
+    from repro.launch.serve import slot_fns
+    from repro.models.zoo import get_model
+
+    # the oracle backend, XLA's scatter and einsum: in the Pallas
+    # interpreter every kernel operand is a copy
+    cfg = get_smoke_config("qwen2-0.5b").replace(attn_backend="oracle")
+    model = get_model(cfg)
+    B, W = 32, 1024
+    params = model.param_specs()
+    cache = model.cache_specs(B, W)
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(cache))
+    _, insert, serve_step = slot_fns(model)
+    i32 = jnp.int32
+    step = serve_step.lower(params, cache, jax.ShapeDtypeStruct((B, 1), i32),
+                            jax.ShapeDtypeStruct((B,), i32), w_live=W
+                            ).compile().memory_analysis()
+    small = jax.tree.map(
+        lambda c: jax.ShapeDtypeStruct(c.shape[:1] + (1,) + c.shape[2:],
+                                       c.dtype), cache)
+    ins = insert.lower(cache, small, jax.ShapeDtypeStruct((), i32)
+                       ).compile().memory_analysis()
+    assert step.alias_size_in_bytes >= cache_bytes
+    assert ins.alias_size_in_bytes >= cache_bytes
+    assert step.temp_size_in_bytes < cache_bytes

@@ -132,6 +132,8 @@ def test_run_arrival_records_admissions_steps_and_traces():
     assert sorted(r.parent_id for r in syncs) == sorted(r.id for r in steps)
     traced = {r.attrs["fn"] for r in inside if r.name == "serve.trace"}
     assert traced == {"prefill1", "insert", "serve_step"}
+    writes = {r.attrs["path"] for r in inside if r.name == "serve.kv_write"}
+    assert writes == {"in_place"}
     assert stats["t_total"] == (call.end_ns - call.start_ns) / 1e9
 
     # per-request times, from the same records
@@ -147,6 +149,37 @@ def test_run_arrival_records_admissions_steps_and_traces():
     lat = latencies(stats)
     assert len(lat["ttft_ms"]) == R and len(lat["itl_ms"]) == R * (gen - 1)
     assert min(lat["ttft_ms"]) > 0 and min(lat["itl_ms"]) > 0
+
+
+def test_kv_write_counter_names_the_write_path():
+    """``serve.kv_write`` counts, per trace, which cache write the step
+    took: in place for a dense step at per-row positions, the per-layer
+    select at a scalar position or under a sharding context."""
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_debug_mesh
+    from repro.models.zoo import get_model
+    from repro.sharding import ctx as shard_ctx
+    from repro.sharding.rules import make_rules
+
+    cfg = get_smoke_config("qwen2-0.5b")
+    model = get_model(cfg)
+    params = model.param_specs()
+    B, W = 2, 128
+    cache = model.cache_specs(B, W)
+    token = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    per_row = jax.ShapeDtypeStruct((B,), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def paths(position):
+        t0 = time.perf_counter_ns()
+        jax.eval_shape(model.decode_step, params, cache, token, position)
+        return [r.attrs["path"] for r in _since(t0, "serve.kv_write")]
+
+    assert paths(per_row) == ["in_place"]
+    assert paths(scalar) == ["select"]
+    mesh = make_debug_mesh(1, 1)
+    with mesh, shard_ctx.use_rules(make_rules(mesh, cfg)):
+        assert paths(per_row) == ["select"]
 
 
 def test_spans_are_host_events_of_a_profile():

@@ -13,6 +13,8 @@ workers all import every test file.  The persistent compilation cache
 is off around the compiles (an entry written for a described chip cannot
 be read back without one).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -106,6 +108,64 @@ def test_decode_fine_grid_compiles(one_chip):
         q, k, v, m, bw=128, interpret=False, fold_batch=False),
         one_chip, ((B, 1, HQ, HD), bf), ((B, W, HKV, HD), bf),
         ((B, W, HKV, HD), bf), ((B, W), jnp.bool_))
+
+
+# the serving cell's cache: 64 slots, a 1280-slot window
+SERVE_B, SERVE_W = 64, 1280
+
+
+def test_decode_layer_indexed_compiles(one_chip):
+    """The stacked cache read at a scalar-prefetched layer index, in
+    window blocks of 256, at the serving shapes."""
+    bf = jnp.bfloat16
+    cache = ((L, SERVE_B, SERVE_W, HKV, HD), bf)
+    _compile(lambda q, k, v, m, layer: da.decode_attention(
+        q, k, v, m, layer, bw=256, interpret=False, fold_batch=False),
+        one_chip, ((SERVE_B, 1, HQ, HD), bf), cache, cache,
+        ((SERVE_B, SERVE_W), jnp.bool_), ((), jnp.int32))
+
+
+def test_cache_write_compiles(one_chip):
+    bf = jnp.bfloat16
+    cache = ((L, SERVE_B, SERVE_W, HKV, HD), bf)
+    new = ((SERVE_B, HKV, HD), bf)
+    text = _compile(lambda k, v, kn, vn, layer, slots: da.cache_write(
+        k, v, kn, vn, layer, slots, interpret=False),
+        one_chip, cache, cache, new, new, ((), jnp.int32),
+        ((SERVE_B,), jnp.int32))
+    assert "output_to_operand_aliasing" in text
+
+
+def test_serve_step_writes_the_cache_in_place(one_chip, monkeypatch):
+    """The slot loop's serve step at smoke widths and the serving
+    cache's shape, with the chip's kernels: the donated cache is aliased
+    to the output, and no more than one layer's K+V of temporaries is
+    live — no second cache, no whole-cache select or layout copy."""
+    from repro.configs import get_smoke_config
+    from repro.kernels import env, ops
+    from repro.launch.serve import slot_fns
+    from repro.models.zoo import get_model
+
+    # the dispatch asks the default backend, which is the CPU here
+    monkeypatch.setattr(env, "interpret_default", lambda: False)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    cfg = get_smoke_config("qwen2-0.5b").replace(compute_dtype="bfloat16")
+    model = get_model(cfg)
+    on_chip = functools.partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip))
+    cache = on_chip(model.cache_specs(SERVE_B, SERVE_W))
+    token, position = on_chip((jax.ShapeDtypeStruct((SERVE_B, 1), jnp.int32),
+                               jax.ShapeDtypeStruct((SERVE_B,), jnp.int32)))
+    _, _, serve_step = slot_fns(model)
+    compiled = serve_step.lower(on_chip(model.param_specs()), cache, token,
+                                position, w_live=SERVE_W).compile()
+    text = compiled.as_text()
+    assert "kv_cache_write" in text and "decode_attention" in text
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize
+                      for c in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // cfg.n_layers
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])

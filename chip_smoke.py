@@ -25,10 +25,12 @@ Four chips::
 
     python chip_smoke.py --chips 4
 
-runs only the mesh path: ``backend="sharded2d"`` on a (2, 2)
-("data", "model") mesh at qwen2-1.5b widths cut to 4 layers, with the
-client weights placed by ``sharding.rules``, against
-``backend="auto"`` on one chip.
+runs only the mesh path at qwen2-1.5b widths cut to the benchmark
+cell's 12 layers: ``aggregate_llm(backend="sharded2d")`` on host
+checkpoints with no mesh given, so the program derives the (2, 2)
+("data", "model") mesh and places each checkpoint into its shards
+itself; ``layers.wq`` and ``layers.w_gate`` are checked against
+``backend="oracle"`` on one chip (the whole merge does not fit one).
 
 Every phase runs in this one process: a chip belongs to one process.
 Printed seconds are cold-run wall time with compilation included —
@@ -241,27 +243,26 @@ def main_path(cfg, seed: int) -> dict:
 
 
 # --------------------------------------------------------------------------
-# four chips: sharded2d on a (2, 2) mesh vs auto on one chip
+# four chips: sharded2d on the mesh the program derives
 # --------------------------------------------------------------------------
-def mesh_path(cfg, seed: int, n_data: int = 2, n_model: int = 2) -> dict:
+def mesh_path(cfg, seed: int) -> dict:
     import jax
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.core.maecho import MAEchoConfig
     from repro.data.synthetic import lm_token_batches
-    from repro.fl.llm_adapter import aggregate_llm, build_projections
-    from repro.launch.mesh import make_debug_mesh
+    from repro.fl.llm_adapter import (aggregate_llm, build_projections,
+                                      stack_levels_fn)
     from repro.models.zoo import get_model
-    from repro.sharding.rules import make_rules
+    from repro.utils import spans
     from repro.utils.trees import tree_paths
 
     devs = jax.devices()
-    mesh = make_debug_mesh(n_data, n_model)
     model = get_model(cfg)
     _say(f"[setup] {cfg.name} widths, {cfg.n_layers} layers, d_model "
-         f"{cfg.d_model}, d_ff {cfg.d_ff}; mesh {dict(mesh.shape)}")
+         f"{cfg.d_model}, d_ff {cfg.d_ff}; {len(devs)} chips")
 
+    # the silos' checkpoints and projectors, kept on the host as an
+    # operator holds them
     t0 = time.time()
     base = model.init_params(jax.random.PRNGKey(seed))
     clients, projs = [], []
@@ -280,51 +281,46 @@ def mesh_path(cfg, seed: int, n_data: int = 2, n_model: int = 2) -> dict:
     _say(f"[setup] clients and projectors {time.time() - t0:.1f}s")
 
     macfg = MAEchoConfig(tau=15, eta=0.5, mu=20.0, qp_batched=False)
-    rules = make_rules(mesh, cfg)
-    shardings = rules.params_shardings(clients[0])
-    replicated = NamedSharding(mesh, PartitionSpec())
-    placed = [jax.device_put(c, shardings) for c in clients]
-    placed_p = [jax.device_put(p, replicated) for p in projs]
-    _say(f"[mesh] bytes_in_use per chip after placement "
-         f"{[_device_bytes(d, 'bytes_in_use') for d in devs]}")
-
     t0 = time.time()
+    t_ns = time.perf_counter_ns()
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        per_leaf, W0 = _coverage(cfg, placed, placed_p, macfg,
-                                 "sharded2d", mesh)
-        sharded = aggregate_llm(cfg, placed, placed_p, macfg,
-                                backend="sharded2d", mesh=mesh)
-        sharded = jax.block_until_ready(sharded)
+        sharded = jax.block_until_ready(aggregate_llm(
+            cfg, clients, projs, macfg, backend="sharded2d"))
     for w in caught:
         _say(f"[mesh] warning: {w.message}")
-    weights = _weight_leaves(per_leaf, W0)
-    routes = {p: r for p, _, r in per_leaf}
-    if any(routes[p] not in ("sharded2d", "sharded") for p in weights):
-        raise RuntimeError(f"weight leaves off the mesh routes: "
-                           f"{[(p, routes[p]) for p in weights]}")
-    _say(f"[mesh] sharded2d cold-run {time.time() - t0:.1f}s (compile "
-         f"included); bytes_in_use per chip "
-         f"{[_device_bytes(d, 'bytes_in_use') for d in devs]}; "
-         f"peak_bytes_in_use per chip "
+    recs = spans.records(t_ns)
+    routes = {r.attrs["route"]: r.attrs["n"] for r in recs
+              if r.name == "maecho.route"}
+    placed = {r.attrs["device"]: r.attrs["n"] for r in recs
+              if r.name == "maecho.place_bytes"}
+    mesh = dict(sharded["layers"]["wq"].sharding.mesh.shape)
+    _say(f"[mesh] sharded2d on mesh {mesh}, routes {routes}; cold-run "
+         f"{time.time() - t0:.1f}s (compile included); bytes placed per "
+         f"chip {placed}; peak_bytes_in_use per chip "
          f"{[_device_bytes(d, 'peak_bytes_in_use') for d in devs]}")
-    sharded = jax.device_get(sharded)
-    del placed, placed_p
+    lv = stack_levels_fn(cfg)
+    weights = [p for p, x in tree_paths(clients[0]) if x.ndim - lv(p) == 2]
+    on_mesh = routes.get("sharded2d", 0) + routes.get("sharded", 0)
+    if on_mesh != len(weights):
+        raise RuntimeError(f"{len(weights)} weight leaves, {on_mesh} on "
+                           f"the mesh routes: {routes}")
+
+    def sub(tree):
+        return {"layers": {k: tree["layers"][k] for k in PARITY_LEAVES}}
 
     t0 = time.time()
-    single = jax.device_get(aggregate_llm(cfg, clients, projs, macfg,
-                                          backend="auto"))
-    _say(f"[mesh] one-chip auto cold-run {time.time() - t0:.1f}s")
-    errs = {p: _rel_err(a, b) for (p, a), (_, b) in zip(
-        tree_paths(sharded), tree_paths(single))}
-    worst = max(errs, key=errs.get)
-    _say(f"[mesh] sharded2d vs one-chip auto: max rel {errs[worst]:.3e} "
-         f"({worst}); " + json.dumps({p: f"{e:.2e}" for p, e in
-                                      errs.items()}))
-    if not errs[worst] < REL_TOL:
-        raise RuntimeError(f"sharded2d vs auto rel error {errs[worst]} "
-                           f">= {REL_TOL} at {worst}")
-    return {"max_rel": errs[worst]}
+    oracle = aggregate_llm(cfg, [sub(c) for c in clients],
+                           [sub(p) for p in projs], macfg, backend="oracle")
+    errs = {k: _rel_err(sharded["layers"][k], oracle["layers"][k])
+            for k in PARITY_LEAVES}
+    _say(f"[mesh] sharded2d vs one-chip oracle {json.dumps(errs)} "
+         f"({time.time() - t0:.1f}s)")
+    bad = {k: e for k, e in errs.items() if not e < REL_TOL}
+    if bad:
+        raise RuntimeError(f"sharded2d vs oracle rel error >= {REL_TOL}: "
+                           f"{bad}")
+    return {"mesh": mesh, "max_rel": max(errs.values())}
 
 
 def main() -> None:
@@ -354,7 +350,7 @@ def main() -> None:
     from repro.configs import get_config
 
     if args.chips == 4:
-        mesh_path(get_config("qwen2-1.5b").replace(n_layers=4), args.seed)
+        mesh_path(get_config("qwen2-1.5b").replace(n_layers=12), args.seed)
     else:
         warnings.filterwarnings("error", category=RuntimeWarning)
         main_path(get_config("qwen2-0.5b"), args.seed)

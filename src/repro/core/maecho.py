@@ -75,7 +75,14 @@ Backends — the ``backend`` argument of :func:`maecho_aggregate`:
     device count factors as osz × isz instead of dividing the
     out-tiles 1-D.  Leaves that fail the 2-D gate degrade to the 1-D
     ``"sharded"`` shard over ``mesh_axis``, then to the ``"auto"``
-    rule (each fallback warned once).
+    rule (each fallback warned once).  Without a mesh it runs on every
+    visible device, factored as squarely as their count allows.
+
+Placement: the plan is compiled from shapes before anything moves, and
+:func:`place` puts each client leaf from the host straight into the
+shards its plan's layout gives (``LeafPlan.specs``), stacks the clients
+on the device and makes W⁽⁰⁾ there, so no device holds a whole stack it
+would only give away again.
 
 Routing is compiled ONCE per (treedef, shapes, conventions,
 stack_levels, backend, mesh, config) by ``core.plan.compile_plan``
@@ -613,6 +620,21 @@ def init_global(client_weights: list[Pytree], how: str,
     raise ValueError(f"unknown init {how!r}")
 
 
+def _hold_layout(flatW, flatV, flatP, plan: AggPlan, mesh):
+    """The loop's carried W and V, held in the layout ``place`` put
+    them in (:meth:`LeafPlan.specs`)."""
+    from jax.sharding import NamedSharding
+
+    W, V = [], []
+    for w, v, p, lp in zip(flatW, flatV, flatP, plan.leaves):
+        ws, vs, _ = lp.specs(mesh, plan.convention, w.ndim, p)
+        W.append(jax.lax.with_sharding_constraint(w, NamedSharding(mesh,
+                                                                   ws)))
+        V.append(jax.lax.with_sharding_constraint(v, NamedSharding(mesh,
+                                                                   vs)))
+    return W, V
+
+
 @partial(jax.jit, static_argnames=("cfg", "convention", "plan",
                                    "mesh"), donate_argnames=("V0",))
 def _maecho_jit(W0, V0, P, cfg: MAEchoConfig, convention: str,
@@ -632,6 +654,8 @@ def _maecho_jit(W0, V0, P, cfg: MAEchoConfig, convention: str,
         flatP = treedef.flatten_up_to(P)
         flatM = (list(masks) if masks is not None
                  else [None] * len(flatW))
+        if mesh is not None:
+            flatW, flatV = _hold_layout(flatW, flatV, flatP, plan, mesh)
         if cfg.qp_batched:
             # Phase 1: every leaf's (and every scanned layer's) Eq. 6
             # Gram, assembled into one (L, N, N) stack.  N — the
@@ -767,20 +791,167 @@ def coverage_report(W0, Pp, levels_tree, macfg, backend: str,
 
 
 def _default_mesh(axis_name: str, in_axis_name: Optional[str] = None):
-    """Mesh over every visible device — the sharded backends'
-    convenience default, so ``maecho_backend="sharded"`` works without
-    explicit mesh plumbing (pass a real mesh for production).  With
-    ``in_axis_name`` (the ``"sharded2d"`` default) the mesh carries a
-    trivial size-1 in-axis: all devices stay on the out-row axis, and
-    callers that want real 2-D spans pass their own factored mesh."""
+    """Mesh over every visible device, so the sharded backends run
+    without mesh plumbing.  Without ``in_axis_name`` (``"sharded"``) it
+    is 1-D.  With it (``"sharded2d"``) the device count factors as
+    squarely as it allows, the larger factor on the out-row axis: 4
+    devices → (2, 2), 8 → (4, 2), 1 → (1, 1)."""
     import numpy as np
     from jax.sharding import Mesh
 
     devs = np.asarray(jax.devices())
     if in_axis_name is None:
         return Mesh(devs, (axis_name,))
-    return Mesh(devs.reshape(len(devs), 1),
+    return Mesh(devs.reshape(mesh_grid(len(devs))),
                 (axis_name, in_axis_name))
+
+
+def mesh_grid(n: int) -> tuple:
+    """(out-row, in-column) axis sizes of ``n`` devices, factored as
+    squarely as ``n`` allows, the larger factor first."""
+    n_in = max(d for d in range(1, math.isqrt(n) + 1) if n % d == 0)
+    return n // n_in, n_in
+
+
+@partial(jax.jit, static_argnums=0)
+def _stack_into(sharding, *parts):
+    """The clients' copies of one leaf, stacked on a new client axis in
+    ``sharding``: each device stacks its own shards, gathering from its
+    peers the parts ``place`` sent them."""
+    out = jnp.stack(parts, 0)
+    if sharding is None:
+        return out
+    return jax.lax.with_sharding_constraint(out, sharding)
+
+
+@partial(jax.jit, static_argnums=0)
+def _average_clients(sharding, V):
+    """``init_global``'s average over the stacked client axis, in its
+    order of operations (the same bits), in ``sharding``."""
+    out = V[0]
+    for i in range(1, V.shape[0]):
+        out = out + V[i]
+    out = out * (1.0 / V.shape[0])
+    if sharding is None:
+        return out
+    return jax.lax.with_sharding_constraint(out, sharding)
+
+
+def _moved_bytes(x, sharding, counts: dict) -> None:
+    """Add to ``counts`` (device id -> bytes) what putting ``x`` into
+    ``sharding`` (``None``: the default device) moves onto each device;
+    nothing where ``x`` already lies there."""
+    if isinstance(x, jax.Array) and (
+            sharding is None or x.sharding.is_equivalent_to(sharding,
+                                                            x.ndim)):
+        return
+    if sharding is None:
+        shard, devices = x.shape, [jax.devices()[0]]
+    else:
+        shard, devices = sharding.shard_shape(x.shape), sharding.device_set
+    nbytes = math.prod(shard) * jnp.dtype(x.dtype).itemsize
+    for d in devices:
+        counts[d.id] = counts.get(d.id, 0) + nbytes
+
+
+def _spread(spec, shape, mesh):
+    """``spec`` with each mesh axis it leaves out put on the largest dim
+    it leaves whole that the axis divides: the spec a host array is sent
+    in, so that every device receives distinct bytes from the host, and
+    the devices that hold the same shard fill it in from each other."""
+    from jax.sharding import PartitionSpec
+
+    spec = list(spec)
+    used = {a for s in spec if s is not None
+            for a in ((s,) if isinstance(s, str) else s)}
+    for name, size in mesh.shape.items():
+        free = [i for i, s in enumerate(spec)
+                if s is None and shape[i] % size == 0]
+        if name not in used and size > 1 and free:
+            spec[max(free, key=lambda i: shape[i])] = name
+    return PartitionSpec(*spec)
+
+
+def place(client_weights: list, projections: list, plan: AggPlan, mesh,
+          init: str = "average", rng=None, init_point=None):
+    """The executor's operands, ``(W0, V0, P)``, on the device in the
+    layout the plan's leaves take (:meth:`LeafPlan.specs`): each
+    client leaf goes from where it lies (the host, as a rule) straight
+    into its shards, the clients are stacked on the device, and W0 is
+    made there from the placed anchors, so no device holds more than
+    its shards.  Where several devices hold the same shard, each
+    receives a distinct part of it (:func:`_spread`) and the stacking
+    fills in the rest between devices.  With ``mesh=None`` everything
+    lands on the default device.  Leaves keep their stack axes; the
+    caller flattens them.  Records the counter ``maecho.place_bytes``
+    (``device``, ``n``): the bytes each device receives from where the
+    leaves lie."""
+    from jax.sharding import NamedSharding
+
+    treedef = jax.tree_util.tree_structure(client_weights[0])
+    flat_c = [treedef.flatten_up_to(c) for c in client_weights]
+    flat_p = [treedef.flatten_up_to(p) for p in projections]
+    moved: dict = {}
+
+    def put(xs, spec):
+        sh = one = None
+        if mesh is not None:
+            sh = NamedSharding(mesh, spec)
+            one = NamedSharding(mesh, _spread(spec[1:], xs[0].shape, mesh))
+        parts = []
+        for x in xs:
+            _moved_bytes(x, one, moved)
+            parts.append(jax.device_put(x, one))
+        return _stack_into(sh, *parts)
+
+    V0, P, specs = [], [], []
+    for i, lp in enumerate(plan.leaves):
+        w = flat_c[0][i]
+        p_tree = flat_p[0][i]
+        ws, vs, ps = lp.specs(mesh, plan.convention, len(w.shape),
+                              _stacked_shape(p_tree, len(flat_p)))
+        specs.append(ws)
+        V0.append(put([c[i] for c in flat_c], vs))
+        if isinstance(p_tree, dict):
+            P.append({k: put([p[i][k] for p in flat_p], ps[k])
+                      for k in p_tree})
+        else:
+            P.append(put([p[i] for p in flat_p], ps))
+    shardings = [None if mesh is None else NamedSharding(mesh, s)
+                 for s in specs]
+    if init_point is not None:
+        W0 = treedef.flatten_up_to(init_point)
+        for w, sh in zip(W0, shardings):
+            _moved_bytes(w, sh, moved)
+    elif init == "average":
+        W0 = [_average_clients(sh, v) for sh, v in zip(shardings, V0)]
+    else:
+        views = [jax.tree_util.tree_unflatten(treedef, [v[i] for v in V0])
+                 for i in range(len(client_weights))]
+        W0 = treedef.flatten_up_to(init_global(views, init, rng))
+    W0 = [jax.device_put(w, sh) for w, sh in zip(W0, shardings)]
+    for dev, nbytes in sorted(moved.items()):
+        spans.count("maecho.place_bytes", nbytes, device=dev)
+    return tuple(jax.tree_util.tree_unflatten(treedef, x)
+                 for x in (W0, V0, P))
+
+
+def _flat_shape(x, levels: int, lead: tuple = ()):
+    """Shape-only stand-in of ``x`` with ``lead`` prepended and its
+    ``levels`` leading stack axes collapsed into one (where
+    ``levels`` > 1, as the executor takes it)."""
+    shape = tuple(x.shape)
+    if levels > 1:
+        shape = (math.prod(shape[:levels]),) + shape[levels:]
+    return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(x.dtype))
+
+
+def _stacked_shape(p, n: int, levels: int = 0):
+    """Shape-only stand-in of one client's projector leaf (or factored
+    dict) stacked over ``n`` clients."""
+    if isinstance(p, dict):
+        return {k: _flat_shape(v, levels, (n,)) for k, v in p.items()}
+    return _flat_shape(p, levels, (n,))
 
 
 def _normalize_client_mask(client_mask, W0, n_clients: int):
@@ -851,8 +1022,9 @@ def maecho_aggregate(
                     for ``backend="sharded"`` (default: a 1-D mesh
                     over every visible device) — plus
                     ``cfg.mesh_in_axis`` for ``backend="sharded2d"``
-                    (default: all devices on the out-row axis and a
-                    trivial size-1 in-axis).  Ignored otherwise.
+                    (default: every visible device, factored as
+                    squarely as the count allows — 4 → (2, 2)).
+                    Ignored otherwise.
     client_mask:    optional ragged-participation mask — one (N,)
                     boolean vector, or a pytree of them matching the
                     weight structure (per-leaf client subsets).
@@ -869,37 +1041,52 @@ def maecho_aggregate(
             mesh = _default_mesh(cfg.mesh_axis, cfg.mesh_in_axis)
         if backend not in ("sharded", "sharded2d"):
             mesh = None                 # keep the jit cache key canonical
+        if projections is None:
+            projections = default_projections(client_weights)
+        treedef = jax.tree_util.tree_structure(client_weights[0])
+        if stack_levels is None:
+            levels_tree = jax.tree_util.tree_unflatten(
+                treedef, [0] * treedef.num_leaves)
+        elif callable(stack_levels):
+            levels_tree = trees.map_with_path(
+                lambda path, _: stack_levels(path), client_weights[0])
+        else:
+            levels_tree = stack_levels
+        levels = tuple(jax.tree_util.tree_leaves(levels_tree))
+        # Multi-level stacks collapse to ONE flat scan axis before
+        # dispatch (pure reshape — the QP treats every scanned layer
+        # independently, so per-layer semantics are unchanged): the
+        # stacked kernel grid wants a single layer axis, and nested vmaps
+        # over the oracle both cost an extra batch dim and trip XLA:CPU's
+        # simplifier on dense projector contractions.  Outputs are
+        # reshaped back below.
+        multi = any(lv > 1 for lv in levels)
+        run_levels = tuple(min(lv, 1) for lv in levels) if multi else levels
+        leads = tuple(w.shape[:lv] for w, lv in
+                      zip(jax.tree_util.tree_leaves(client_weights[0]),
+                          levels))
+        # the compile-once step, from shapes alone, before anything is
+        # placed: routing for every leaf is frozen here, and with it the
+        # layout each operand is placed in (memoized — repeated
+        # aggregations over the same model reuse the identical plan
+        # object AND therefore the executor's jit cache)
+        n = len(client_weights)
+        W_shapes = [_flat_shape(w, lv) for w, lv in
+                    zip(jax.tree_util.tree_leaves(client_weights[0]),
+                        levels)]
+        P_shapes = [_stacked_shape(p, n, lv) for p, lv in
+                    zip(treedef.flatten_up_to(projections[0]), levels)]
+        plan = compile_plan(
+            jax.tree_util.tree_unflatten(treedef, W_shapes),
+            jax.tree_util.tree_unflatten(treedef, P_shapes),
+            jax.tree_util.tree_unflatten(treedef, list(run_levels)),
+            cfg, convention, backend, mesh)
         with spans.span("maecho.place"):
-            if projections is None:
-                projections = default_projections(client_weights)
-            W0 = (init_point if init_point is not None
-                  else init_global(client_weights, cfg.init, rng))
+            W0, V0, P = place(client_weights, projections, plan, mesh,
+                              cfg.init, rng, init_point)
             masks = (None if client_mask is None else
-                     _normalize_client_mask(client_mask, W0,
-                                            len(client_weights)))
-            if stack_levels is None:
-                levels_tree = trees.tree_map(lambda _: 0, W0)
-            elif callable(stack_levels):
-                levels_tree = trees.map_with_path(
-                    lambda path, _: stack_levels(path), W0)
-            else:
-                levels_tree = stack_levels
-            levels = tuple(jax.tree_util.tree_leaves(levels_tree))
-            V0 = trees.tree_map(lambda *xs: jnp.stack(xs, 0),
-                                *client_weights)
-            P = trees.tree_map(lambda *xs: jnp.stack(xs, 0), *projections)
-            # Multi-level stacks collapse to ONE flat scan axis before
-            # dispatch (pure reshape — the QP treats every scanned layer
-            # independently, so per-layer semantics are unchanged): the
-            # stacked kernel grid wants a single layer axis, and nested vmaps
-            # over the oracle both cost an extra batch dim and trip XLA:CPU's
-            # simplifier on dense projector contractions.  Outputs are
-            # reshaped back below.
-            treedef = jax.tree_util.tree_structure(W0)
-            multi = any(lv > 1 for lv in levels)
+                     _normalize_client_mask(client_mask, W0, n))
             if multi:
-                leads = tuple(w.shape[:lv] for w, lv in
-                              zip(jax.tree_util.tree_leaves(W0), levels))
                 fW, fV, fP = [], [], []
                 for w, v, p, lv in zip(jax.tree_util.tree_leaves(W0),
                                        treedef.flatten_up_to(V0),
@@ -916,14 +1103,9 @@ def maecho_aggregate(
             # the executor cannot start before they land, so waiting here
             # costs only its dispatch, and the span times the placement
             jax.block_until_ready((W0, V0, P))
-        run_levels = tuple(min(lv, 1) for lv in levels) if multi else levels
-        # the compile-once step: routing for every leaf is frozen here
-        # (memoized — repeated aggregations over the same model reuse the
-        # identical plan object AND therefore the executor's jit cache)
-        plan = compile_plan(
-            W0, P, jax.tree_util.tree_unflatten(treedef, list(run_levels)),
-            cfg, convention, backend, mesh)
         with spans.span("maecho.execute"):
+            for route, count in sorted(plan.route_counts().items()):
+                spans.count("maecho.route", count, route=route)
             W, V = _maecho_jit(W0, V0, P, cfg, convention, plan, mesh, masks)
         if multi:
             W = jax.tree_util.tree_unflatten(treedef, [

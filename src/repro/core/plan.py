@@ -106,6 +106,36 @@ class LeafPlan:
     def stacked(self) -> bool:
         return self.levels > 0
 
+    def specs(self, mesh, convention: str, w_ndim: int, p) -> tuple:
+        """``(W, V, P)`` PartitionSpecs of this leaf's executor operands
+        in the caller's ``convention``: the layout both the gram's and
+        the apply's ``shard_map`` take on the sharded routes, so
+        operands placed in it reach the kernels with no reshard.
+        ``w_ndim`` counts W's axes (leading stack axes, flattened or
+        not, stay replicated); ``p`` is the stacked projector leaf, or
+        the factored kind's dict, which the P spec mirrors.
+
+        Out-rows split over ``out_axes``, V's client axis in front.
+        Everything else is whole on every device: on ``sharded2d`` each
+        device forms its in-column tile from whole rows and a whole
+        projector, since Eq. 11 contracts full rows, and a dim the
+        executor pads (out-dim not a multiple of ``block`` × its axis
+        size) stays replicated, since padding a split dim would move
+        data between devices."""
+        from jax.sharding import PartitionSpec
+
+        from repro.kernels.ops import axis_size_of
+
+        def rep(x):
+            return PartitionSpec(*([None] * len(x.shape)))
+
+        w = [None] * w_ndim
+        if self.out_axes and self.out_d % (
+                self.block * axis_size_of(mesh, self.out_axes)) == 0:
+            w[-2 if convention == "oi" else -1] = self.out_axes
+        pspec = trees.tree_map(rep, p) if isinstance(p, dict) else rep(p)
+        return PartitionSpec(*w), PartitionSpec(None, *w), pspec
+
 
 @dataclasses.dataclass(frozen=True)
 class AggPlan:

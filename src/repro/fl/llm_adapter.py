@@ -363,15 +363,14 @@ def aggregate_llm(cfg: ModelConfig, client_params: list,
     tile — including the scan-over-layers transformer stacks, whose
     layer axis folds into the stacked kernel grid — to the fused
     Pallas pipeline; smoke-scale models (dims below one 128-tile)
-    degrade to the oracle with identical results.  Pass
-    ``backend="sharded"`` plus a ``mesh`` to additionally split leaf
-    out-rows across devices (one psum per leaf per outer iteration),
-    or ``backend="sharded2d"`` plus a mesh carrying both
-    ``macfg.mesh_axis`` and ``macfg.mesh_in_axis`` to shard the
-    residual 2-D (out × in) — the route for attention/MLP leaves
-    whose out-dim alone cannot span the fleet (still one psum, taken
-    over both axis groups).  Routing is compiled once per model shape
-    into an ``AggPlan`` (``core.plan``); inspect it with
+    degrade to the oracle with identical results.  On a host with
+    several chips, ``backend="sharded2d"`` shards each leaf's residual
+    2-D (out × in) over every chip — the mesh derived from the device
+    count (4 → (2, 2)) unless ``mesh`` is given, with one psum per leaf
+    per outer iteration over both axis groups — and places each host
+    checkpoint straight into its shards; ``backend="sharded"`` splits
+    only the out-rows.  Routing is compiled once per model shape into
+    an ``AggPlan`` (``core.plan``); inspect it with
     ``core.maecho.dispatch_summary`` or ``dryrun_agg --backend ...``.
     """
     if client_projs is None:

@@ -802,6 +802,17 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
 # --------------------------------------------------------------------------
 # 2-D (out × in) mesh-sharded pipeline: backend="sharded2d"
 # --------------------------------------------------------------------------
+def _pin(mesh, *pairs):
+    """Each array of ``pairs`` ((array, spec), …) held in ``spec`` on
+    ``mesh``.  The 2-D gram slices its in-columns out of operands that
+    the row-local apply then takes whole: pinned to the apply's layout,
+    each is sliced on its device and never gathered back."""
+    from jax.sharding import NamedSharding
+
+    return [jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+            for x, spec in pairs]
+
+
 def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
                           axis_in="model", block: int = DEFAULT_BLOCK,
                           interpret=None):
@@ -835,15 +846,19 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
     isz = axis_size_of(mesh, axis_in)
     out_d, in_d = W.shape
     kind = _proj_kind(P)
-    Wp = _pad_to(_pad_to(W, block * osz, 0)[0], block * isz, 1)[0]
-    Vp = _pad_to(_pad_to(V, block * osz, 1)[0], block * isz, 2)[0]
     row = PartitionSpec(no, None)
     crow = PartitionSpec(None, no, None)
     col3 = PartitionSpec(None, None, ni)
     rep2 = PartitionSpec(None, None)
     rep3 = PartitionSpec(None, None, None)
+    Wp, Vp = _pin(mesh,
+                  (_pad_to(_pad_to(W, block * osz, 0)[0], block * isz,
+                           1)[0], row),
+                  (_pad_to(_pad_to(V, block * osz, 1)[0], block * isz,
+                           2)[0], crow))
     if kind == "factored":
-        Up, sp = _pad_factored(P["U"], P["s"], block)
+        Up, sp = _pin(mesh, *zip(_pad_factored(P["U"], P["s"], block),
+                                 (rep3, rep2)))
         UTs = jnp.swapaxes(Up, 1, 2).astype(jnp.float32)
 
         def body_f(Wl, Vl, U, s, UTl):
@@ -860,7 +875,8 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         in_p = Wp.shape[1]
-        Pk = _pad_to(_pad_to(P, in_p, 1)[0], in_p, 2)[0]
+        Pk, = _pin(mesh, (_pad_to(_pad_to(P, in_p, 1)[0], in_p, 2)[0],
+                          rep3))
 
         def body_d(Wl, Vl, Pl):
             # residual tile = Δ @ P[:, owned columns]: the delta rows
@@ -875,7 +891,7 @@ def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
                       out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
     else:                                   # scalar / diag
         p = _as_diag(P, in_d) if kind == "scalar" else P
-        Pk = _pad_to(p, block * isz, 1)[0]
+        Pk, = _pin(mesh, (_pad_to(p, block * isz, 1)[0], rep2))
 
         def body_g(Wl, Vl, pl):
             # elementwise kind: 2-D-slicing the operands is exact
@@ -929,15 +945,20 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
     isz = axis_size_of(mesh, axis_in)
     L, out_d, in_d = W.shape
     kind = _proj_kind_stacked(P)
-    Wp = _pad_to(_pad_to(W, block * osz, 1)[0], block * isz, 2)[0]
-    Vp = _pad_to(_pad_to(V, block * osz, 2)[0], block * isz, 3)[0]
     row = PartitionSpec(None, no, None)
     crow = PartitionSpec(None, None, no, None)
     col4 = PartitionSpec(None, None, None, ni)
     rep3 = PartitionSpec(None, None, None)
     rep4 = PartitionSpec(None, None, None, None)
+    Wp, Vp = _pin(mesh,
+                  (_pad_to(_pad_to(W, block * osz, 1)[0], block * isz,
+                           2)[0], row),
+                  (_pad_to(_pad_to(V, block * osz, 2)[0], block * isz,
+                           3)[0], crow))
     if kind == "factored":
-        Up, sp = _pad_factored_stacked(P["U"], P["s"], block)
+        Up, sp = _pin(mesh, *zip(_pad_factored_stacked(P["U"], P["s"],
+                                                       block),
+                                 (rep4, rep3)))
         UTs = jnp.swapaxes(Up, 2, 3).astype(jnp.float32)
 
         def body_f(Wl, Vl, U, s, UTl):
@@ -952,7 +973,8 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
         return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
     if kind == "full":
         in_p = Wp.shape[2]
-        Pk = _pad_to(_pad_to(P, in_p, 2)[0], in_p, 3)[0]
+        Pk, = _pin(mesh, (_pad_to(_pad_to(P, in_p, 2)[0], in_p, 3)[0],
+                          rep4))
 
         def body_d(Wl, Vl, Pl):
             # Δ (N, L, o_sh, in_p) is already the left-factor layout;
@@ -967,7 +989,7 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
     else:                                   # scalar / diag
         p = (jnp.broadcast_to(P[:, :, None], P.shape + (in_d,))
              if kind == "scalar" else P)
-        Pk = _pad_to(p, block * isz, 2)[0]
+        Pk, = _pin(mesh, (_pad_to(p, block * isz, 2)[0], rep3))
 
         def body_g(Wl, Vl, pl_):
             return jax.lax.psum(

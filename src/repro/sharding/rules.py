@@ -189,85 +189,6 @@ class Rules:
                                  self.batch_spec(path, leaf.shape))
         return trees.map_with_path(mk, input_specs)
 
-    # ---------------- one-shot aggregation ----------------
-    # PartitionSpecs for the mesh-sharded MA-Echo pipeline
-    # (core.maecho backend="sharded"): leaf out-rows split over the
-    # data axes, everything that feeds the global QP replicated.  The
-    # block-granular eligibility itself lives in kernels.ops.sharded_ok
-    # (padding makes the row count exact); these placement rules apply
-    # the plain `_ok` divisibility contract for callers that stage the
-    # operands onto the mesh ahead of the call.  The shapes must stay
-    # congruent with the shard_map specs ops.maecho_sharded_gram/apply
-    # build inline (W rows on dim 0, V rows on dim 1, the rest
-    # replicated) — pinned by tests/test_sharded_agg.py.
-    def agg_out_axes(self, out_dim: int):
-        """Axes for a leaf's out-rows — ("pod","data") when the dim
-        divides, else None (the single-device fallback)."""
-        return self._ok(out_dim, data_axes(self.mesh))
-
-    def agg_weight_spec(self, shape: tuple) -> P:
-        """Global weight leaf W (out, in): rows over the data axes.
-        1-D bias leaves (oracle path) stay replicated."""
-        if len(shape) != 2:
-            return P(*([None] * len(shape)))
-        return self.spec(shape, (data_axes(self.mesh), None))
-
-    def agg_anchor_spec(self, shape: tuple) -> P:
-        """Client-stacked anchors V (N, out, in): the same out-rows on
-        axis 1, clients replicated (every device sees all N for the
-        pairwise Gram)."""
-        if len(shape) != 3:
-            return P(*([None] * len(shape)))
-        return self.spec(shape, (None, data_axes(self.mesh), None))
-
-    def agg_proj_spec(self, shape: tuple) -> P:
-        """Projectors act on the (unsharded) in-axis — replicated."""
-        return P(*([None] * len(shape)))
-
-    # ------ 2-D (out × in) aggregation: backend="sharded2d" ------
-    # Rows stay on the data axes; the residual's in-columns (and dense
-    # projectors' *output* column axis) additionally shard over
-    # "model".  Divisibility gating is `sharded_ok2d` above; the
-    # shapes must stay congruent with the shard_map specs
-    # ops.maecho_sharded2d_gram builds inline (pinned by
-    # tests/test_plan.py).
-    def agg_in_axes(self, in_dim: int):
-        """Axes for a leaf's in-columns — "model" when the dim
-        divides, else None (degrades to the 1-D out-row shard)."""
-        return self._ok(in_dim, "model")
-
-    def agg_weight_spec2d(self, shape: tuple) -> P:
-        """Global weight leaf W (out, in): rows over the data axes AND
-        columns over "model" (each with the `_ok` fallback)."""
-        if len(shape) != 2:
-            return P(*([None] * len(shape)))
-        return self.spec(shape, (data_axes(self.mesh), "model"))
-
-    def agg_anchor_spec2d(self, shape: tuple) -> P:
-        """Client-stacked anchors V (N, out, in): same 2-D placement
-        on the trailing dims, clients replicated."""
-        if len(shape) != 3:
-            return P(*([None] * len(shape)))
-        return self.spec(shape,
-                         (None, data_axes(self.mesh), "model"))
-
-    def agg_proj_spec2d(self, shape: tuple) -> P:
-        """Dense projectors P (N, in, in): the *output* column axis
-        (the last one — the residual's in-index) shards over "model";
-        the contraction axis stays replicated (each device contracts
-        the full in-dim when forming its residual tile)."""
-        if len(shape) != 3:
-            return P(*([None] * len(shape)))
-        return self.spec(shape, (None, None, "model"))
-
-    def agg_gram_spec(self) -> P:
-        """(N, N) Grams are psum-reconstructed — replicated."""
-        return P(None, None)
-
-    def agg_alpha_spec(self) -> P:
-        """Simplex weights α feed every row shard — replicated."""
-        return P(None)
-
 
 def make_rules(mesh: Mesh, cfg: ModelConfig) -> Rules:
     return Rules(mesh, cfg)

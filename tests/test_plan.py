@@ -343,28 +343,29 @@ def test_sharded2d_missing_in_axis_warns_once():
 
 
 def test_agg_partition_specs_2d():
-    """The rules' 2-D aggregation placement specs: rows over the data
-    axes AND columns over "model", dense projectors sharded on their
-    output column axis only — congruent with the shard_map layout
-    ops.maecho_sharded2d_gram builds inline."""
+    """The plan's placement specs on the 2-D shard (``LeafPlan.specs``):
+    out-rows over the data axes only — the apply contracts whole rows,
+    so the devices along "model" hold the same rows — and every
+    projector kind whole, dense and factored alike."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.configs import get_config
-    from repro.sharding.rules import make_rules
-
-    rules = make_rules(FakeMesh({"pod": 2, "data": 16, "model": 16}),
-                       get_config("llama3_8b"))
-    assert rules.agg_in_axes(4096) == "model"
-    assert rules.agg_in_axes(100) is None
-    assert rules.agg_weight_spec2d((4096, 2048)) == P(
-        ("pod", "data"), "model")
-    assert rules.agg_weight_spec2d((4096, 100)) == P(
-        ("pod", "data"), None)
-    assert rules.agg_weight_spec2d((4096,)) == P(None)
-    assert rules.agg_anchor_spec2d((8, 4096, 2048)) == P(
-        None, ("pod", "data"), "model")
-    assert rules.agg_proj_spec2d((8, 2048, 2048)) == P(
-        None, None, "model")
+    mesh = FakeMesh({"data": 2, "model": 4})
+    cfg = MAEchoConfig()
+    W = {"d": jax.ShapeDtypeStruct((2, 512, 256), jnp.float32),
+         "f": jax.ShapeDtypeStruct((512, 256), jnp.float32)}
+    Ps = {"d": jax.ShapeDtypeStruct((3, 2, 512, 512), jnp.float32),
+          "f": {"U": jax.ShapeDtypeStruct((3, 512, 8), jnp.float32),
+                "s": jax.ShapeDtypeStruct((3, 8), jnp.float32)}}
+    plan = compile_plan(W, Ps, {"d": 1, "f": 0}, cfg, "io", "sharded2d",
+                        mesh)
+    lp = {x.path: x for x in plan.leaves}
+    assert {x.route for x in plan.leaves} == {"sharded2d"}
+    assert lp["d"].specs(mesh, "io", 3, Ps["d"]) == (
+        P(None, None, ("data",)), P(None, None, None, ("data",)),
+        P(None, None, None, None))
+    assert lp["f"].specs(mesh, "io", 2, Ps["f"]) == (
+        P(None, ("data",)), P(None, None, ("data",)),
+        {"U": P(None, None, None), "s": P(None, None)})
 
 
 # --------------------------------------------------------------------------

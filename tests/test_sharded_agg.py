@@ -238,30 +238,37 @@ def test_make_debug_mesh_raises_on_shortfall():
 
 
 def test_agg_partition_specs():
-    """The rules' aggregation placement specs: rows over the data
-    axes with the `_ok` divisibility fallback, QP inputs replicated —
-    congruent with the shard_map layout ops builds inline (W rows on
-    dim 0, V rows on dim 1)."""
+    """The plan's placement specs on the 1-D shard (``LeafPlan.specs``):
+    W's out-rows over the data axes — the last axis for "io" — V's the
+    same behind its client axis, projectors whole; a leaf whose out-dim
+    the executor pads, and every leaf off the mesh routes, whole."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.configs import get_config
-    from repro.sharding.rules import make_rules
+    from repro.core.plan import compile_plan
 
-    rules = make_rules(FakeMesh({"pod": 2, "data": 16, "model": 16}),
-                       get_config("llama3_8b"))
-    assert rules.agg_out_axes(4096) == ("pod", "data")
-    assert rules.agg_out_axes(100) is None
-    assert rules.agg_weight_spec((4096, 1024)) == P(("pod", "data"),
-                                                    None)
-    # non-divisible out / 1-D bias: replicated
-    assert rules.agg_weight_spec((100, 1024)) == P(None, None)
-    assert rules.agg_weight_spec((4096,)) == P(None)
-    assert rules.agg_anchor_spec((8, 4096, 1024)) == P(
-        None, ("pod", "data"), None)
-    assert rules.agg_anchor_spec((8, 4096)) == P(None, None)
-    assert rules.agg_proj_spec((8, 1024, 1024)) == P(None, None, None)
-    assert rules.agg_gram_spec() == P(None, None)
-    assert rules.agg_alpha_spec() == P(None)
+    mesh = FakeMesh({"pod": 2, "data": 16, "model": 16})
+    cfg = MAEchoConfig(mesh_axis=("pod", "data"))
+    W = {"w": jax.ShapeDtypeStruct((8192, 1024), jnp.float32),
+         "pad": jax.ShapeDtypeStruct((4000, 1024), jnp.float32),
+         "b": jax.ShapeDtypeStruct((1024,), jnp.float32)}
+    Ps = {"w": jax.ShapeDtypeStruct((8, 1024, 1024), jnp.float32),
+          "pad": jax.ShapeDtypeStruct((8, 1024), jnp.float32),
+          "b": jax.ShapeDtypeStruct((8,), jnp.float32)}
+    plan = compile_plan(W, Ps, {"w": 0, "pad": 0, "b": 0}, cfg, "oi",
+                        "sharded", mesh)
+    lp = {x.path: x for x in plan.leaves}
+    rows = ("pod", "data")
+    assert lp["w"].specs(mesh, "oi", 2, Ps["w"]) == (
+        P(rows, None), P(None, rows, None), P(None, None, None))
+    assert lp["w"].specs(mesh, "io", 3, Ps["w"]) == (
+        P(None, None, rows), P(None, None, None, rows),
+        P(None, None, None))
+    # 4000 rows pad to 32 tiles of 128: sharded, but placed whole
+    assert lp["pad"].route == "sharded"
+    assert lp["pad"].specs(mesh, "oi", 2, Ps["pad"])[0] == P(None, None)
+    assert lp["b"].route == "oracle"
+    assert lp["b"].specs(mesh, "oi", 1, Ps["b"]) == (
+        P(None), P(None, None), P(None))
 
 
 # --------------------------------------------------------------------------
@@ -386,3 +393,220 @@ def test_divisibility_fallback_8dev():
     r = _run_forced(code)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "FALLBACK_OK" in r.stdout
+
+
+# --------------------------------------------------------------------------
+# the program's own mesh and placement: aggregate_llm(backend="sharded2d")
+# with no mesh, host checkpoints placed straight into their shards
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("n,grid", [(1, (1, 1)), (2, (2, 1)), (4, (2, 2)),
+                                    (6, (3, 2)), (8, (4, 2)),
+                                    (16, (4, 4))])
+def test_mesh_grid_is_as_square_as_the_count_allows(n, grid):
+    from repro.core.maecho import mesh_grid
+
+    assert mesh_grid(n) == grid
+
+
+def _host_case(init="average"):
+    """Three host (numpy) checkpoints of a two-leaf model with a stacked
+    leaf, dense and scalar projectors: the layout a federation operator
+    hands over."""
+    rng = np.random.default_rng(5)
+    clients = [{"w": rng.standard_normal((2, 256, 128), np.float32),
+                "b": rng.standard_normal((2, 128), np.float32)}
+               for _ in range(3)]
+    projs = []
+    for _ in range(3):
+        x = rng.standard_normal((2, 256, 16), np.float32)
+        projs.append({"w": np.einsum("lik,ljk->lij", x, x) / 16,
+                      "b": np.ones((2,), np.float32)})
+    cfg = MAEchoConfig(tau=2, eta=0.5, qp_iters=40, init=init)
+    return clients, projs, cfg
+
+
+@pytest.mark.parametrize("init", ["average", "first"])
+def test_one_chip_placement_matches_stacking_on_the_host(init):
+    """With no mesh, placing each leaf and making W0 on the device gives
+    the bits of the old flow: W0 by ``init_global`` over the host
+    checkpoints, anchors and projectors stacked by ``jnp.stack``."""
+    from repro.core import maecho
+    from repro.core.plan import compile_plan
+
+    clients, projs, cfg = _host_case(init)
+    levels = {"w": 1, "b": 1}
+    got = maecho_aggregate(clients, projs, cfg, convention="io",
+                           stack_levels=levels, backend="auto")
+    W0 = maecho.init_global(clients, init)
+    V0 = jax.tree.map(lambda *xs: jnp.stack(xs), *clients)
+    P = jax.tree.map(lambda *xs: jnp.stack(xs), *projs)
+    plan = compile_plan(W0, P, levels, cfg, "io", "auto", None)
+    want, _ = maecho._maecho_jit(W0, V0, P, cfg, "io", plan)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]))
+
+
+def test_one_chip_placement_moves_each_client_once():
+    """``maecho.place_bytes`` on one chip: every host anchor and
+    projector once (W0 is made on the device, not moved again)."""
+    import time
+
+    from repro.utils import spans
+
+    clients, projs, cfg = _host_case()
+    t0 = time.perf_counter_ns()
+    maecho_aggregate(clients, projs, cfg, convention="io",
+                     stack_levels={"w": 1, "b": 1}, backend="auto")
+    recs = [r for r in spans.records(t0) if r.name == "maecho.place_bytes"]
+    host = sum(x.nbytes for t in clients + projs for x in t.values())
+    assert [(r.attrs["device"], r.attrs["n"]) for r in recs] == [
+        (jax.devices()[0].id, host)]
+    routes = {r.attrs["route"]: r.attrs["n"] for r in spans.records(t0)
+              if r.name == "maecho.route"}
+    assert routes == {"stacked": 1, "oracle": 1}
+
+
+_MESH4 = textwrap.dedent("""
+    import json
+    import re
+    import time
+
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.core import maecho
+    from repro.core.maecho import MAEchoConfig
+    from repro.core.plan import compile_plan
+    from repro.fl.llm_adapter import (aggregate_llm, build_projections,
+                                      stack_levels_fn)
+    from repro.models.zoo import get_model
+    from repro.utils import spans, trees
+
+    assert len(jax.devices()) == 4, jax.devices()
+    # Qwen2-shaped, every tile count even: d_model 256 (2 tiles), 2 KV
+    # heads of 128 (256), d_ff 512 (4 tiles); the vocabulary's 3 tiles
+    # cannot split over "model", as 151936 (1187 tiles) cannot
+    cfg = get_config("qwen2-1.5b").replace(
+        n_layers=2, d_model=256, n_heads=2, n_kv_heads=2, head_dim=128,
+        d_ff=512, vocab=384)
+    model = get_model(cfg)
+    base = model.init_params(jax.random.PRNGKey(0))
+    clients, projs = [], []
+    for i in range(2):
+        keys = jax.random.split(jax.random.PRNGKey(1 + i),
+                                len(jax.tree.leaves(base)))
+        c = jax.tree.unflatten(jax.tree.structure(base), [
+            x + 1e-2 * jax.random.normal(k, x.shape, x.dtype)
+            for x, k in zip(jax.tree.leaves(base), keys)])
+        rng = np.random.default_rng(i)
+        probe = [{"tokens": rng.integers(0, cfg.vocab, (2, 64))}]
+        projs.append(jax.device_get(build_projections(cfg, c, probe)))
+        clients.append(jax.device_get(c))
+    macfg = MAEchoConfig(tau=2, eta=0.5, qp_iters=40)
+
+    t0 = time.perf_counter_ns()
+    got = aggregate_llm(cfg, clients, projs, macfg, backend="sharded2d")
+    recs = spans.records(t0)
+    want = aggregate_llm(cfg, clients, projs, macfg, backend="oracle")
+    rel = {p: float(np.max(np.abs(np.asarray(a) - np.asarray(b)))
+                    / np.max(np.abs(np.asarray(b))))
+           for (p, a), (_, b) in zip(trees.tree_paths(got),
+                                     trees.tree_paths(want))}
+
+    # the executor as the aggregate compiled it, on the operands as
+    # placed: which collectives it holds
+    lv = stack_levels_fn(cfg)
+    levels = trees.map_with_path(lambda p, _: lv(p), clients[0])
+    mesh = maecho._default_mesh(macfg.mesh_axis, macfg.mesh_in_axis)
+    P = jax.tree.map(lambda *xs: np.stack(xs), *projs)
+    plan = compile_plan(clients[0], P, levels, macfg, "io", "sharded2d",
+                        mesh)
+    W0, V0, P = maecho.place(clients, projs, plan, mesh)
+    text = maecho._maecho_jit.lower(W0, V0, P, macfg, "io", plan,
+                                    mesh).compile().as_text()
+    colls = [m.group(1) for m in re.finditer(
+        r" (all-reduce|all-gather|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?[(]", text)]
+    leaves = dict(trees.tree_paths(clients[0]))
+    print("RESULT " + json.dumps({
+        "mesh": dict(got["layers"]["wq"].sharding.mesh.shape),
+        "specs": {p: str(x.sharding.spec) for p, x in
+                  trees.tree_paths(got)},
+        "routes": {r.attrs["route"]: r.attrs["n"] for r in recs
+                   if r.name == "maecho.route"},
+        "placed": {str(r.attrs["device"]): r.attrs["n"] for r in recs
+                   if r.name == "maecho.place_bytes"},
+        "leaves": {p: [list(x.shape), x.nbytes] for p, x in leaves.items()},
+        "levels": dict(trees.tree_paths(levels)),
+        "proj_bytes": sum(x.nbytes for t in projs
+                          for _, x in trees.tree_paths(t)),
+        "rel": rel, "collectives": colls}))
+""")
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    """One forced-four-device run of the program's own mesh path."""
+    r = _run_forced(_MESH4, n_devices=4)
+    assert r.returncode == 0, r.stdout + r.stderr
+    line = next(ln for ln in r.stdout.splitlines()
+                if ln.startswith("RESULT "))
+    import json
+
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.slow
+def test_sharded2d_without_a_mesh_builds_two_by_two(mesh4):
+    assert mesh4["mesh"] == {"data": 2, "model": 2}
+    # the merged weights come back in the layout they were placed in:
+    # out-rows ("io": the last axis) over "data", whole along "model"
+    assert mesh4["specs"]["layers.wq"] == "PartitionSpec(None, None, 'data')"
+    assert mesh4["specs"]["embed"] == "PartitionSpec(None, 'data')"
+
+
+@pytest.mark.slow
+def test_sharded2d_sends_every_matrix_leaf_down_a_mesh_route(mesh4):
+    matrices = [p for p, (shape, _) in mesh4["leaves"].items()
+                if len(shape) - mesh4["levels"][p] == 2]
+    assert len(matrices) == 8
+    assert mesh4["routes"] == {"sharded2d": 7, "sharded": 1, "oracle": 6}
+
+
+@pytest.mark.slow
+def test_sharded2d_sends_each_host_byte_once(mesh4):
+    """The four devices receive equal parts of the checkpoints and
+    projectors from the host, together the host's bytes, and nothing
+    twice but the few leaves too small to split (the final norm, the
+    scalar projectors: under 1 %).  A device holding a whole stack, or
+    the row shards sent to both devices along "model", would break it."""
+    n = 2
+    host = n * sum(b for _, b in mesh4["leaves"].values()) \
+        + mesh4["proj_bytes"]
+    placed = mesh4["placed"]
+    assert sorted(placed) == ["0", "1", "2", "3"]
+    assert len(set(placed.values())) == 1
+    assert host <= sum(placed.values()) < 1.01 * host
+
+
+@pytest.mark.slow
+def test_sharded2d_merge_matches_the_oracle(mesh4):
+    """Against the jnp oracle on one device.  The sharded Grams are sums
+    of four partial contractions, the oracle's one: the fp32 rounding
+    differs in the last bits (observed ≤ 5e-7 of the largest weight),
+    which the QP and τ = 2 steps carry without amplifying; 1e-5 leaves
+    that room twenty times over and still catches a dropped shard or a
+    wrong α (errors of order 1e-2)."""
+    worst = max(mesh4["rel"], key=mesh4["rel"].get)
+    assert mesh4["rel"][worst] < 1e-5, (worst, mesh4["rel"][worst])
+
+
+@pytest.mark.slow
+def test_sharded2d_step_reduces_grams_only(mesh4):
+    """The compiled step moves no anchor between devices: its only
+    collectives are all-reduces (the per-leaf Gram psums)."""
+    assert mesh4["collectives"]
+    assert set(mesh4["collectives"]) == {"all-reduce"}, \
+        mesh4["collectives"]

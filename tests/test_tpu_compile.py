@@ -183,3 +183,82 @@ def test_flash_attention_compiles(one_chip, grad):
         lambda q, k, v: fwd(q, k, v).astype(F32).sum(), argnums=(0, 1, 2))
     _compile(fn, one_chip, ((B, S, HQ, HD), bf), ((B, S, HKV, HD), bf),
              ((B, S, HKV, HD), bf))
+
+
+def test_sharded2d_step_compiles_for_the_2x2_mesh(topo, one_chip,
+                                                  monkeypatch):
+    """The sharded2d executor for four described v5e chips on the mesh
+    the program derives from four devices, at Qwen2-shaped smoke widths
+    whose tiles split 2 × 2, its operands in the layout ``place`` puts
+    them in (``LeafPlan.specs``): it fits a chip, and its collectives
+    are the Gram all-reduces alone — no gather or exchange of anchors."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.configs import get_config
+    from repro.core import maecho
+    from repro.core.plan import compile_plan
+    from repro.fl.llm_adapter import stack_levels_fn
+    from repro.kernels import env, ops
+    from repro.models.zoo import get_model
+    from repro.utils import trees
+
+    monkeypatch.setattr(env, "interpret_default", lambda: False)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    cfg = get_config("qwen2-1.5b").replace(
+        n_layers=2, d_model=256, n_heads=2, n_kv_heads=2, head_dim=128,
+        d_ff=512, vocab=384)
+    n = 2
+    macfg = maecho.MAEchoConfig(tau=20, eta=0.5, qp_iters=200)
+    shapes = jax.eval_shape(get_model(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    full = ("layers.wq", "layers.wk", "layers.wv", "layers.w_gate",
+            "layers.w_up")
+    lv = stack_levels_fn(cfg)
+
+    def proj(path, w):
+        if path in full:
+            return (n,) + w.shape[:-1] + w.shape[-2:-1]
+        if path == "embed":
+            return (n, w.shape[0])
+        return (n,) + w.shape[:lv(path)]
+
+    P = trees.map_with_path(lambda path, w: jax.ShapeDtypeStruct(
+        proj(path, w), F32), shapes)
+    levels = trees.map_with_path(lambda path, _: lv(path), shapes)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(maecho.mesh_grid(4)),
+                ("data", "model"))
+    plan = compile_plan(shapes, P, levels, macfg, "io", "sharded2d", mesh)
+    assert plan.route_counts() == {"sharded2d": 7, "sharded": 1,
+                                   "oracle": 6}
+    W0, V0, Ps = [], [], []
+    for w, p, lp in zip(jax.tree.leaves(shapes), jax.tree.leaves(P),
+                        plan.leaves):
+        ws, vs, ps = lp.specs(mesh, "io", w.ndim, p)
+        W0.append(jax.ShapeDtypeStruct(w.shape, w.dtype,
+                                       sharding=NamedSharding(mesh, ws)))
+        V0.append(jax.ShapeDtypeStruct((n,) + w.shape, w.dtype,
+                                       sharding=NamedSharding(mesh, vs)))
+        Ps.append(jax.ShapeDtypeStruct(p.shape, p.dtype,
+                                       sharding=NamedSharding(mesh, ps)))
+    tdef = jax.tree.structure(shapes)
+    W0, V0, Ps = (jax.tree.unflatten(tdef, x) for x in (W0, V0, Ps))
+    compiled = maecho._maecho_jit.lower(W0, V0, Ps, macfg, "io", plan,
+                                        mesh, None).compile()
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert per_chip < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    ops_ = re.findall(r"= (.+?) (all-reduce|all-gather|all-to-all|"
+                      r"collective-permute|reduce-scatter)(?:-start)?\(",
+                      text)
+    assert ops_ and {op for _, op in ops_} == {"all-reduce"}, ops_
+    # each reduces Grams: (L, N, N) or (N, N), alone or in a tuple
+    grams = {f"f32[{cfg.n_layers},{n},{n}]", f"f32[{n},{n}]"}
+    for result, _ in ops_:
+        shapes_ = re.findall(r"f32\[[\d,]*\]", result)
+        assert shapes_ and set(shapes_) <= grams, result

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models.config import ModelConfig
+from repro.utils import spans
 
 
 # --------------------------------------------------------------------------
@@ -77,19 +78,46 @@ def init_params(cfg: ModelConfig, rng):
 # --------------------------------------------------------------------------
 # per-layer blocks (operate on the scanned per-layer param slice ``lp``)
 # --------------------------------------------------------------------------
+def layer_weight(w, dtype):
+    """One layer's weight slice ``w`` in the compute ``dtype``, rounded
+    where the matmul that consumes it reads it.
+
+    A plain ``astype`` narrowing of a scanned layer slice lets the TPU
+    compiler move the convert above the scan's slice onto the whole
+    stacked parameter and hoist it out of the loop: every program then
+    casts every stack of weights before its first layer, and holds the
+    narrowed copy as a temporary.  For float32 weights and bfloat16
+    compute this rounds the float32 values to bfloat16's precision
+    first (``reduce_precision``, round to nearest even, NaN kept NaN):
+    the convert that follows is exact and stays in the matmul's fusion.
+    Values and derivative equal ``astype``'s bit for bit.  Any other
+    pair of dtypes is the plain ``astype``.
+
+    Counts ``model.weight_round`` at trace time, ``path="in_layer"`` for
+    the rounding in the layer and ``"astype"`` for the plain cast.
+    """
+    if w.dtype == jnp.float32 and jnp.dtype(dtype) == jnp.bfloat16:
+        spans.count("model.weight_round", path="in_layer")
+        # bfloat16 keeps float32's 8 exponent bits and 7 of its mantissa
+        w = jax.lax.reduce_precision(w, exponent_bits=8, mantissa_bits=7)
+    else:
+        spans.count("model.weight_round", path="astype")
+    return w.astype(dtype)
+
+
 def _qkv(lp, x, cfg: ModelConfig):
     B, S, _ = x.shape
     hd = cfg.hd()
 
     def proj(w, b):
         if not cfg.qkv_bias:
-            return x @ lp[w].astype(cfg.cdtype)
+            return x @ layer_weight(lp[w], cfg.cdtype)
         # the bias is added to the fp32 product and rounded once: with
         # a bf16 product plus a bf16 bias the TPU compiler drops the
         # inner rounding in some fusions and not in others, by batch
         # shape, and a batch-1 prefill then writes other K/V than the
         # same prompt in a bigger batch
-        y = jnp.matmul(x, lp[w].astype(cfg.cdtype),
+        y = jnp.matmul(x, layer_weight(lp[w], cfg.cdtype),
                        preferred_element_type=jnp.float32)
         return (y + lp[b].astype(jnp.float32)).astype(cfg.cdtype)
 
@@ -116,7 +144,8 @@ def attn_block(lp, x, positions, cfg: ModelConfig, *, causal=True):
                             q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
                             unroll=cfg.unroll_layers,
                             backend=cfg.attn_backend)
-    o = o.reshape(B, S, cfg.n_heads * cfg.hd()) @ lp["wo"].astype(cfg.cdtype)
+    o = o.reshape(B, S, cfg.n_heads * cfg.hd()) @ \
+        layer_weight(lp["wo"], cfg.cdtype)
     if cfg.seq_shard and shard_ctx.active():
         o = shard_ctx.constrain_seq(o)
     return o
@@ -145,14 +174,15 @@ def attn_block_decode(lp, x, cache, position, cfg: ModelConfig, *,
     o = L.decode_attention(q, cache["k"], cache["v"], valid,
                            backend=cfg.attn_backend, w_live=w_live,
                            layer=layer)
-    y = o.reshape(B, 1, cfg.n_heads * cfg.hd()) @ lp["wo"].astype(cfg.cdtype)
+    y = o.reshape(B, 1, cfg.n_heads * cfg.hd()) @ \
+        layer_weight(lp["wo"], cfg.cdtype)
     return y, cache
 
 
 def mlp_block(lp, x, cfg: ModelConfig):
-    return L.swiglu(x, lp["w_gate"].astype(cfg.cdtype),
-                    lp["w_up"].astype(cfg.cdtype),
-                    lp["w_down"].astype(cfg.cdtype))
+    return L.swiglu(x, layer_weight(lp["w_gate"], cfg.cdtype),
+                    layer_weight(lp["w_up"], cfg.cdtype),
+                    layer_weight(lp["w_down"], cfg.cdtype))
 
 
 def layer_fn(lp, x, positions, cfg: ModelConfig):
@@ -176,7 +206,7 @@ def layer_fn_decode(lp, x, cache, position, cfg: ModelConfig, *,
 # --------------------------------------------------------------------------
 def embed_inputs(cfg: ModelConfig, params, batch):
     """Token (+ optional patch) embedding.  Returns (x, positions)."""
-    tok = params["embed"].astype(cfg.cdtype)[batch["tokens"]]
+    tok = params["embed"][batch["tokens"]].astype(cfg.cdtype)
     if cfg.family == "vlm" and "patch_embeds" in batch:
         pe = batch["patch_embeds"].astype(cfg.cdtype) @ \
             params["vision_proj"].astype(cfg.cdtype)
@@ -238,7 +268,7 @@ def prefill(cfg: ModelConfig, params, batch, mlp_fn=None):
                                 unroll=cfg.unroll_layers,
                                 backend=cfg.attn_backend)
         a = o.reshape(B, S, cfg.n_heads * cfg.hd()) @ \
-            lp["wo"].astype(cfg.cdtype)
+            layer_weight(lp["wo"], cfg.cdtype)
         h = x + a
         fn = mlp_fn or (lambda lp, y: mlp_block(lp, y, cfg))
         h = h + fn(lp, L.rms_norm(h, lp["ln2"], cfg.norm_eps))
@@ -290,7 +320,7 @@ def decode_step(cfg: ModelConfig, params, cache, token, position,
     """
     from repro.sharding import ctx as shard_ctx
 
-    x = params["embed"].astype(cfg.cdtype)[token]
+    x = params["embed"][token].astype(cfg.cdtype)
     position = jnp.asarray(position, jnp.int32)
     fn = mlp_fn or (lambda lp, y: mlp_block(lp, y, cfg))
     unroll = cfg.n_layers if cfg.unroll_layers else 1

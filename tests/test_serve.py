@@ -99,6 +99,124 @@ def test_qkv_bias_rounds_once():
             np.asarray(want, np.float32))
 
 
+def _bits(x):
+    return np.asarray(x).view(np.uint16)
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+def test_layer_weight_rounds_like_astype(jit):
+    """fp32 -> bf16 in the layer: bit for bit ``astype`` on random bit
+    patterns over the whole exponent range and on the edge cases; NaN
+    stays NaN."""
+    from repro.models.dense import layer_weight
+
+    rng = np.random.default_rng(0)
+    f32 = np.finfo(np.float32)
+    edges = np.array([
+        0.0, -0.0, np.inf, -np.inf, f32.max, -f32.max,   # max rounds to inf
+        f32.smallest_subnormal, -f32.smallest_subnormal, 1e-40, -3e-39,
+        f32.tiny, 1e-38, 1e38, -1e38,
+    ], np.float32)
+    ties = np.array([0x3F808000, 0x3F818000, 0xBF808000, 0xBF818000,
+                     0x00008000, 0x00018000, 0x7F7F8000, 0x7F7E8000],
+                    np.uint32).view(np.float32)          # halfway: to even
+    w = np.concatenate([
+        rng.integers(0, 2**32, 2**20, dtype=np.uint32).view(np.float32),
+        edges, ties])
+    round_ = jax.jit(layer_weight, static_argnums=1) if jit else layer_weight
+    cast = jax.jit(lambda x: x.astype(jnp.bfloat16)) if jit else (
+        lambda x: x.astype(jnp.bfloat16))
+    got, want = round_(jnp.asarray(w), jnp.bfloat16), cast(jnp.asarray(w))
+    assert got.dtype == jnp.bfloat16
+    nan = np.isnan(w)
+    assert nan.any() and np.isnan(np.asarray(got, np.float32)[nan]).all()
+    np.testing.assert_array_equal(_bits(got)[~nan], _bits(want)[~nan])
+    top = np.asarray(got, np.float32)[np.abs(w) == f32.max]
+    assert np.isinf(top).all() and len(top) == 2
+    # any other pair of dtypes is the plain cast
+    for x, dtype in ((w[:64], jnp.float32), (want[:64], jnp.float32),
+                     (want[:64], jnp.bfloat16)):
+        np.testing.assert_array_equal(
+            np.asarray(layer_weight(jnp.asarray(x), dtype), np.float32),
+            np.asarray(jnp.asarray(x).astype(dtype), np.float32))
+
+
+def _astype_weight(w, dtype):
+    return w.astype(dtype)
+
+
+def _layer_params(cfg, seed):
+    d, f, hd = cfg.d_model, cfg.d_ff, cfg.hd()
+    shapes = {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+              "wv": (d, cfg.n_kv_heads * hd), "w_gate": (d, f),
+              "w_up": (d, f), "w_down": (f, d)}
+    shapes.update({"b" + n[1]: (shapes[n][1],) for n in ("wq", "wk", "wv")})
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return {n: jax.random.normal(k, s) / np.sqrt(s[0])
+            for (n, s), k in zip(shapes.items(), keys)}
+
+
+@pytest.mark.parametrize("block", ["mlp_block", "_qkv"])
+def test_layer_weight_grads_match_astype(block, monkeypatch):
+    """``jax.grad`` through the dense blocks with fp32 weights and bf16
+    compute equals the gradient with a plain ``astype``, bit for bit,
+    and is not zero: a rounding written without a derivative (say, on
+    the integer bits) would hand the weights zeros silently."""
+    from repro.configs import get_smoke_config
+    from repro.models import dense
+
+    cfg = get_smoke_config("qwen2-0.5b").replace(compute_dtype="bfloat16")
+    lp = _layer_params(cfg, 5)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 5, cfg.d_model))
+    fn = getattr(dense, block)
+
+    def loss(lp, x):
+        out = fn(lp, x.astype(cfg.cdtype), cfg)
+        return sum(jnp.sum(jnp.sin(o.astype(jnp.float32)))
+                   for o in jax.tree.leaves(out))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    got = grad(lp, x)
+    monkeypatch.setattr(dense, "layer_weight", _astype_weight)
+    want = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    weights = "wq" if block == "_qkv" else "w_down"
+    assert np.abs(np.asarray(got[0][weights])).max() > 0
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode_step"])
+def test_serving_logits_match_astype(step, monkeypatch):
+    """prefill and decode logits with fp32 weights and bf16 compute are
+    bit for bit those of the same blocks with a plain ``astype``."""
+    from repro.configs import get_smoke_config
+    from repro.models import dense
+    from repro.models.zoo import get_model
+
+    cfg = get_smoke_config("qwen2-0.5b").replace(compute_dtype="bfloat16")
+    assert cfg.pdtype == jnp.float32
+    model = get_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    B, P, W = 3, 12, 128
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, P), 0, cfg.vocab)
+    cache = _random_cache(model, B, W, 2)
+    position = jnp.asarray([3, 40, W + 9], jnp.int32)
+
+    def logits():
+        if step == "prefill":
+            fn = jax.jit(lambda p, t: model.prefill(p, {"tokens": t})[0])
+            return fn(params, tokens)
+        fn = jax.jit(lambda p, c, t, pos: model.decode_step(p, c, t, pos)[0])
+        return fn(params, cache, tokens[:, :1], position)
+
+    got = logits()
+    monkeypatch.setattr(dense, "layer_weight", _astype_weight)
+    want = logits()
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.slow
 def test_arrival_matches_fixed_batch_tokens():
     """Per-request tokens from the slot loop == the fixed-batch run,

@@ -182,6 +182,29 @@ def test_kv_write_counter_names_the_write_path():
         assert paths(per_row) == ["select"]
 
 
+def test_weight_round_counter_names_the_rounding_path():
+    """``model.weight_round`` counts, per trace, each layer weight the
+    dense blocks take into the compute dtype: rounded in the layer for
+    fp32 weights and bf16 compute, the plain cast otherwise."""
+    from repro.configs import get_smoke_config
+    from repro.models.zoo import get_model
+
+    def paths(cfg):
+        model = get_model(cfg)
+        B, W = 2, 128
+        t0 = time.perf_counter_ns()
+        jax.eval_shape(model.decode_step, model.param_specs(),
+                       model.cache_specs(B, W),
+                       jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                       jax.ShapeDtypeStruct((B,), jnp.int32))
+        return [r.attrs["path"] for r in _since(t0, "model.weight_round")]
+
+    cfg = get_smoke_config("qwen2-0.5b")
+    # wq, wk, wv, wo and the three MLP weights, once in the layer scan
+    assert paths(cfg.replace(compute_dtype="bfloat16")) == ["in_layer"] * 7
+    assert paths(cfg) == ["astype"] * 7
+
+
 def test_spans_are_host_events_of_a_profile():
     from jax._src.lib import _profiler
     from jax.profiler import ProfileData

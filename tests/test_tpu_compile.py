@@ -168,6 +168,53 @@ def test_serve_step_writes_the_cache_in_place(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < cache_bytes // cfg.n_layers
 
 
+@pytest.mark.parametrize("program", ["serve_step", "prefill1"])
+def test_serving_programs_round_weights_in_layer(one_chip, monkeypatch,
+                                                 program):
+    """The slot loop's serve step (64 slots, the serving window) and
+    batch-1 prefill (a 1024-token prompt) at qwen2-0.5b widths, fp32
+    weights and bf16 compute: no convert narrows a whole layer stack or
+    the embedding table to bf16 ahead of the layer scan, and the
+    temporaries stay below one layer's fp32 weights."""
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels import env, ops
+    from repro.launch.serve import slot_fns
+    from repro.models.zoo import get_model
+
+    monkeypatch.setattr(env, "interpret_default", lambda: False)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    cfg = get_config("qwen2-0.5b").replace(param_dtype="float32",
+                                           compute_dtype="bfloat16")
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (L, D, VOCAB)
+    model = get_model(cfg)
+    on_chip = functools.partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip))
+    params = on_chip(model.param_specs())
+    prefill1, _, serve_step = slot_fns(model)
+    if program == "serve_step":
+        token, position = on_chip((
+            jax.ShapeDtypeStruct((SERVE_B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((SERVE_B,), jnp.int32)))
+        lowered = serve_step.lower(
+            params, on_chip(model.cache_specs(SERVE_B, SERVE_W)), token,
+            position, w_live=SERVE_W)
+    else:
+        lowered = prefill1.lower(params, on_chip(
+            {"tokens": jax.ShapeDtypeStruct((1, 1024), jnp.int32)}))
+    compiled = lowered.compile()
+    to_bf16 = r"= bf16\[([\d,]+)\]\{[^}]*\} convert\("
+    narrowed = [tuple(int(n) for n in dims.split(","))
+                for dims in re.findall(to_bf16, compiled.as_text())]
+    assert narrowed
+    stacks = [s for s in narrowed if len(s) >= 3 and s[0] == L]
+    assert not stacks and (VOCAB, D) not in narrowed, stacks
+    layer_bytes = sum(w.size * w.dtype.itemsize for w in
+                      jax.tree.leaves(params["layers"])) // L
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 def test_flash_attention_compiles(one_chip, grad):
     B, S = 4, 128

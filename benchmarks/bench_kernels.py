@@ -13,6 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row, timed
+from repro.kernels import maecho_gram as mg
+from repro.kernels import maecho_update as mu
+from repro.kernels import maecho_v_update as mv
 from repro.kernels import ops, ref
 from repro.kernels.env import interpret_default
 
@@ -30,7 +33,9 @@ def run(quick: bool = False):
     fn = jax.jit(lambda: ref.maecho_update_ref(W, V, P, alpha, 0.5))
     fn()
     _, us = timed(fn)
-    got = ops.maecho_update(W, V, P, alpha, eta=0.5)
+    # the kernels take a stack of layers: this leaf is the stack L = 1
+    W1, V1, P1 = W[None], V[:, None], P[:, None]
+    got = mu.maecho_update_stacked(W1, V1, P1, alpha[None], eta=0.5)[0]
     ok = np.allclose(np.asarray(got),
                      np.asarray(ref.maecho_update_ref(W, V, P, alpha,
                                                       0.5)), atol=1e-3)
@@ -40,14 +45,15 @@ def run(quick: bool = False):
     fn = jax.jit(lambda: ref.maecho_gram_ref(W, V, P))
     fn()
     _, us = timed(fn)
-    ok = np.allclose(np.asarray(ops.maecho_gram(W, V, P)),
+    ok = np.allclose(np.asarray(mg.maecho_gram_stacked(W1, V1, P1)[0]),
                      np.asarray(fn()), atol=1e-2, rtol=1e-4)
     row("kernels/maecho_gram_512x512_N5", us, f"allclose={ok} interpret={interp}")
 
     fn = jax.jit(lambda: ref.maecho_v_update_ref(W, V, P, 0.5))
     fn()
     _, us = timed(fn)
-    ok = np.allclose(np.asarray(ops.maecho_v_update(W, V, P, frac=0.5)),
+    ok = np.allclose(np.asarray(mv.maecho_v_update_stacked(
+                         W1, V1, P1, frac=0.5)[:, 0]),
                      np.asarray(fn()), atol=1e-3)
     row("kernels/maecho_v_update_512x512_N5", us, f"allclose={ok} interpret={interp}")
 

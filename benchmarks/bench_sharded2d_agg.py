@@ -2,8 +2,9 @@
 
 Times the sharded2d MA-Echo pipeline over factored host-device grids
 (1x1 / 2x1 / 2x2 / 2x4): the two-axis Gram phase alone
-(``ops.maecho_sharded2d_gram`` — per-device residual *tile* + partial
-contraction + ONE psum over both axis groups) and a full
+(``ops.maecho_sharded2d_gram_stacked`` on the stack L = 1 —
+per-device residual *tile* + partial contraction + ONE psum over both
+axis groups) and a full
 ``maecho_aggregate`` with ``backend="sharded2d"``.  A "thin" row times
 the fleet-spanning case the 2-D shard exists for: a leaf whose
 out-dim tile count cannot divide the full device count 1-D
@@ -65,8 +66,9 @@ def best_of(fn, reps=3):
     return out, best * 1e6
 
 
-gram = jax.jit(lambda W, V, P: ops.maecho_sharded2d_gram(
-    W, V, P, mesh=mesh, axis_out="data", axis_in="model")[0])
+gram = jax.jit(lambda W, V, P: ops.maecho_sharded2d_gram_stacked(
+    W[None], V[:, None], P[:, None], mesh=mesh, axis_out="data",
+    axis_in="model")[0][0])
 G, gram_us = best_of(lambda: gram(W, V, P))
 # parity against a float64 numpy reference: at in_d >= 1024 the fp32
 # jnp oracle's own single-pass accumulation error exceeds 1e-3, while

@@ -1,11 +1,12 @@
 """Mesh-sharded aggregation scaling (ISSUE 3 tentpole).
 
 Times the out-dim-sharded MA-Echo pipeline at 1/2/4/8 host devices:
-the Gram phase alone (``ops.maecho_sharded_gram`` — residual tiles +
-partial contraction + one psum) and a full ``maecho_aggregate`` with
-``backend="sharded"``.  The forced host-device count must be fixed
-before jax initializes, so every device count runs in its own
-subprocess; the parent collects one JSON line per child.
+the Gram phase alone (``ops.maecho_sharded_gram_stacked`` on the
+stack L = 1 — residual tiles + partial contraction + one psum) and a
+full ``maecho_aggregate`` with ``backend="sharded"``.  The forced
+host-device count must be fixed before jax initializes, so every
+device count runs in its own subprocess; the parent collects one JSON
+line per child.
 
 On this CPU container the "devices" share one socket, so the curve
 records interpret-mode *overhead* scaling, not the TPU speedup — the
@@ -60,8 +61,8 @@ def best_of(fn, reps=3):
     return out, best * 1e6
 
 
-gram = jax.jit(lambda W, V, P: ops.maecho_sharded_gram(
-    W, V, P, mesh=mesh, axis="data")[0])
+gram = jax.jit(lambda W, V, P: ops.maecho_sharded_gram_stacked(
+    W[None], V[:, None], P[:, None], mesh=mesh, axis="data")[0][0])
 G, gram_us = best_of(lambda: gram(W, V, P))
 Gr = ref.maecho_gram_ref(W, V, P)
 rel = float(jnp.max(jnp.abs(G - Gr)) / jnp.max(jnp.abs(Gr)))

@@ -173,7 +173,7 @@ def main_path(cfg, seed: int) -> dict:
     per_leaf, W0 = _coverage(cfg, silos, projs, macfg, "auto")
     weights = _weight_leaves(per_leaf, W0)
     off = [(p, r) for p, _, r in per_leaf
-           if p in weights and r not in ("kernel", "stacked")]
+           if p in weights and r != "kernel"]
     if off:
         raise RuntimeError(f"weight leaves off the kernel routes: {off}")
     merged = aggregate_llm(cfg, silos, projs, macfg, backend="auto")

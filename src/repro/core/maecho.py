@@ -17,7 +17,7 @@ Projection leaves may be:
     tables where the input space is the one-hot vocabulary — P is the
     client's token-support indicator);
   - scalar 1.0: full-rank "input is always live" projector, the bias /
-    norm-parameter rule (DESIGN.md §4);
+    norm-parameter rule;
   - any of the above with a leading stacked-layer axis L, matching a
     weight leaf (L, …) — the scan-over-layers LLM layout.  The QP is
     then solved per scanned layer (vmap), exactly like the paper's
@@ -33,37 +33,36 @@ Backends — the ``backend`` argument of :func:`maecho_aggregate`:
     Rᵢ = (W − Vᵢ)Pᵢ twice (once for the Eq. 6/7 Gram+update, once
     re-projected for Eq. 11) — 2·N·out·in fp32 of HBM traffic per
     layer per iteration that exists only to be contracted away.
-  - ``"kernel"``: the fused streaming pipeline.  Eligible leaves (2-D
-    weights, with or without leading stacked-layer axes) run three
+  - ``"kernel"``: the fused streaming pipeline.  Eligible leaves (a
+    2-D weight, with or without leading stacked-layer axes) run three
     Pallas passes per iteration — ``maecho_gram`` (Eq. 6 Gram,
     residual tiles formed in VMEM and contracted on the fly),
     ``maecho_update`` (Eq. 7) and ``maecho_v_update`` (Eq. 11) — so
-    no residual tensor is ever resident in HBM.  A stacked leaf's
-    layer axes are flattened into the kernel grid's outermost
-    dimension (one launch per pass covers all L scanned layers — the
-    ``*_stacked`` kernels); factored ``{"U", "s"}`` projectors stay
-    factored through the compute: the (N, [L,] out, k) compressed
-    residual replaces the full one and every GEMM chain drops from
-    O(out·in²) to O(out·in·k).  Ineligible leaves (1-D biases, shapes
-    below one tile) fall back to the oracle — dispatch happens at
-    trace time, the whole τ-loop still jits as one program, and the
-    fallback is surfaced once via ``ops.fallback_warn``.
+    no residual tensor is ever resident in HBM.  Every leaf is a stack
+    of L layers to the kernels: a stacked leaf's layer axes are
+    flattened into the kernel grid's outermost dimension (one launch
+    per pass covers all L scanned layers — the ``*_stacked`` kernels),
+    and a 2-D leaf is the stack L = 1.  Factored ``{"U", "s"}``
+    projectors stay factored through the compute: the
+    (N, L, out, k) compressed residual replaces the full one and every
+    GEMM chain drops from O(out·in²) to O(out·in·k).  Ineligible
+    leaves (1-D biases, shapes below one tile) fall back to the oracle
+    — dispatch happens at trace time, the whole τ-loop still jits as
+    one program, and the fallback is surfaced once via
+    ``ops.fallback_warn``.
   - ``"auto"``: ``"kernel"`` for leaves big enough to tile
     (min trailing dim ≥ 128), ``"oracle"`` otherwise.
-  - ``"sharded"``: the mesh-sharded pipeline.  Eligible leaves (2-D
-    weights, stacked or not, out-dim tile count divisible by the
-    mesh-axis size — ``ops.sharded_ok``) run the streaming gram/apply
-    under ``shard_map`` over ``MAEchoConfig.mesh_axis``: each device
-    owns an out-row shard, forms only its residual tiles, and ONE
-    ``psum`` per leaf per outer iteration reconstructs the Gram —
-    (N, N), or the whole (L, N, N) stack for a stacked leaf whose
-    layer axis rides the grid; the stacked QP solve stays global and
-    the Eq. 7/11 applies run purely on the owned rows
-    (compressed-residual reuse intact).  Ineligible leaves degrade to
-    the single-device ``"auto"`` dispatch.  Pass the mesh via
-    ``maecho_aggregate(..., mesh=...)`` (default: a 1-D mesh over
-    every visible device).
-  - ``"sharded2d"``: the 2-D (out × in) mesh-sharded pipeline.
+  - ``"sharded"``: the same pipeline under ``shard_map`` over
+    ``MAEchoConfig.mesh_axis``.  Eligible leaves (out-dim tile count
+    divisible by the mesh-axis size — ``ops.sharded_ok``) split their
+    out-rows: each device forms only its residual tiles, and ONE
+    ``psum`` per leaf per outer iteration reconstructs the (L, N, N)
+    Gram stack; the QP solve stays global and the Eq. 7/11 applies
+    run purely on the owned rows (compressed-residual reuse intact).
+    Ineligible leaves degrade to the single-device ``"auto"``
+    dispatch.  Pass the mesh via ``maecho_aggregate(..., mesh=...)``
+    (default: a 1-D mesh over every visible device).
+  - ``"sharded2d"``: the 2-D (out × in) shard of the same pipeline.
     Eligible leaves (``rules.sharded_ok2d`` — BOTH trailing dims'
     tile counts divide their axis group) split out-rows over
     ``MAEchoConfig.mesh_axis`` AND in-columns over
@@ -178,11 +177,10 @@ def _apply_P(delta, P, convention: str):
 
     P kinds: scalar (bias rule), 1-D diag (embedding token support),
     2-D full matrix, or FACTORED {"U": (in, k), "s": (k,)} with
-    P = U·diag(s)·Uᵀ — the beyond-paper optimisation (EXPERIMENTS.md
-    §Perf H3): the Eq. 7 GEMM chain drops from O(out·in²) to
-    O(out·in·k), and communication from in² to in·(k+1) (paper Table 6
-    shows the projectors are low-rank; we keep them factored through
-    the *compute*, not just the wire).
+    P = U·diag(s)·Uᵀ — the beyond-paper optimisation: the Eq. 7 GEMM
+    chain drops from O(out·in²) to O(out·in·k), and communication from
+    in² to in·(k+1) (paper Table 6 shows the projectors are low-rank;
+    we keep them factored through the *compute*, not just the wire).
     """
     if isinstance(P, dict):                 # factored projector
         U = P["U"]
@@ -219,9 +217,10 @@ def _qp_alpha(G, cfg: MAEchoConfig, mask=None):
 
 def _flatten_stack(W, V, P, levels: int):
     """Collapse ``levels`` leading stacked-layer axes into one flat L
-    axis for the stacked kernel grid.  Returns ``(Wf, Vf, Pf, lead)``
-    with Wf (L, out, in), Vf (N, L, out, in), Pf stacked per kind, and
-    ``lead`` the original leading shape for un-flattening."""
+    axis for the kernel grid (``levels`` 0 gives L = 1, ``lead`` ()).
+    Returns ``(Wf, Vf, Pf, lead)`` with Wf (L, out, in),
+    Vf (N, L, out, in), Pf stacked per kind, and ``lead`` the original
+    leading shape for un-flattening."""
     lead = W.shape[:levels]
     Wf = W.reshape((-1,) + W.shape[levels:])
     Vf = V.reshape(V.shape[:1] + (-1,) + V.shape[1 + levels:])
@@ -237,9 +236,8 @@ def _flatten_stack(W, V, P, levels: int):
 def _to_kernel_layout(W, V, P, convention: str, levels: int = 0):
     """The kernel pipelines are "oi"-native; "io" leaves are transposed
     around the call (XLA fuses the transposes into the kernels' operand
-    loads).  Shared by the streaming and sharded gram halves — one copy
-    of the layout contract; stacked leaves transpose the trailing two
-    axes only."""
+    loads).  Shared by every kernel gram half — one copy of the layout
+    contract; stacked leaves transpose the trailing two axes only."""
     if convention != "io":
         return W, V, P
     # oracle applies delta·P from the left for "io": (PᵢΔ)ᵀ = ΔᵀPᵢᵀ
@@ -248,102 +246,22 @@ def _to_kernel_layout(W, V, P, convention: str, levels: int = 0):
     return jnp.swapaxes(W, -1, -2), jnp.swapaxes(V, -1, -2), Pk
 
 
-def _leaf_gram_kernel(W, V, P, cfg: MAEchoConfig, convention: str,
-                      block: int):
-    """Gram half of the fused streaming pipeline: the Eq. 6 Gram plus
-    the padded-operand reuse context (padding/kind dispatch and the
-    factored-path compressed-residual sharing live in
-    ``ops.maecho_streaming_gram``).  ``block`` is the leaf plan's
-    effective tile edge — the plan is the one source of the tiling,
-    so the summary can never drift from what executes."""
-    from repro.kernels import ops
-
-    Wk, Vk, Pk = _to_kernel_layout(W, V, P, convention)
-    return ops.maecho_streaming_gram(Wk, Vk, Pk, block=block)
-
-
-def _leaf_apply_kernel(alpha, ctx, cfg: MAEchoConfig, convention: str,
-                       block: int):
-    """Update half of the fused streaming pipeline: Eq. 7 + Eq. 11 on
-    the context from :func:`_leaf_gram_kernel`."""
-    from repro.kernels import ops
-
-    W_new, V_new = ops.maecho_streaming_apply(
-        alpha, ctx, eta=cfg.eta, frac=cfg.mu / (1.0 + cfg.mu),
-        norm=cfg.norm, eps=cfg.eps, block=block)
-    if convention == "io":
-        return W_new.T, jnp.swapaxes(V_new, 1, 2)
-    return W_new, V_new
-
-
-def _leaf_gram_sharded(W, V, P, cfg: MAEchoConfig, convention: str,
-                       mesh):
-    """Gram half of the mesh-sharded pipeline: the shared "oi"-native
-    layout contract (:func:`_to_kernel_layout`), with the out-rows
-    shard_map'd over ``cfg.mesh_axis`` (one Gram psum)."""
-    from repro.kernels import ops
-
-    Wk, Vk, Pk = _to_kernel_layout(W, V, P, convention)
-    return ops.maecho_sharded_gram(Wk, Vk, Pk, mesh=mesh,
-                                   axis=cfg.mesh_axis)
-
-
-def _leaf_gram_sharded2d(W, V, P, cfg: MAEchoConfig, convention: str,
-                         mesh):
-    """Gram half of the 2-D (out × in) sharded pipeline: one partial
-    Gram per (out, in) tile, psum'd over BOTH axis groups at once."""
-    from repro.kernels import ops
-
-    Wk, Vk, Pk = _to_kernel_layout(W, V, P, convention)
-    return ops.maecho_sharded2d_gram(Wk, Vk, Pk, mesh=mesh,
-                                     axis_out=cfg.mesh_axis,
-                                     axis_in=cfg.mesh_in_axis)
-
-
-def _leaf_apply_sharded2d(alpha, ctx, cfg: MAEchoConfig,
-                          convention: str, mesh):
-    """Update half of the 2-D sharded pipeline: Eq. 7 + Eq. 11 stay
-    row/col-local — no collectives (the gram's two-axis psum is the
-    leaf's only one per outer iteration)."""
-    from repro.kernels import ops
-
-    W_new, V_new = ops.maecho_sharded2d_apply(
-        alpha, ctx, mesh=mesh, axis_out=cfg.mesh_axis,
-        axis_in=cfg.mesh_in_axis, eta=cfg.eta,
-        frac=cfg.mu / (1.0 + cfg.mu), norm=cfg.norm, eps=cfg.eps)
-    if convention == "io":
-        return W_new.T, jnp.swapaxes(V_new, 1, 2)
-    return W_new, V_new
-
-
-def _leaf_apply_sharded(alpha, ctx, cfg: MAEchoConfig, convention: str,
-                        mesh):
-    """Update half of the mesh-sharded pipeline: Eq. 7 + Eq. 11 run
-    row-local on each device's owned shard — no collectives."""
-    from repro.kernels import ops
-
-    W_new, V_new = ops.maecho_sharded_apply(
-        alpha, ctx, mesh=mesh, axis=cfg.mesh_axis, eta=cfg.eta,
-        frac=cfg.mu / (1.0 + cfg.mu), norm=cfg.norm, eps=cfg.eps)
-    if convention == "io":
-        return W_new.T, jnp.swapaxes(V_new, 1, 2)
-    return W_new, V_new
-
-
 def _leaf_gram_stacked(W, V, P, cfg: MAEchoConfig, convention: str,
                        route: str, mesh, levels: int,
                        block: int = 0):
-    """Gram half for a stacked leaf on the kernel or sharded
-    pipelines: the ``levels`` leading layer axes are flattened into
-    the kernel grid's outer dimension — ONE launch (and, sharded, ONE
-    psum carrying the (L, N, N) stack) covers every scanned layer.
-    ``route`` is the leaf plan's: "stacked" | "sharded" | "sharded2d".
-    Returns ``(G, ctx)`` with G carrying the original leading axes
-    before its trailing (N, N), matching the oracle-vmap layout."""
+    """Gram half of the kernel pipelines: the ``levels`` leading layer
+    axes are flattened into the kernel grid's outer dimension — ONE
+    launch (and, sharded, ONE psum carrying the (L, N, N) stack)
+    covers every scanned layer; a 2-D leaf (``levels`` 0) is the stack
+    L = 1.  ``route`` is the leaf plan's: "kernel" | "sharded" |
+    "sharded2d"; ``block`` its effective tile edge on "kernel" (the
+    plan is the one source of the tiling).  Returns ``(G, ctx)`` with
+    G carrying the original leading axes before its trailing (N, N),
+    matching the oracle-vmap layout."""
     from repro.kernels import ops
 
-    Wf, Vf, Pf, lead = _flatten_stack(W, V, P, levels)
-    Wk, Vk, Pk = _to_kernel_layout(Wf, Vf, Pf, convention, levels=1)
+    Wk, Vk, Pk = _to_kernel_layout(W, V, P, convention, levels)
+    Wk, Vk, Pk, lead = _flatten_stack(Wk, Vk, Pk, levels)
     if route == "sharded2d":
         G, ctx = ops.maecho_sharded2d_gram_stacked(
             Wk, Vk, Pk, mesh=mesh, axis_out=cfg.mesh_axis,
@@ -359,9 +277,10 @@ def _leaf_gram_stacked(W, V, P, cfg: MAEchoConfig, convention: str,
 
 def _leaf_apply_stacked(alpha, ctx, cfg: MAEchoConfig,
                         convention: str, mesh, block: int = 0):
-    """Update half for a stacked leaf: per-layer Eq. 7 + Eq. 11 from
-    the flattened-grid context.  ``alpha`` carries the leaf's leading
-    stack axes before its trailing N (the QP batch layout)."""
+    """Update half of the kernel pipelines: per-layer Eq. 7 + Eq. 11
+    from the flattened-grid context of :func:`_leaf_gram_stacked`.
+    ``alpha`` carries the leaf's leading stack axes before its
+    trailing N (the QP batch layout)."""
     from repro.kernels import ops
 
     _, route, lead, inner = ctx
@@ -378,10 +297,11 @@ def _leaf_apply_stacked(alpha, ctx, cfg: MAEchoConfig,
     else:
         Wn, Vn = ops.maecho_streaming_apply_stacked(
             af, inner, block=block or ops.DEFAULT_BLOCK, **kw)
+    Wn = Wn.reshape(lead + Wn.shape[-2:])
+    Vn = Vn.reshape(Vn.shape[:1] + lead + Vn.shape[-2:])
     if convention == "io":
-        Wn, Vn = jnp.swapaxes(Wn, -1, -2), jnp.swapaxes(Vn, -1, -2)
-    return (Wn.reshape(lead + Wn.shape[-2:]),
-            Vn.reshape(Vn.shape[:1] + lead + Vn.shape[-2:]))
+        return jnp.swapaxes(Wn, -1, -2), jnp.swapaxes(Vn, -1, -2)
+    return Wn, Vn
 
 
 def _leaf_gram_chunked(W, V, P, lp: LeafPlan, cfg: MAEchoConfig,
@@ -519,14 +439,8 @@ def _leaf_gram(W, V, P, lp: LeafPlan, cfg: MAEchoConfig,
                 in_axes=(0, 1, 1), out_axes=0)(Wf, Vf, Pf)
             return G.reshape(lead + G.shape[1:]), R
         return _leaf_gram_oracle(W, V, P, convention)
-    if lp.levels > 0:
-        return _leaf_gram_stacked(W, V, P, cfg, convention, route,
-                                  mesh, lp.levels, lp.block)
-    if route == "sharded2d":
-        return _leaf_gram_sharded2d(W, V, P, cfg, convention, mesh)
-    if route == "sharded":
-        return _leaf_gram_sharded(W, V, P, cfg, convention, mesh)
-    return _leaf_gram_kernel(W, V, P, cfg, convention, lp.block)
+    return _leaf_gram_stacked(W, V, P, cfg, convention, route, mesh,
+                              lp.levels, lp.block)
 
 
 def _leaf_apply(W, V, P, ctx, alpha, lp: LeafPlan, cfg: MAEchoConfig,
@@ -552,14 +466,8 @@ def _leaf_apply(W, V, P, ctx, alpha, lp: LeafPlan, cfg: MAEchoConfig,
             return (Wn.reshape(lead + Wn.shape[1:]),
                     Vn.reshape(Vn.shape[:1] + lead + Vn.shape[2:]))
         return _leaf_apply_oracle(W, V, P, ctx, alpha, cfg, convention)
-    if lp.levels > 0:
-        return _leaf_apply_stacked(alpha, ctx, cfg, convention, mesh,
-                                   lp.block)
-    if route == "sharded2d":
-        return _leaf_apply_sharded2d(alpha, ctx, cfg, convention, mesh)
-    if route == "sharded":
-        return _leaf_apply_sharded(alpha, ctx, cfg, convention, mesh)
-    return _leaf_apply_kernel(alpha, ctx, cfg, convention, lp.block)
+    return _leaf_apply_stacked(alpha, ctx, cfg, convention, mesh,
+                               lp.block)
 
 
 def _scope(phase: str, lp: Optional[LeafPlan] = None):
@@ -740,8 +648,8 @@ def dispatch_summary(W0: Pytree, P: Pytree, levels_tree: Pytree,
     axis) projector trees — arrays or ``jax.ShapeDtypeStruct``s both
     work, routing is static-shape-only.  Returns ``(per_leaf,
     counts)``: ``per_leaf`` is a list of ``(path, levels, route)``
-    with route in ``plan.ROUTES`` ({"oracle", "kernel", "stacked",
-    "sharded", "sharded2d"}); ``counts`` maps route -> leaf count,
+    with route in ``plan.ROUTES`` ({"oracle", "kernel", "sharded",
+    "sharded2d"}); ``counts`` maps route -> leaf count,
     plus a ``"chunked"`` entry (the number of leaves sweeping their
     client axis in ``cfg.client_chunk`` blocks) whenever chunking is
     active.

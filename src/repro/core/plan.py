@@ -23,13 +23,13 @@ Routes:
 
   ``oracle``     the jnp reference path (vmapped over a stacked leaf's
                  layer axis); consumes no mesh axes.
-  ``kernel``     the fused streaming Pallas pipeline (2-D leaf).
-  ``stacked``    the same pipeline with the flattened scan-layer axis
-                 riding the kernel grid as its outermost dimension.
-  ``sharded``    out-rows shard_map'd over ``cfg.mesh_axis``; one
-                 (…, N, N) Gram psum over that axis per leaf per outer
-                 iteration (stacked leaves fold their layer axis into
-                 the per-device grid).
+  ``kernel``     the fused streaming Pallas pipeline: the leaf's
+                 (flattened) scan-layer axis rides the kernel grid as
+                 its outermost dimension, a 2-D leaf being the stack
+                 L = 1 (``levels`` says which a leaf is).
+  ``sharded``    the same pipeline with out-rows shard_map'd over
+                 ``cfg.mesh_axis``; one (L, N, N) Gram psum over that
+                 axis per leaf per outer iteration.
   ``sharded2d``  the 2-D (out × in) shard: out-rows over
                  ``cfg.mesh_axis`` AND in-columns over
                  ``cfg.mesh_in_axis`` ("model"), partial Grams psum'd
@@ -54,7 +54,7 @@ import jax
 from repro.utils import trees
 
 BACKENDS = ("oracle", "kernel", "auto", "sharded", "sharded2d")
-ROUTES = ("oracle", "kernel", "stacked", "sharded", "sharded2d")
+ROUTES = ("oracle", "kernel", "sharded", "sharded2d")
 
 Pytree = Any
 
@@ -79,7 +79,7 @@ class LeafPlan:
     ``out_d`` / ``in_d`` are the kernel-layout ("oi"-native) trailing
     dims — already convention-swapped; ``block`` is the effective
     streaming tile edge (``_eff_block``-clamped ``cfg.kernel_block``)
-    on the kernel/stacked routes and the sharded pipelines' fixed
+    on the kernel route and the sharded pipelines' fixed
     ``DEFAULT_BLOCK`` otherwise; ``out_axes`` / ``in_axes`` are the
     mesh axis names sharding the leaf (empty on unsharded routes), and
     their concatenation is exactly the psum axis set of the leaf's one
@@ -101,10 +101,6 @@ class LeafPlan:
     @property
     def psum_axes(self) -> tuple:
         return self.out_axes + self.in_axes
-
-    @property
-    def stacked(self) -> bool:
-        return self.levels > 0
 
     def specs(self, mesh, convention: str, w_ndim: int, p) -> tuple:
         """``(W, V, P)`` PartitionSpecs of this leaf's executor operands
@@ -292,8 +288,8 @@ def _plan_leaf(path: str, W, P, levels: int, cfg, convention: str,
     # ref-fell-back internally).
     if not sub_tile:
         block = _eff_tile(cfg, out_d, in_d)
-        return LeafPlan(path, levels, "stacked" if levels else "kernel",
-                        kind, out_d, in_d, block, client_chunk=ck)
+        return LeafPlan(path, levels, "kernel", kind, out_d, in_d, block,
+                        client_chunk=ck)
     if backend not in ("oracle", "auto"):
         ops.fallback_warn(
             f"{'stacked ' if levels else ''}leaf {path or '<leaf>'} "

@@ -5,12 +5,17 @@ through ``env.resolve`` at trace time — the Pallas interpreter when
 JAX's default backend is the CPU, Mosaic on a TPU.  There is no switch
 to set: a chip run always lowers the real kernels.
 
-The ``maecho_*_auto`` wrappers are the backend used by
-``core.maecho``'s fused streaming pipeline: they normalise the
-projector kind (stacked scalar / diagonal / dense / factored
-``{"U", "s"}``), zero-pad non-block-multiple shapes via ``_pad_to``
-(zero padding is exact: padded residual tiles are identically zero),
-and fall back to the jnp oracles in ``ref.py`` for shapes too small to
+The MA-Echo pipelines below are the backend of ``core.maecho``: one
+gram half and one apply half per route — single-device streaming
+(``maecho_streaming_*_stacked``), the 1-D out-row shard
+(``maecho_sharded_*_stacked``), the 2-D (out × in) shard
+(``maecho_sharded2d_*_stacked``) and the client-chunked sweeps.  Each
+takes a stack of L layers, W (L, out, in) with the clients in front of
+V and P; a 2-D leaf is the stack L = 1.  They normalise the projector
+kind (scalar / diagonal / dense / factored ``{"U", "s"}``), zero-pad
+non-block-multiple shapes via ``_pad_to`` (zero padding is exact:
+padded residual tiles are identically zero), and the streaming halves
+fall back to the jnp oracles in ``ref.py`` for shapes too small to
 tile.  All of them assume the "oi" layout — ``core.maecho`` transposes
 "io" leaves before dispatch.
 """
@@ -32,18 +37,11 @@ from repro.kernels import maecho_v_update as _mv
 from repro.kernels import rank_update as _ru
 
 __all__ = [
-    "flash_attention", "maecho_update", "maecho_update_factored",
-    "maecho_update_diag", "maecho_gram", "maecho_gram_factored",
-    "maecho_gram_diag", "maecho_v_update", "maecho_v_update_factored",
-    "maecho_v_update_diag", "rank_downdate", "block_rls_update",
-    "maecho_update_auto", "maecho_gram_auto", "maecho_v_update_auto",
-    "maecho_streaming_step", "maecho_streaming_gram",
-    "maecho_streaming_apply", "maecho_streaming_gram_stacked",
-    "maecho_streaming_apply_stacked", "maecho_sharded_gram",
-    "maecho_sharded_apply", "maecho_sharded_gram_stacked",
-    "maecho_sharded_apply_stacked", "maecho_sharded2d_gram",
-    "maecho_sharded2d_apply", "maecho_sharded2d_gram_stacked",
-    "maecho_sharded2d_apply_stacked", "maecho_gram_cross",
+    "flash_attention", "rank_downdate", "block_rls_update",
+    "maecho_streaming_gram_stacked", "maecho_streaming_apply_stacked",
+    "maecho_sharded_gram_stacked", "maecho_sharded_apply_stacked",
+    "maecho_sharded2d_gram_stacked", "maecho_sharded2d_apply_stacked",
+    "maecho_gram_cross",
     "maecho_streaming_gram_chunked", "maecho_streaming_apply_chunked",
     "maecho_streaming_gram_chunked_stacked",
     "maecho_streaming_apply_chunked_stacked",
@@ -54,8 +52,8 @@ __all__ = [
     "live_window", "cache_write", "DEFAULT_BLOCK",
 ]
 
-# one tile edge: the auto wrappers fall back to the jnp oracles below
-# this, and core.maecho's backend="auto" keys off the same constant
+# one tile edge: the streaming halves fall back to the jnp oracles
+# below this, and core.plan's routing keys off the same constant
 DEFAULT_BLOCK = 128
 
 # re-exported from env.py (the raw kernel modules resolve their
@@ -99,40 +97,12 @@ def _proj_kind(P) -> str:
     return "full"                # (N, in, in)
 
 
-def _as_diag(P, in_d: int):
-    """Broadcast stacked scalars (N,) to a diagonal (N, in)."""
-    return jnp.broadcast_to(P[:, None], (P.shape[0], in_d))
-
-
-def _pad_wv(W, V, block):
-    Wp, po = _pad_to(W, block, 0)
-    Wp, pi = _pad_to(Wp, block, 1)
-    if po or pi:
-        Vp, _ = _pad_to(_pad_to(V, block, 1)[0], block, 2)
-    else:
-        Vp = V
-    return Wp, Vp, po, pi
-
-
-def _pad_factored(U, s, block):
-    """Pad the in-axis to ``block``; pad the rank only when it exceeds
-    one lane tile (bk = rank otherwise).  Zero-padded (U, s) columns
-    produce zero compressed-residual columns — exact."""
-    Up, _ = _pad_to(U, block, 1)
-    kd = U.shape[2]
-    if kd > block:
-        Up, _ = _pad_to(Up, block, 2)
-        sp, _ = _pad_to(s, block, 1)
-    else:
-        sp = s
-    return Up, sp
-
-
 def _pad_factored_stacked(U, s, block):
-    """:func:`_pad_factored` for the stacked (N, L, in, k) layout —
-    the same rule shifted by the flattened layer axis, shared by every
-    stacked gram wrapper so the rank-padding exactness argument lives
-    in one place."""
+    """Pad a factored (N, L, in, k) projector's in-axis to ``block``;
+    pad the rank only when it exceeds one lane tile (bk = rank
+    otherwise).  Zero-padded (U, s) columns produce zero
+    compressed-residual columns — exact.  Shared by every gram half, so
+    the rank-padding exactness argument lives in one place."""
     Up, _ = _pad_to(U, block, 2)
     kd = U.shape[3]
     if kd > block:
@@ -147,105 +117,12 @@ def _pad_factored_stacked(U, s, block):
 # the raw kernels under their public names (each resolves interpret=None
 # through env.resolve itself)
 # --------------------------------------------------------------------------
-maecho_update = _mu.maecho_update
-maecho_update_factored = _mu.maecho_update_factored
-maecho_update_diag = _mu.maecho_update_diag
-maecho_gram = _mg.maecho_gram
-maecho_gram_factored = _mg.maecho_gram_factored
-maecho_gram_diag = _mg.maecho_gram_diag
 maecho_gram_cross = _mg.maecho_gram_cross
-maecho_v_update = _mv.maecho_v_update
-maecho_v_update_factored = _mv.maecho_v_update_factored
-maecho_v_update_diag = _mv.maecho_v_update_diag
 flash_attention = _fa.flash_attention
 decode_attention = _da.decode_attention
 cache_write = _da.cache_write
 rank_downdate = _ru.rank_downdate
 block_rls_update = _ru.block_rls_update
-
-
-# --------------------------------------------------------------------------
-# auto dispatch: kind normalisation + padding + small-shape fallback
-# --------------------------------------------------------------------------
-def _normalize_padded(W, V, P, block: int):
-    """Shared front half of the auto wrappers: classify the projector
-    and zero-pad every operand to block multiples.
-
-    Returns ``(kind, Wp, Vp, Pk)`` where ``Pk`` is the padded kernel
-    operand for the kind — an ``(U, s)`` tuple for "factored", a
-    (N, in_p) diagonal for "scalar"/"diag" (scalars broadcast), or the
-    (N, in_p, in_p) dense matrix for "full".
-    """
-    in_d = W.shape[1]
-    kind = _proj_kind(P)
-    Wp, Vp, po, pi = _pad_wv(W, V, block)
-    if kind == "factored":
-        Pk = _pad_factored(P["U"], P["s"], block)
-    elif kind in ("scalar", "diag"):
-        p = _as_diag(P, in_d) if kind == "scalar" else P
-        Pk = _pad_to(p, block, 1)[0]
-    else:
-        Pk = (_pad_to(_pad_to(P, block, 1)[0], block, 2)[0]
-              if (po or pi) else P)
-    return kind, Wp, Vp, Pk
-
-
-def maecho_update_auto(W, V, P, alpha, *, eta: float = 1.0,
-                       block: int = 128, interpret=None):
-    """Eq. 7 for any projector kind: kernel when tileable, oracle else."""
-    out_d, in_d = W.shape
-    if out_d < block or in_d < block:
-        return ref.maecho_update_ref_any(W, V, P, alpha, eta)
-    kind, Wp, Vp, Pk = _normalize_padded(W, V, P, block)
-    if kind == "factored":
-        out = maecho_update_factored(Wp, Vp, *Pk, alpha, eta=eta,
-                                     interpret=interpret)
-    elif kind == "full":
-        out = maecho_update(Wp, Vp, Pk, alpha, eta=eta,
-                            interpret=interpret)
-    else:
-        out = maecho_update_diag(Wp, Vp, Pk, alpha, eta=eta,
-                                 interpret=interpret)
-    return out[:out_d, :in_d]
-
-
-def maecho_gram_auto(W, V, P, *, block: int = 128, interpret=None):
-    """(N, N) projected-residual Gram for any projector kind."""
-    out_d, in_d = W.shape
-    if out_d < block or in_d < block:
-        return ref.maecho_gram_ref(W, V, P)
-    kind, Wp, Vp, Pk = _normalize_padded(W, V, P, block)
-    if kind == "factored":
-        return maecho_gram_factored(Wp, Vp, *Pk, interpret=interpret)
-    if kind == "full":
-        return maecho_gram(Wp, Vp, Pk, interpret=interpret)
-    return maecho_gram_diag(Wp, Vp, Pk, interpret=interpret)
-
-
-def maecho_v_update_auto(W, V, P, *, frac: float, norm: bool = False,
-                         eps: float = 1e-12, block: int = 128,
-                         interpret=None):
-    """Eq. 11 for any projector kind.
-
-    With ``norm=True`` the kernels need full rows resident (bi = padded
-    in_d) — fine up to rows of ~16k fp32.
-    """
-    out_d, in_d = W.shape
-    if out_d < block or in_d < block:
-        return ref.maecho_v_update_ref(W, V, P, frac, norm, eps)
-    kind, Wp, Vp, Pk = _normalize_padded(W, V, P, block)
-    bi = Wp.shape[1] if norm else block
-    if kind == "factored":
-        out = maecho_v_update_factored(Wp, Vp, *Pk, frac=frac,
-                                       norm=norm, eps=eps, bi=bi,
-                                       interpret=interpret)
-    elif kind == "full":
-        out = maecho_v_update(Wp, Vp, Pk, frac=frac, norm=norm, eps=eps,
-                              bi=bi, interpret=interpret)
-    else:
-        out = maecho_v_update_diag(Wp, Vp, Pk, frac=frac, norm=norm,
-                                   eps=eps, bi=bi, interpret=interpret)
-    return out[:, :out_d, :in_d]
 
 
 def _eff_block(block: int, out_d: int, in_d: int,
@@ -263,118 +140,12 @@ def _eff_block(block: int, out_d: int, in_d: int,
     return min(block, cap)
 
 
-def maecho_streaming_gram(W, V, P, *, block: int = DEFAULT_BLOCK,
-                          interpret=None):
-    """Gram half of the fused leaf iteration: returns ``(G, ctx)``.
-
-    G is the (N, N) Eq. 6 Gram matrix; ``ctx`` is an opaque reuse
-    context for :func:`maecho_streaming_apply` carrying the classified
-    kind, the padded operands, and — on the factored path — the
-    compressed residual A shared with the Eq. 7 kernel (the dominant
-    O(N·out·in·k) einsum is not recomputed).  Splitting gram from
-    apply is what lets ``core.maecho`` stack every leaf's Gram into
-    one (L, N, N) batch and run a single vmapped QP solve per outer
-    iteration instead of L sequential ones.
-    """
-    out_d, in_d = W.shape
-    if out_d < DEFAULT_BLOCK or in_d < DEFAULT_BLOCK:
-        fallback_warn(
-            f"leaf (out={out_d}, in={in_d}) below one "
-            f"{DEFAULT_BLOCK}-tile: running the jnp oracle instead of "
-            f"the streaming kernels")
-        return ref.maecho_gram_ref(W, V, P), ("ref", W, V, P,
-                                              out_d, in_d)
-    block = _eff_block(block, out_d, in_d)
-    kind, Wp, Vp, Pk = _normalize_padded(W, V, P, block)
-    if kind == "factored":
-        from repro.kernels.maecho_gram import compressed_residual
-
-        Up, sp = Pk
-        A = compressed_residual(Wp, Vp, Up, sp)
-        UT = jnp.swapaxes(Up, 1, 2).astype(jnp.float32)
-        G = _mg.maecho_gram_left(A, UT, bo=block, bi=block, bk=block,
-                                 interpret=interpret)
-        return G, (kind, Wp, Vp, (Up, sp, A, UT), out_d, in_d)
-    if kind == "full":
-        G = maecho_gram(Wp, Vp, Pk, bo=block, bi=block, bk=block,
-                        interpret=interpret)
-    else:
-        G = maecho_gram_diag(Wp, Vp, Pk, bo=block, bi=block,
-                             interpret=interpret)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
-
-
-def maecho_streaming_apply(alpha, ctx, *, eta: float = 1.0,
-                           frac: float = 0.5, norm: bool = False,
-                           eps: float = 1e-12, block: int = DEFAULT_BLOCK,
-                           interpret=None):
-    """Update half of the fused leaf iteration: Eq. 7 then Eq. 11.
-
-    ``ctx`` is the context returned by :func:`maecho_streaming_gram`
-    for the same leaf (same padded operands — the pipeline stays in
-    padded space; zero padding is invariant under all three passes).
-    Returns ``(W', V')`` cropped back to the original shape.
-    """
-    kind, Wp, Vp, Pk, out_d, in_d = ctx
-    if kind == "ref":
-        W_new = ref.maecho_update_ref_any(Wp, Vp, Pk, alpha, eta)
-        return W_new, ref.maecho_v_update_ref(W_new, Vp, Pk, frac,
-                                              norm, eps)
-    block = _eff_block(block, out_d, in_d)   # same clamp as the gram
-    bi = Wp.shape[1] if norm else block
-    if kind == "factored":
-        Up, sp, A, UT = Pk
-        Wn = _mu.maecho_update_left(Wp, A, UT, alpha, eta=eta,
-                                    bo=block, bi=block, bk=block,
-                                    interpret=interpret)
-        Vn = maecho_v_update_factored(Wn, Vp, Up, sp, frac=frac,
-                                      norm=norm, eps=eps, bo=block,
-                                      bi=bi, bk=block,
-                                      interpret=interpret)
-    elif kind == "full":
-        Wn = maecho_update(Wp, Vp, Pk, alpha, eta=eta, bo=block,
-                           bi=block, bk=block, interpret=interpret)
-        Vn = maecho_v_update(Wn, Vp, Pk, frac=frac, norm=norm, eps=eps,
-                             bo=block, bi=bi, bk=block,
-                             interpret=interpret)
-    else:
-        Wn = maecho_update_diag(Wp, Vp, Pk, alpha, eta=eta, bo=block,
-                                bi=block, interpret=interpret)
-        Vn = maecho_v_update_diag(Wn, Vp, Pk, frac=frac, norm=norm,
-                                  eps=eps, bo=block, bi=bi,
-                                  interpret=interpret)
-    return Wn[:out_d, :in_d], Vn[:, :out_d, :in_d]
-
-
-def maecho_streaming_step(W, V, P, qp, *, eta: float = 1.0,
-                          frac: float = 0.5, norm: bool = False,
-                          eps: float = 1e-12, block: int = DEFAULT_BLOCK,
-                          interpret=None):
-    """One fused Algorithm-1 leaf iteration: gram → QP → Eq. 7 → Eq. 11.
-
-    ``qp`` maps the (N, N) Gram matrix to the simplex weights α.  The
-    projector is normalised and padded **once** (in the gram half) and
-    the whole pipeline runs in padded space.  This is the single-leaf
-    composition of :func:`maecho_streaming_gram` and
-    :func:`maecho_streaming_apply`; the batched path in
-    ``core.maecho`` calls the two halves directly around one stacked
-    QP solve.  Layout is "oi"; shapes below one tile run the jnp
-    oracles with the same QP.
-    """
-    G, ctx = maecho_streaming_gram(W, V, P, block=block,
-                                   interpret=interpret)
-    alpha = qp(G)
-    return maecho_streaming_apply(alpha, ctx, eta=eta, frac=frac,
-                                  norm=norm, eps=eps, block=block,
-                                  interpret=interpret)
-
-
 # --------------------------------------------------------------------------
-# stacked-leaf streaming pipeline: the scan-layer axis rides the grid
+# streaming pipeline: the (flattened) layer axis rides the kernel grid
 # --------------------------------------------------------------------------
 def _proj_kind_stacked(P) -> str:
-    """Kind of a stacked projector leaf with (N, L) leading axes —
-    every unstacked kind shifted by the flattened layer axis."""
+    """Kind of a projector leaf with (N, L) leading axes — every kind
+    of :func:`_proj_kind` shifted by the layer axis."""
     if isinstance(P, dict):
         return "factored"
     if P.ndim == 2:
@@ -385,10 +156,13 @@ def _proj_kind_stacked(P) -> str:
 
 
 def _normalize_padded_stacked(W, V, P, block: int):
-    """Stacked analogue of :func:`_normalize_padded`: classify the
-    projector of a flattened (L, out, in) leaf and zero-pad the
-    out/in (and factored-rank) axes to block multiples.  The layer
-    axis L is a grid axis, never padded."""
+    """Classify the projector of an (L, out, in) leaf and zero-pad the
+    out/in (and factored-rank) axes to block multiples.  Returns
+    ``(kind, Wp, Vp, Pk)`` where ``Pk`` is the padded kernel operand
+    for the kind — a ``(U, s)`` tuple for "factored", an (N, L, in_p)
+    diagonal for "scalar"/"diag" (scalars broadcast), or the
+    (N, L, in_p, in_p) dense matrix for "full".  The layer axis L is a
+    grid axis, never padded."""
     in_d = W.shape[2]
     kind = _proj_kind_stacked(P)
     Wp, po = _pad_to(W, block, 1)
@@ -409,16 +183,19 @@ def _normalize_padded_stacked(W, V, P, block: int):
 
 def maecho_streaming_gram_stacked(W, V, P, *, block: int = DEFAULT_BLOCK,
                                   interpret=None):
-    """Stacked gram half of the fused leaf iteration: ``(G, ctx)``.
+    """Gram half of the fused leaf iteration: ``(G, ctx)``.
 
     W: (L, out, in); V: (N, L, out, in); P stacked per kind.  G is the
     per-layer (L, N, N) Eq. 6 Gram stack from ONE kernel launch (the
     layer axis is the outermost grid dimension — see
     ``maecho_gram.maecho_gram_stacked``); ``ctx`` is the reuse payload
-    for :func:`maecho_streaming_apply_stacked`, carrying the factored
-    path's (N, L, out, k) compressed residual exactly like the
-    per-layer pipeline.  Shapes below one tile fall back to the vmapped
-    jnp oracle (same contract as :func:`maecho_streaming_gram`)."""
+    for :func:`maecho_streaming_apply_stacked`: the classified kind,
+    the padded operands and, on the factored path, the (N, L, out, k)
+    compressed residual A shared with the Eq. 7 kernel (the dominant
+    O(N·out·in·k) einsum is not recomputed).  Splitting gram from apply
+    is what lets ``core.maecho`` stack every leaf's Gram into one batch
+    and run a single vmapped QP solve per outer iteration.  Shapes
+    below one tile fall back to the vmapped jnp oracle."""
     L, out_d, in_d = W.shape
     if out_d < DEFAULT_BLOCK or in_d < DEFAULT_BLOCK:
         fallback_warn(
@@ -453,10 +230,13 @@ def maecho_streaming_apply_stacked(alpha, ctx, *, eta: float = 1.0,
                                    eps: float = 1e-12,
                                    block: int = DEFAULT_BLOCK,
                                    interpret=None):
-    """Stacked update half: per-layer Eq. 7 then Eq. 11 from one
-    launch each.  ``alpha`` is the (L, N) per-layer solve stack;
-    ``ctx`` comes from :func:`maecho_streaming_gram_stacked` for the
-    same leaf.  Returns ``(W', V')`` cropped to the original shape."""
+    """Update half: per-layer Eq. 7 then Eq. 11 from one launch each.
+    ``alpha`` is the (L, N) per-layer solve stack; ``ctx`` comes from
+    :func:`maecho_streaming_gram_stacked` for the same leaf (same
+    padded operands — the pipeline stays in padded space; zero padding
+    is invariant under all three passes).  With ``norm=True`` the
+    Eq. 11 kernel takes whole rows (bi = padded in_d).  Returns
+    ``(W', V')`` cropped to the original shape."""
     kind, Wp, Vp, Pk, out_d, in_d = ctx
     if kind == "ref":
         W_new = jax.vmap(
@@ -540,152 +320,28 @@ def sharded_ok(out_d: int, in_d: int, axis_size: int,
     return ok
 
 
-def maecho_sharded_gram(W, V, P, *, mesh, axis="data",
-                        block: int = DEFAULT_BLOCK, interpret=None):
-    """Out-dim-sharded gram half of the streaming pipeline.
-
-    Same ``(G, ctx)`` contract as :func:`maecho_streaming_gram`, but
-    the leaf's out-rows are split over the ``axis`` mesh axes with
-    ``shard_map``: each device forms only its own
-    (out / axis_size, in) residual tiles in VMEM, contracts a partial
-    (N, N) Gram locally, and ONE ``psum`` over the axis reconstructs
-    the full replicated Gram that feeds the (global, unchanged) QP
-    solve.  The apply half (:func:`maecho_sharded_apply`) then runs
-    purely locally on the owned rows — no further collectives.
-
-    Operands are zero-padded so the out-dim is a multiple of
-    ``block × axis_size`` (even, block-tileable shards; zero padding
-    is exact for all three passes) and the in-dim to ``block``.  On
-    the factored path the (N, out, k) compressed residual is computed
-    *sharded* and carried in ``ctx`` for the Eq. 7 kernel — the
-    compressed-residual reuse survives the sharding.  Callers gate
-    eligibility with :func:`sharded_ok`; "oi" layout, like the rest of
-    the kernel pipeline.
-    """
-    names = _axis_names(axis)
-    asz = axis_size_of(mesh, axis)
-    out_d, in_d = W.shape
-    kind = _proj_kind(P)
-    Wp, _ = _pad_to(_pad_to(W, block * asz, 0)[0], block, 1)
-    Vp, _ = _pad_to(_pad_to(V, block * asz, 1)[0], block, 2)
-    row = PartitionSpec(names, None)           # W rows
-    crow = PartitionSpec(None, names, None)    # V / A rows (axis 1)
-    rep2 = PartitionSpec(None, None)
-    rep3 = PartitionSpec(None, None, None)
-    if kind == "factored":
-        Up, sp = _pad_factored(P["U"], P["s"], block)
-
-        def body_f(Wl, Vl, U, s):
-            A = _mg.compressed_residual(Wl, Vl, U, s)
-            UT = jnp.swapaxes(U, 1, 2).astype(jnp.float32)
-            Gl = _mg.maecho_gram_left(A, UT, interpret=interpret)
-            return jax.lax.psum(Gl, names), A
-
-        G, A = jax.shard_map(body_f, mesh=mesh,
-                         in_specs=(row, crow, rep3, rep2),
-                         out_specs=(rep2, crow),
-                         check_vma=False)(Wp, Vp, Up, sp)
-        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
-    if kind == "full":
-        Pk = _pad_to(_pad_to(P, block, 1)[0], block, 2)[0]
-
-        def body_d(Wl, Vl, Pl):
-            return jax.lax.psum(
-                _mg.maecho_gram(Wl, Vl, Pl, interpret=interpret), names)
-
-        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep3),
-                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
-    else:                                   # scalar / diag
-        p = _as_diag(P, in_d) if kind == "scalar" else P
-        Pk = _pad_to(p, block, 1)[0]
-
-        def body_g(Wl, Vl, pl):
-            return jax.lax.psum(
-                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=interpret), names)
-
-        G = jax.shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep2),
-                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
-
-
-def maecho_sharded_apply(alpha, ctx, *, mesh, axis="data",
-                         eta: float = 1.0, frac: float = 0.5,
-                         norm: bool = False, eps: float = 1e-12,
-                         block: int = DEFAULT_BLOCK, interpret=None):
-    """Update half of the sharded pipeline: Eq. 7 then Eq. 11.
-
-    ``ctx`` is the context from :func:`maecho_sharded_gram` for the
-    same leaf.  Both phases are row-local under the same out-dim
-    sharding: Eq. 7 scales the owned rows' residuals by the replicated
-    α, and Eq. 11's row normalisation runs along the unsharded in-axis
-    — zero collectives (the gram phase's single psum is the outer
-    iteration's only one).  Returns ``(W', V')`` cropped to the
-    original shape.
-    """
-    kind, Wp, Vp, Pk, out_d, in_d = ctx
-    names = _axis_names(axis)
-    bi = Wp.shape[1] if norm else block
-    row = PartitionSpec(names, None)
-    crow = PartitionSpec(None, names, None)
-    rep1 = PartitionSpec(None)
-    rep2 = PartitionSpec(None, None)
-    rep3 = PartitionSpec(None, None, None)
-    if kind == "factored":
-        Up, sp, A = Pk
-
-        def body_f(a, Wl, Vl, U, s, Al):
-            UT = jnp.swapaxes(U, 1, 2).astype(jnp.float32)
-            Wn = _mu.maecho_update_left(Wl, Al, UT, a, eta=eta,
-                                        interpret=interpret)
-            Vn = _mv.maecho_v_update_factored(
-                Wn, Vl, U, s, frac=frac, norm=norm, eps=eps, bi=bi,
-                interpret=interpret)
-            return Wn, Vn
-
-        Wn, Vn = jax.shard_map(
-            body_f, mesh=mesh,
-            in_specs=(rep1, row, crow, rep3, rep2, crow),
-            out_specs=(row, crow), check_vma=False)(
-            alpha, Wp, Vp, Up, sp, A)
-    elif kind == "full":
-        def body_d(a, Wl, Vl, Pl):
-            Wn = _mu.maecho_update(Wl, Vl, Pl, a, eta=eta,
-                                   interpret=interpret)
-            Vn = _mv.maecho_v_update(Wn, Vl, Pl, frac=frac, norm=norm,
-                                     eps=eps, bi=bi, interpret=interpret)
-            return Wn, Vn
-
-        Wn, Vn = jax.shard_map(
-            body_d, mesh=mesh, in_specs=(rep1, row, crow, rep3),
-            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
-    else:                                   # scalar / diag
-        def body_g(a, Wl, Vl, pl):
-            Wn = _mu.maecho_update_diag(Wl, Vl, pl, a, eta=eta,
-                                        interpret=interpret)
-            Vn = _mv.maecho_v_update_diag(Wn, Vl, pl, frac=frac,
-                                          norm=norm, eps=eps, bi=bi,
-                                          interpret=interpret)
-            return Wn, Vn
-
-        Wn, Vn = jax.shard_map(
-            body_g, mesh=mesh, in_specs=(rep1, row, crow, rep2),
-            out_specs=(row, crow), check_vma=False)(alpha, Wp, Vp, Pk)
-    return Wn[:out_d, :in_d], Vn[:, :out_d, :in_d]
-
-
 def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
                                 block: int = DEFAULT_BLOCK,
                                 interpret=None):
-    """Out-dim-sharded stacked gram half.
+    """Out-dim-sharded gram half of the streaming pipeline.
 
-    Same contract as :func:`maecho_sharded_gram` with the flattened
-    scan-layer axis riding the kernel grid inside every shard:
-    W (L, out, in) splits its out-rows over ``axis``, each device runs
-    ONE stacked kernel launch over its (L, out/axis_size, in) slab,
-    and a single ``psum`` per leaf per outer iteration reconstructs
-    the replicated (L, N, N) Gram stack that feeds the (unchanged)
-    stacked QP solve.  The factored path's (N, L, out, k) compressed
-    residual is computed sharded and carried in ``ctx``.
+    Same ``(G, ctx)`` contract as :func:`maecho_streaming_gram_stacked`,
+    but the leaf's out-rows are split over the ``axis`` mesh axes with
+    ``shard_map``: W (L, out, in) splits its out-rows, each device runs
+    ONE kernel launch over its (L, out/axis_size, in) slab — forming
+    only its own residual tiles in VMEM — and a single ``psum`` per
+    leaf per outer iteration reconstructs the replicated (L, N, N) Gram
+    stack that feeds the (global, unchanged) QP solve.  The apply half
+    (:func:`maecho_sharded_apply_stacked`) then runs purely locally on
+    the owned rows — no further collectives.
+
+    Operands are zero-padded so the out-dim is a multiple of
+    ``block × axis_size`` (even, block-tileable shards; zero padding is
+    exact for all three passes) and the in-dim to ``block``.  On the
+    factored path the (N, L, out, k) compressed residual is computed
+    *sharded* and carried in ``ctx`` for the Eq. 7 kernel.  Callers
+    gate eligibility with :func:`sharded_ok`; "oi" layout, like the
+    rest of the kernel pipeline.
     """
     names = _axis_names(axis)
     asz = axis_size_of(mesh, axis)
@@ -741,12 +397,14 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
                                  norm: bool = False, eps: float = 1e-12,
                                  block: int = DEFAULT_BLOCK,
                                  interpret=None):
-    """Stacked update half of the sharded pipeline: per-layer Eq. 7
-    then Eq. 11, row-local on each device's owned out-rows under the
-    same sharding as :func:`maecho_sharded_gram_stacked` — zero
-    collectives (the gram psum is the iteration's only one).
-    ``alpha`` is the replicated (L, N) per-layer solve stack.
-    Returns ``(W', V')`` cropped to the original shape."""
+    """Update half of the sharded pipeline: per-layer Eq. 7 then
+    Eq. 11, row-local on each device's owned out-rows under the same
+    sharding as :func:`maecho_sharded_gram_stacked`.  Eq. 7 scales the
+    owned rows' residuals by the replicated α and Eq. 11's row
+    normalisation runs along the unsharded in-axis — zero collectives
+    (the gram psum is the iteration's only one).  ``alpha`` is the
+    replicated (L, N) per-layer solve stack.  Returns ``(W', V')``
+    cropped to the original shape."""
     kind, Wp, Vp, Pk, out_d, in_d = ctx
     names = _axis_names(axis)
     bi = Wp.shape[2] if norm else block
@@ -813,132 +471,36 @@ def _pin(mesh, *pairs):
             for x, spec in pairs]
 
 
-def maecho_sharded2d_gram(W, V, P, *, mesh, axis_out="data",
-                          axis_in="model", block: int = DEFAULT_BLOCK,
-                          interpret=None):
-    """2-D-sharded gram half: out-rows over ``axis_out`` AND
-    in-columns over ``axis_in``.
-
-    Each device forms only its own (out/osz, in/isz) tile of the
-    projected residual — the dominant O(N·out·in²) projection FLOPs
-    split over the *whole* osz × isz fleet, which is the point: a leaf
-    whose out-dim tile count cannot divide the full device count 1-D
-    can still span it as the product of two smaller per-axis factors
-    (``rules.sharded_ok2d`` gates both dims).  The partial (N, N)
-    Grams are reconstructed by ONE ``psum`` over BOTH axis groups —
-    the leaf's only collective per outer iteration.
-
-    The residual tile is formed as a left-factor product (``Δ`` rows
-    against the projector's owned output columns), so dense and
-    factored kinds ride the existing ``maecho_gram_left`` kernel and
-    diagonal/scalar kinds the elementwise ``maecho_gram_diag`` on
-    pre-sliced operands.  Operands are zero-padded to
-    ``block × axis_size`` multiples on each sharded dim (zero padding
-    is exact for all three passes).
-
-    Returns ``(G, ctx)`` with ``ctx`` in the SAME format as
-    :func:`maecho_sharded_gram` — the apply half reuses the 1-D
-    row-local kernels verbatim (see :func:`maecho_sharded2d_apply`).
-    """
-    no, ni = _axis_names(axis_out), _axis_names(axis_in)
-    allnames = no + ni
-    osz = axis_size_of(mesh, axis_out)
-    isz = axis_size_of(mesh, axis_in)
-    out_d, in_d = W.shape
-    kind = _proj_kind(P)
-    row = PartitionSpec(no, None)
-    crow = PartitionSpec(None, no, None)
-    col3 = PartitionSpec(None, None, ni)
-    rep2 = PartitionSpec(None, None)
-    rep3 = PartitionSpec(None, None, None)
-    Wp, Vp = _pin(mesh,
-                  (_pad_to(_pad_to(W, block * osz, 0)[0], block * isz,
-                           1)[0], row),
-                  (_pad_to(_pad_to(V, block * osz, 1)[0], block * isz,
-                           2)[0], crow))
-    if kind == "factored":
-        Up, sp = _pin(mesh, *zip(_pad_factored(P["U"], P["s"], block),
-                                 (rep3, rep2)))
-        UTs = jnp.swapaxes(Up, 1, 2).astype(jnp.float32)
-
-        def body_f(Wl, Vl, U, s, UTl):
-            # A (full in-contraction, replicated over axis_in);
-            # the gram contracts A against only the owned UT columns
-            A = _mg.compressed_residual(Wl, Vl, U, s)
-            Gl = _mg.maecho_gram_left(A, UTl, interpret=interpret)
-            return jax.lax.psum(Gl, allnames), A
-
-        G, A = jax.shard_map(body_f, mesh=mesh,
-                         in_specs=(row, crow, rep3, rep2, col3),
-                         out_specs=(rep2, crow),
-                         check_vma=False)(Wp, Vp, Up, sp, UTs)
-        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
-    if kind == "full":
-        in_p = Wp.shape[1]
-        Pk, = _pin(mesh, (_pad_to(_pad_to(P, in_p, 1)[0], in_p, 2)[0],
-                          rep3))
-
-        def body_d(Wl, Vl, Pl):
-            # residual tile = Δ @ P[:, owned columns]: the delta rows
-            # are the left factor, the projector's owned output
-            # columns the right — maecho_gram_left streams the tiles
-            A = (Wl[None] - Vl).astype(jnp.float32)
-            Gl = _mg.maecho_gram_left(A, Pl.astype(jnp.float32),
-                                      interpret=interpret)
-            return jax.lax.psum(Gl, allnames)
-
-        G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, col3),
-                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
-    else:                                   # scalar / diag
-        p = _as_diag(P, in_d) if kind == "scalar" else P
-        Pk, = _pin(mesh, (_pad_to(p, block * isz, 1)[0], rep2))
-
-        def body_g(Wl, Vl, pl):
-            # elementwise kind: 2-D-slicing the operands is exact
-            return jax.lax.psum(
-                _mg.maecho_gram_diag(Wl, Vl, pl, interpret=interpret),
-                allnames)
-
-        G = jax.shard_map(body_g, mesh=mesh,
-                      in_specs=(PartitionSpec(no, ni),
-                                PartitionSpec(None, no, ni),
-                                PartitionSpec(None, ni)),
-                      out_specs=rep2, check_vma=False)(Wp, Vp, Pk)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
-
-
-def maecho_sharded2d_apply(alpha, ctx, *, mesh, axis_out="data",
-                           axis_in="model", eta: float = 1.0,
-                           frac: float = 0.5, norm: bool = False,
-                           eps: float = 1e-12,
-                           block: int = DEFAULT_BLOCK, interpret=None):
-    """Update half of the 2-D pipeline: Eq. 7 then Eq. 11, row/col-local.
-
-    Delegates to the 1-D row-local apply over ``axis_out``: the
-    devices along ``axis_in`` hold replicated rows (the in-dim
-    contraction of Eq. 11 needs full Δ' rows, which stay resident from
-    the gram phase's in-replicated operands) and recompute identical
-    row shards — ZERO collectives either way, so the gram phase's
-    single two-axis psum remains the leaf's only one per outer
-    iteration.  ``ctx`` comes from :func:`maecho_sharded2d_gram`
-    (same layout as the 1-D context; the extra in-padding to
-    ``block × axis_in_size`` is still a block multiple, which is all
-    the kernels require)."""
-    del axis_in  # rows-only: the in-group replicates the apply
-    return maecho_sharded_apply(alpha, ctx, mesh=mesh, axis=axis_out,
-                                eta=eta, frac=frac, norm=norm, eps=eps,
-                                block=block, interpret=interpret)
-
-
 def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
                                   axis_in="model",
                                   block: int = DEFAULT_BLOCK,
                                   interpret=None):
-    """Stacked 2-D gram half: same contract as
-    :func:`maecho_sharded2d_gram` with the flattened scan-layer axis
-    riding the kernel grid inside every (out × in) shard — ONE stacked
-    launch per device and ONE two-axis ``psum`` per leaf per outer
-    iteration carrying the whole (L, N, N) Gram stack."""
+    """2-D-sharded gram half: out-rows over ``axis_out`` AND
+    in-columns over ``axis_in``.
+
+    Each device forms only its own (L, out/osz, in/isz) tile of the
+    projected residual — the dominant O(N·out·in²) projection FLOPs
+    split over the *whole* osz × isz fleet, which is the point: a leaf
+    whose out-dim tile count cannot divide the full device count 1-D
+    can still span it as the product of two smaller per-axis factors
+    (``rules.sharded_ok2d`` gates both dims).  The layer axis rides the
+    kernel grid inside every shard: ONE launch per device, and ONE
+    ``psum`` over BOTH axis groups per leaf per outer iteration
+    reconstructs the whole (L, N, N) Gram stack.
+
+    The residual tile is formed as a left-factor product (``Δ`` rows
+    against the projector's owned output columns), so dense and
+    factored kinds ride the ``maecho_gram_left_stacked`` kernel and
+    diagonal/scalar kinds the elementwise ``maecho_gram_diag_stacked``
+    on pre-sliced operands.  Operands are zero-padded to
+    ``block × axis_size`` multiples on each sharded dim (zero padding
+    is exact for all three passes) and pinned to the apply's layout
+    (:func:`_pin`).
+
+    Returns ``(G, ctx)`` with ``ctx`` in the SAME format as
+    :func:`maecho_sharded_gram_stacked` — the apply half reuses the
+    1-D row-local kernels verbatim (:func:`maecho_sharded2d_apply_stacked`).
+    """
     no, ni = _axis_names(axis_out), _axis_names(axis_in)
     allnames = no + ni
     osz = axis_size_of(mesh, axis_out)
@@ -1011,10 +573,19 @@ def maecho_sharded2d_apply_stacked(alpha, ctx, *, mesh,
                                    eps: float = 1e-12,
                                    block: int = DEFAULT_BLOCK,
                                    interpret=None):
-    """Stacked 2-D apply: row/col-local per-layer Eq. 7 + Eq. 11 via
-    the 1-D stacked apply over ``axis_out`` (the in-group replicates
-    the rows — zero collectives, cf. :func:`maecho_sharded2d_apply`)."""
-    del axis_in
+    """Update half of the 2-D pipeline: per-layer Eq. 7 then Eq. 11,
+    row/col-local.
+
+    Delegates to the 1-D row-local apply over ``axis_out``: the devices
+    along ``axis_in`` hold replicated rows (the in-dim contraction of
+    Eq. 11 needs full Δ' rows, which stay resident from the gram
+    phase's in-replicated operands) and recompute identical row shards
+    — ZERO collectives either way, so the gram phase's single two-axis
+    psum remains the leaf's only one per outer iteration.  ``ctx``
+    comes from :func:`maecho_sharded2d_gram_stacked` (same layout as
+    the 1-D context; the extra in-padding to ``block × axis_in_size``
+    is still a block multiple, which is all the kernels require)."""
+    del axis_in  # rows-only: the in-group replicates the apply
     return maecho_sharded_apply_stacked(
         alpha, ctx, mesh=mesh, axis=axis_out, eta=eta, frac=frac,
         norm=norm, eps=eps, block=block, interpret=interpret)
@@ -1222,9 +793,8 @@ def _cross_pair(bd: int, interpret):
 def maecho_streaming_gram_chunked(W, V, P, *, chunk: int,
                                   use_kernel: bool = False,
                                   bd: int = 512, interpret=None):
-    """Client-chunked gram half: same ``(G, ctx)`` contract as
-    :func:`maecho_streaming_gram`, but the (N, N) Gram accumulates
-    over client chunks — peak residual residency is O(chunk·out·in),
+    """Client-chunked gram half of a 2-D leaf: ``(G, ctx)``, the
+    (N, N) Gram accumulated over client chunks — peak residual residency is O(chunk·out·in),
     not O(N·out·in), which is what lets one aggregation span
     cross-device cohorts (N in the thousands).  With ``use_kernel``
     the (chunk, chunk) pair blocks stream through the Pallas

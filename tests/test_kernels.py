@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import maecho_update as mu
 from repro.kernels import ops, ref
 
 
@@ -21,7 +22,9 @@ def test_maecho_update_sweep(out_d, in_d, N, dtype):
          * 0.05).astype(dtype)
     alpha = jax.nn.softmax(jax.random.normal(jax.random.fold_in(k, 3),
                                              (N,)))
-    got = ops.maecho_update(W, V, P, alpha, eta=0.7)
+    # the kernel takes a stack of layers: a 2-D leaf is the stack L = 1
+    got = mu.maecho_update_stacked(W[None], V[:, None], P[:, None],
+                                   alpha[None], eta=0.7)[0]
     want = ref.maecho_update_ref(W.astype(jnp.float32),
                                  V.astype(jnp.float32),
                                  P.astype(jnp.float32), alpha, 0.7)
@@ -36,7 +39,11 @@ def test_maecho_update_auto_pads_odd_shapes():
     V = jax.random.normal(jax.random.fold_in(k, 1), (2, 200, 300))
     P = jax.random.normal(jax.random.fold_in(k, 2), (2, 300, 300)) * 0.05
     alpha = jnp.array([0.6, 0.4])
-    got = ops.maecho_update_auto(W, V, P, alpha, eta=1.0)
+    # the streaming halves pad the stack L = 1 to whole tiles
+    _, ctx = ops.maecho_streaming_gram_stacked(W[None], V[:, None],
+                                               P[:, None])
+    got = ops.maecho_streaming_apply_stacked(alpha[None], ctx,
+                                             eta=1.0)[0][0]
     want = ref.maecho_update_ref(W, V, P, alpha, 1.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4)
@@ -107,7 +114,8 @@ def test_kernel_used_inside_algorithm_one():
     R = jnp.einsum("noi,nij->noj", W[None] - V, P)
     G = jnp.einsum("noi,moi->nm", R, R)
     alpha = solve_qp(G, 1.0, iters=100)
-    W_kernel = ops.maecho_update(W, V, P, alpha, eta=0.5)
+    W_kernel = mu.maecho_update_stacked(W[None], V[:, None], P[:, None],
+                                        alpha[None], eta=0.5)[0]
     np.testing.assert_allclose(np.asarray(W1), np.asarray(W_kernel),
                                atol=1e-3)
 

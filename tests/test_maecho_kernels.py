@@ -39,13 +39,33 @@ CFG = MAEchoConfig(tau=2, eta=0.5, qp_iters=60)
 
 
 # --------------------------------------------------------------------------
-# kernel-level property parity: the three auto wrappers
+# kernel-level property parity: a 2-D leaf through the streaming halves
+# as the stack L = 1 (kind normalisation, padding, sub-tile fallback)
 # --------------------------------------------------------------------------
+def _one_layer(V, P):
+    """V and the stacked projector with a layer axis of 1 behind the
+    clients."""
+    return V[:, None], jax.tree_util.tree_map(lambda x: x[:, None], P)
+
+
+def _gram1(W, V, P):
+    """The (N, N) Gram of one 2-D leaf, and the apply context."""
+    G, ctx = ops.maecho_streaming_gram_stacked(W[None], *_one_layer(V, P))
+    return G[0], ctx
+
+
+def _apply1(W, V, P, alpha, **kw):
+    """Eq. 7 then Eq. 11 on one 2-D leaf: ``(W', V')``."""
+    _, ctx = _gram1(W, V, P)
+    Wn, Vn = ops.maecho_streaming_apply_stacked(alpha[None], ctx, **kw)
+    return Wn[0], Vn[:, 0]
+
+
 @given(strat.seeds(), strat.n_clients(), strat.kinds(), strat.shapes())
 @settings(max_examples=8, deadline=None)
 def test_gram_parity(seed, n, kind, shape):
     W, V, P = strat.build_layer(seed, n, kind, shape)
-    got = ops.maecho_gram_auto(W, V, P)
+    got, _ = _gram1(W, V, P)
     want = ref.maecho_gram_ref(W, V, P)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-2, rtol=1e-4)
@@ -56,7 +76,9 @@ def test_gram_parity(seed, n, kind, shape):
 @settings(max_examples=8, deadline=None)
 def test_v_update_parity(seed, n, kind, shape, norm):
     W, V, P = strat.build_layer(seed, n, kind, shape)
-    got = ops.maecho_v_update_auto(W, V, P, frac=0.5, norm=norm)
+    # α = 0 and η = 0 leave W' = W exactly: Eq. 11 alone, from W
+    _, got = _apply1(W, V, P, jnp.zeros((n,)), eta=0.0, frac=0.5,
+                     norm=norm)
     want = ref.maecho_v_update_ref(W, V, P, 0.5, norm)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
@@ -68,7 +90,7 @@ def test_update_parity(seed, n, kind, shape):
     W, V, P = strat.build_layer(seed, n, kind, shape)
     alpha = jax.nn.softmax(jax.random.normal(
         jax.random.PRNGKey(seed + 1), (n,)))
-    got = ops.maecho_update_auto(W, V, P, alpha, eta=0.7)
+    got, _ = _apply1(W, V, P, alpha, eta=0.7)
     want = ref.maecho_update_ref_any(W, V, P, alpha, 0.7)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
@@ -188,17 +210,18 @@ def test_factored_rank_above_one_tile():
     Ps = [strat.make_projector(jax.random.PRNGKey(50 + i), "factored",
                                (), 256, rank=150) for i in range(2)]
     P = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs, 0), *Ps)
-    got = ops.maecho_gram_auto(W, V, P)
+    got, _ = _gram1(W, V, P)
     want = ref.maecho_gram_ref(W, V, P)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-2, rtol=1e-4)
 
 
 def test_small_shapes_fall_back_to_oracle():
-    """Below one tile the autos must return the oracle result exactly."""
+    """Below one tile the streaming gram must return the oracle result
+    exactly."""
     W, V, P = strat.build_layer(37, 2, "full", (6, 4))
     np.testing.assert_allclose(
-        np.asarray(ops.maecho_gram_auto(W, V, P)),
+        np.asarray(_gram1(W, V, P)[0]),
         np.asarray(ref.maecho_gram_ref(W, V, P)), rtol=1e-6)
 
 

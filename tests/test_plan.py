@@ -181,10 +181,7 @@ class _GramTap:
     @given re-runs the test body per example, and real-hypothesis
     forbids function-scoped fixtures inside property tests."""
 
-    _NAMES = {"_leaf_gram_oracle": "oracle",
-              "_leaf_gram_kernel": "kernel",
-              "_leaf_gram_sharded": "sharded",
-              "_leaf_gram_sharded2d": "sharded2d"}
+    _NAMES = {"_leaf_gram_oracle": "oracle"}
 
     def __init__(self):
         import repro.core.maecho as M
@@ -429,13 +426,15 @@ def test_sharded2d_gram_apply_parity_one_device(kind):
                                              (N,)))
 
     def step(W, V, P):
-        G, ctx = ops.maecho_sharded2d_gram(W, V, P, mesh=mesh,
-                                           axis_out="data",
-                                           axis_in="model")
-        Wn, Vn = ops.maecho_sharded2d_apply(
-            alpha, ctx, mesh=mesh, axis_out="data", axis_in="model",
-            eta=0.7, frac=0.5, norm=True)
-        return G, Wn, Vn
+        # the 2-D leaf as the stack L = 1
+        G, ctx = ops.maecho_sharded2d_gram_stacked(
+            W[None], V[:, None],
+            jax.tree_util.tree_map(lambda x: x[:, None], P), mesh=mesh,
+            axis_out="data", axis_in="model")
+        Wn, Vn = ops.maecho_sharded2d_apply_stacked(
+            alpha[None], ctx, mesh=mesh, axis_out="data",
+            axis_in="model", eta=0.7, frac=0.5, norm=True)
+        return G[0], Wn[0], Vn[:, 0]
 
     G, Wn, Vn = jax.jit(step)(W, V, P)
     Gr = ref.maecho_gram_ref(W, V, P)

@@ -140,12 +140,15 @@ def test_sharded_gram_apply_parity_one_device(kind):
                                              (N,)))
 
     def step(W, V, P):
-        G, ctx = ops.maecho_sharded_gram(W, V, P, mesh=mesh,
-                                         axis="data")
-        Wn, Vn = ops.maecho_sharded_apply(alpha, ctx, mesh=mesh,
-                                          axis="data", eta=0.7,
-                                          frac=0.5, norm=True)
-        return G, Wn, Vn
+        # the 2-D leaf as the stack L = 1
+        G, ctx = ops.maecho_sharded_gram_stacked(
+            W[None], V[:, None],
+            jax.tree_util.tree_map(lambda x: x[:, None], P), mesh=mesh,
+            axis="data")
+        Wn, Vn = ops.maecho_sharded_apply_stacked(
+            alpha[None], ctx, mesh=mesh, axis="data", eta=0.7, frac=0.5,
+            norm=True)
+        return G[0], Wn[0], Vn[:, 0]
 
     G, Wn, Vn = jax.jit(step)(W, V, P)
     Gr = ref.maecho_gram_ref(W, V, P)
@@ -464,7 +467,7 @@ def test_one_chip_placement_moves_each_client_once():
         (jax.devices()[0].id, host)]
     routes = {r.attrs["route"]: r.attrs["n"] for r in spans.records(t0)
               if r.name == "maecho.route"}
-    assert routes == {"stacked": 1, "oracle": 1}
+    assert routes == {"kernel": 1, "oracle": 1}
 
 
 _MESH4 = textwrap.dedent("""

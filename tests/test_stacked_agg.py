@@ -64,7 +64,7 @@ def test_stacked_sharded_eligibility():
                       "sharded", FakeMesh()) == "sharded"
     # non-divisible out-dim tiles fall back, stacked or not
     assert leaf_route(jnp.zeros((4, 300, 256)), P, 1, cfg, "oi",
-                      "sharded", FakeMesh()) == "stacked"
+                      "sharded", FakeMesh()) == "kernel"
 
 
 # --------------------------------------------------------------------------
@@ -171,10 +171,10 @@ def test_dispatch_summary_routes():
     # "small" is requested onto the kernel route by backend="kernel"
     # but is below one tile — the plan routes (and the summary
     # reports) the jnp oracle that actually executes; the eligible
-    # stacked leaf takes the "stacked" kernel-grid route
-    assert routes == {"stack": "stacked", "small": "oracle",
+    # stacked leaf takes the kernel route, its layer axis on the grid
+    assert routes == {"stack": "kernel", "small": "oracle",
                       "b": "oracle"}
-    assert counts == {"stacked": 1, "oracle": 2}
+    assert counts == {"kernel": 1, "oracle": 2}
     # sharded promotes the eligible stacked leaf
 
     class FakeMesh:

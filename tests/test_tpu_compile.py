@@ -76,11 +76,12 @@ def test_gram_stacked_compiles(one_chip):
 
 
 def test_gram_diag_compiles(one_chip):
-    # the embedding in "oi" layout: out = d_model, in = vocab
-    _compile(lambda W, V, p: mg.maecho_gram_diag(W, V, p,
-                                                 interpret=False),
-             one_chip, ((D, VOCAB), F32), ((N, D, VOCAB), F32),
-             ((N, VOCAB), F32))
+    # the embedding in "oi" layout: out = d_model, in = vocab, as the
+    # stack L = 1 the executor hands the kernel
+    _compile(lambda W, V, p: mg.maecho_gram_diag_stacked(
+        W, V, p, interpret=False),
+        one_chip, ((1, D, VOCAB), F32), ((N, 1, D, VOCAB), F32),
+        ((N, 1, VOCAB), F32))
 
 
 def test_update_stacked_compiles(one_chip):
@@ -304,8 +305,9 @@ def test_sharded2d_step_compiles_for_the_2x2_mesh(topo, one_chip,
                       r"collective-permute|reduce-scatter)(?:-start)?\(",
                       text)
     assert ops_ and {op for _, op in ops_} == {"all-reduce"}, ops_
-    # each reduces Grams: (L, N, N) or (N, N), alone or in a tuple
-    grams = {f"f32[{cfg.n_layers},{n},{n}]", f"f32[{n},{n}]"}
+    # each reduces Grams, alone or in a tuple: (L, N, N), or (1, N, N)
+    # for a 2-D leaf, which the kernels take as the stack L = 1
+    grams = {f"f32[{cfg.n_layers},{n},{n}]", f"f32[1,{n},{n}]"}
     for result, _ in ops_:
         shapes_ = re.findall(r"f32\[[\d,]*\]", result)
         assert shapes_ and set(shapes_) <= grams, result

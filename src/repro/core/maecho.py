@@ -152,12 +152,13 @@ class MAEchoConfig:
     qp_batched: bool = True       # one stacked PGD solve per outer iter
     mesh_axis: str = "data"       # out-row shard axis ("sharded"/"2d")
     mesh_in_axis: str = "model"   # in-column shard axis ("sharded2d")
-    # kernel tile edge for the (non-sharded) streaming pipeline;
-    # 0 = ops.DEFAULT_BLOCK (128, the TPU-safe MXU tile).  Bigger
-    # blocks shrink the grid — the interpret-mode benches use 512 to
-    # amortize per-step interpreter overhead; on TPU stay within VMEM
-    # (the gram rstore is N·bo·bi fp32).  The sharded pipeline keeps
-    # DEFAULT_BLOCK (its out-padding granularity is block × axis_size).
+    # kernel blocks of the (non-sharded) streaming pipeline: 0 = derived
+    # per leaf by the plan from its extents, N and the VMEM budget
+    # (core.plan.kernel_blocks); an edge > 0 overrides them with that
+    # cube and pads to it — the interpret-mode benches use 512 to
+    # amortize per-step interpreter overhead.  The sharded pipelines
+    # always derive theirs (their out-padding granularity is
+    # DEFAULT_BLOCK × axis_size).
     kernel_block: int = 0
     # client-axis chunk for the Gram/apply sweeps; 0 = unchunked.  When
     # set, eligible leaves accumulate their (N, N) Gram over blocks of
@@ -248,16 +249,16 @@ def _to_kernel_layout(W, V, P, convention: str, levels: int = 0):
 
 def _leaf_gram_stacked(W, V, P, cfg: MAEchoConfig, convention: str,
                        route: str, mesh, levels: int,
-                       block: int = 0):
+                       block: int = 0, blocks=None):
     """Gram half of the kernel pipelines: the ``levels`` leading layer
     axes are flattened into the kernel grid's outer dimension — ONE
     launch (and, sharded, ONE psum carrying the (L, N, N) stack)
     covers every scanned layer; a 2-D leaf (``levels`` 0) is the stack
     L = 1.  ``route`` is the leaf plan's: "kernel" | "sharded" |
-    "sharded2d"; ``block`` its effective tile edge on "kernel" (the
-    plan is the one source of the tiling).  Returns ``(G, ctx)`` with
-    G carrying the original leading axes before its trailing (N, N),
-    matching the oracle-vmap layout."""
+    "sharded2d"; ``block`` its padding edge on "kernel" and ``blocks``
+    the kernels' blocks (the plan is the one source of the tiling).
+    Returns ``(G, ctx)`` with G carrying the original leading axes
+    before its trailing (N, N), matching the oracle-vmap layout."""
     from repro.kernels import ops
 
     Wk, Vk, Pk = _to_kernel_layout(W, V, P, convention, levels)
@@ -265,22 +266,23 @@ def _leaf_gram_stacked(W, V, P, cfg: MAEchoConfig, convention: str,
     if route == "sharded2d":
         G, ctx = ops.maecho_sharded2d_gram_stacked(
             Wk, Vk, Pk, mesh=mesh, axis_out=cfg.mesh_axis,
-            axis_in=cfg.mesh_in_axis)
+            axis_in=cfg.mesh_in_axis, blocks=blocks)
     elif route == "sharded":
         G, ctx = ops.maecho_sharded_gram_stacked(Wk, Vk, Pk, mesh=mesh,
-                                                 axis=cfg.mesh_axis)
+                                                 axis=cfg.mesh_axis,
+                                                 blocks=blocks)
     else:
         G, ctx = ops.maecho_streaming_gram_stacked(
-            Wk, Vk, Pk, block=block or ops.DEFAULT_BLOCK)
+            Wk, Vk, Pk, block=block or ops.DEFAULT_BLOCK, blocks=blocks)
     return G.reshape(lead + G.shape[-2:]), ("stk", route, lead, ctx)
 
 
 def _leaf_apply_stacked(alpha, ctx, cfg: MAEchoConfig,
-                        convention: str, mesh, block: int = 0):
+                        convention: str, mesh):
     """Update half of the kernel pipelines: per-layer Eq. 7 + Eq. 11
-    from the flattened-grid context of :func:`_leaf_gram_stacked`.
-    ``alpha`` carries the leaf's leading stack axes before its
-    trailing N (the QP batch layout)."""
+    from the flattened-grid context of :func:`_leaf_gram_stacked`
+    (which carries the gram's blocks).  ``alpha`` carries the leaf's
+    leading stack axes before its trailing N (the QP batch layout)."""
     from repro.kernels import ops
 
     _, route, lead, inner = ctx
@@ -295,8 +297,7 @@ def _leaf_apply_stacked(alpha, ctx, cfg: MAEchoConfig,
         Wn, Vn = ops.maecho_sharded_apply_stacked(
             af, inner, mesh=mesh, axis=cfg.mesh_axis, **kw)
     else:
-        Wn, Vn = ops.maecho_streaming_apply_stacked(
-            af, inner, block=block or ops.DEFAULT_BLOCK, **kw)
+        Wn, Vn = ops.maecho_streaming_apply_stacked(af, inner, **kw)
     Wn = Wn.reshape(lead + Wn.shape[-2:])
     Vn = Vn.reshape(Vn.shape[:1] + lead + Vn.shape[-2:])
     if convention == "io":
@@ -439,8 +440,10 @@ def _leaf_gram(W, V, P, lp: LeafPlan, cfg: MAEchoConfig,
                 in_axes=(0, 1, 1), out_axes=0)(Wf, Vf, Pf)
             return G.reshape(lead + G.shape[1:]), R
         return _leaf_gram_oracle(W, V, P, convention)
+    spans.count("maecho.kernel_blocks", route=route, kind=lp.kind,
+                **lp.blocks._asdict())
     return _leaf_gram_stacked(W, V, P, cfg, convention, route, mesh,
-                              lp.levels, lp.block)
+                              lp.levels, lp.block, lp.blocks)
 
 
 def _leaf_apply(W, V, P, ctx, alpha, lp: LeafPlan, cfg: MAEchoConfig,
@@ -466,8 +469,7 @@ def _leaf_apply(W, V, P, ctx, alpha, lp: LeafPlan, cfg: MAEchoConfig,
             return (Wn.reshape(lead + Wn.shape[1:]),
                     Vn.reshape(Vn.shape[:1] + lead + Vn.shape[2:]))
         return _leaf_apply_oracle(W, V, P, ctx, alpha, cfg, convention)
-    return _leaf_apply_stacked(alpha, ctx, cfg, convention, mesh,
-                               lp.block)
+    return _leaf_apply_stacked(alpha, ctx, cfg, convention, mesh)
 
 
 def _scope(phase: str, lp: Optional[LeafPlan] = None):
@@ -673,7 +675,7 @@ def coverage_report(W0, Pp, levels_tree, macfg, backend: str,
 
     Beyond route counts, every leaf gets a detail line with the
     ``LeafPlan`` knobs that decide its memory/collective shape: the
-    mesh axes its Gram psums over, the effective sharding tile edge,
+    mesh axes its Gram psums over, the kernels' (bo, bi, bk) blocks,
     and the client-chunk size (``-`` where the knob is off), visible
     before a long production compile (``launch.dryrun_agg``,
     ``chip_smoke.py``)."""
@@ -687,8 +689,10 @@ def coverage_report(W0, Pp, levels_tree, macfg, backend: str,
     print(f"[coverage] backend={backend}: {total} leaves ({summary})")
     for lp in plan.leaves:
         axes = ",".join(lp.psum_axes) if lp.psum_axes else "-"
+        tile = ("x".join(map(str, lp.blocks[:3])) if lp.blocks
+                else "-")
         print(f"[coverage]   {lp.path}: route={lp.route} "
-              f"psum_axes={axes} tile={lp.block or '-'} "
+              f"psum_axes={axes} tile={tile} "
               f"chunk={lp.client_chunk or '-'}")
     if backend != "oracle":
         for path, lv, route in per_leaf:

@@ -13,7 +13,8 @@ plan-then-execute split:
     is memoized, so repeated aggregations over the same model reuse
     the identical :class:`AggPlan` object — and produces one frozen
     :class:`LeafPlan` per leaf: the route, the kernel-layout dims, the
-    effective tile edge, and the mesh axes that shard (and psum) it.
+    padding edge, the kernels' blocks (:func:`kernel_blocks`), and the
+    mesh axes that shard (and psum) it.
   - ``core.maecho``'s outer loop is a pure executor over those plans:
     it looks up ``leaf.route`` and calls the matching gram/apply pair.
     ``dispatch_summary`` is a *view* of the same compiled plan, so the
@@ -46,8 +47,9 @@ silent degradation is the failure mode the plan layer exists to kill.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import lru_cache
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 
@@ -72,15 +74,31 @@ def validate_backend(backend: str) -> None:
         raise ValueError(_backend_error(backend))
 
 
+class Blocks(NamedTuple):
+    """The kernels' blocks for one leaf: out-rows ``bo``, columns
+    ``bi``, contraction ``bk`` (0 for the elementwise diagonal kinds);
+    ``steps``, the grid steps of the leaf's largest launch; and
+    ``fallback``, set where nothing larger than the 128 cube fit
+    ``env.VMEM_BUDGET`` and the rule fell back to it."""
+    bo: int
+    bi: int
+    bk: int
+    steps: int
+    fallback: bool = False
+
+
 @dataclasses.dataclass(frozen=True)
 class LeafPlan:
     """Frozen per-leaf routing decision.
 
     ``out_d`` / ``in_d`` are the kernel-layout ("oi"-native) trailing
-    dims — already convention-swapped; ``block`` is the effective
-    streaming tile edge (``_eff_block``-clamped ``cfg.kernel_block``)
-    on the kernel route and the sharded pipelines' fixed
-    ``DEFAULT_BLOCK`` otherwise; ``out_axes`` / ``in_axes`` are the
+    dims — already convention-swapped; ``block`` is the padding edge:
+    the ``_eff_block``-clamped ``cfg.kernel_block`` on the kernel
+    route (``DEFAULT_BLOCK`` when it is 0), and ``DEFAULT_BLOCK`` on
+    the sharded pipelines, which pad to it times the axis size;
+    ``blocks`` are the kernels' :class:`Blocks`, which divide the
+    padded extents (:func:`kernel_blocks`, or ``cfg.kernel_block``'s
+    cube where it is set); ``out_axes`` / ``in_axes`` are the
     mesh axis names sharding the leaf (empty on unsharded routes), and
     their concatenation is exactly the psum axis set of the leaf's one
     Gram collective.  ``client_chunk`` is the effective client-axis
@@ -97,6 +115,7 @@ class LeafPlan:
     out_axes: tuple = ()
     in_axes: tuple = ()
     client_chunk: int = 0
+    blocks: Blocks | None = None
 
     @property
     def psum_axes(self) -> tuple:
@@ -204,8 +223,8 @@ def leaf_route(W, P, levels: int, cfg, convention: str, backend: str,
     """Route of a single leaf under the given dispatch inputs — the one
     copy of the routing rules (:func:`compile_plan` maps it over the
     tree).  Static shapes only."""
-    return _plan_leaf(path, W, P, levels, cfg, convention, backend,
-                      mesh).route
+    return _route_leaf(path, W, P, levels, cfg, convention, backend,
+                       mesh).route
 
 
 def _eff_chunk(cfg, P, eligible: bool) -> int:
@@ -222,6 +241,15 @@ def _eff_chunk(cfg, P, eligible: bool) -> int:
 
 def _plan_leaf(path: str, W, P, levels: int, cfg, convention: str,
                backend: str, mesh) -> LeafPlan:
+    lp = _route_leaf(path, W, P, levels, cfg, convention, backend, mesh)
+    if lp.route == "oracle":
+        return lp
+    return dataclasses.replace(lp, blocks=_leaf_blocks(lp, W, P, cfg,
+                                                       mesh))
+
+
+def _route_leaf(path: str, W, P, levels: int, cfg, convention: str,
+                backend: str, mesh) -> LeafPlan:
     from repro.kernels import ops
 
     eligible = kernel_eligible(W, P, levels)
@@ -306,6 +334,105 @@ def _eff_tile(cfg, out_d: int, in_d: int) -> int:
     from repro.kernels.ops import DEFAULT_BLOCK, _eff_block
 
     return _eff_block(cfg.kernel_block or DEFAULT_BLOCK, out_d, in_d)
+
+
+# --------------------------------------------------------------------------
+# the kernels' blocks
+# --------------------------------------------------------------------------
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _divisors(ext: int, unit: int) -> list:
+    """The multiples of ``unit`` that divide ``ext``, largest first;
+    ``[ext]`` where there are none (a rank below one lane tile)."""
+    return [d for d in range(ext - ext % unit, 0, -unit)
+            if ext % d == 0] or [ext]
+
+
+def kernel_blocks(kind: str, n: int, layers: int, out_ext: int,
+                  in_ext: int, in_gram: int, kd: int, norm: bool,
+                  cube: int = 0) -> Blocks:
+    """The blocks a leaf's three MA-Echo kernels take.
+
+    The extents are the local ones each kernel sees, padded: ``out_ext``
+    rows and ``in_ext`` columns in the Eq. 7/11 applies, ``in_gram``
+    columns in the Gram (smaller on the 2-D shard), ``kd`` the
+    contraction (``in_ext`` for a dense projector, the padded rank for a
+    factored one, 0 for the elementwise diagonal kinds), ``n`` clients
+    (or the client chunk) and ``layers`` the stack.  ``bi`` and ``bk``
+    span whole rows where they fit, so each operand block is one
+    contiguous run of rows and W and V cross HBM once; ``bo``, a
+    multiple of 8 that divides ``out_ext``, is then as large as fits,
+    so that the projectors are read ``out_ext / bo`` times.  A block
+    fits when each launch's VMEM (the kernel modules' ``vmem``, with
+    whole rows in Eq. 11 under ``norm``) is within ``env.VMEM_BUDGET``
+    and one client's Gram tile within ``maecho_gram.PAIR_TILE``.
+    ``bk``, then ``bi``, shrink where whole rows fit at no ``bo`` of at
+    least 128 (or all the rows), so that no operand is read more often
+    than the 128 cube reads it; ``bo`` goes below that floor only where
+    nothing fits above it (a large ``n``); where nothing fits at all,
+    the rule falls back to the 128 cube.  ``cube``, where set
+    (``MAEchoConfig.kernel_block``), is taken as every edge."""
+    from repro.kernels import env
+    from repro.kernels import maecho_gram as mg
+    from repro.kernels import maecho_update as mu
+    from repro.kernels import maecho_v_update as mv
+
+    op = {"full": "full", "factored": "left"}.get(kind, "diag")
+
+    def steps(bo, bi, bk):
+        rows = layers * out_ext // bo
+        ks = kd // bk if kd else 1
+        # the diagonal kinds' Gram and Eq. 7 take every client a step
+        cl = n if kd else 1
+        gram = rows * (in_gram // min(bi, in_gram)) * cl * ks
+        update = rows * (in_ext // bi) * cl * ks
+        v_update = rows * n * (1 if norm else in_ext // bi) * ks
+        return max(gram, update, v_update)
+
+    def fits(bo, bi, bk):
+        gi = min(bi, in_gram)
+        need = max(mg.vmem(op, n, bo, gi, bk), mu.vmem(op, n, bo, bi, bk),
+                   mv.vmem(op, n, bo, in_ext if norm else bi, bk))
+        return bo * gi <= mg.PAIR_TILE and need <= env.VMEM_BUDGET
+
+    if cube:
+        edges = (min(cube, out_ext), min(cube, in_ext), min(cube, kd))
+        return Blocks(*edges, steps(*edges))
+    for floor in (min(128, out_ext), 8):
+        for bi in [in_ext] + [d for d in _divisors(in_gram, 128)
+                              if d < in_ext]:
+            for bk in _divisors(kd, 128) if kd else [0]:
+                for bo in _divisors(out_ext, 8):
+                    if bo >= floor and fits(bo, bi, bk):
+                        return Blocks(bo, bi, bk, steps(bo, bi, bk))
+    edges = (min(128, out_ext), min(128, in_ext), min(128, kd))
+    return Blocks(*edges, steps(*edges), fallback=True)
+
+
+def _leaf_blocks(lp: LeafPlan, W, P, cfg, mesh) -> Blocks:
+    """:func:`kernel_blocks` at the extents the leaf's kernels see on
+    its route: padded to ``lp.block`` (times the axis size on a mesh
+    route, as ``ops`` pads), out-rows split over ``out_axes``, the
+    Gram's columns over ``in_axes``."""
+    from repro.kernels.ops import axis_size_of
+
+    osz = axis_size_of(mesh, lp.out_axes) if lp.out_axes else 1
+    isz = axis_size_of(mesh, lp.in_axes) if lp.in_axes else 1
+    pad = lp.block
+    in_ext = _round_up(lp.in_d, pad * isz)
+    if lp.kind == "factored":
+        rank = P["U"].shape[-1]
+        kd = _round_up(rank, pad) if rank > pad else rank
+    else:
+        kd = in_ext if lp.kind == "full" else 0
+    n = lp.client_chunk or (P["U"] if lp.kind == "factored"
+                            else P).shape[0]
+    cube = lp.block if lp.route == "kernel" and cfg.kernel_block else 0
+    return kernel_blocks(lp.kind, int(n), math.prod(W.shape[:lp.levels]),
+                         _round_up(lp.out_d, pad * osz) // osz, in_ext,
+                         in_ext // isz, kd, cfg.norm, cube)
 
 
 # --------------------------------------------------------------------------

@@ -31,9 +31,12 @@ Variants (all share the accumulate/finalize tail):
     (embedding token support / broadcast scalar rule) — elementwise
     residuals, single fused pass, no reduction axis.
 
-VMEM budget: rstore is N·bo·bi fp32 — with the default 128×128 blocks
-that caps N around 40 per core (the paper runs N ≤ 50; shrink ``bo``
-for larger cohorts).
+Blocks are per axis: ``core.plan`` derives (bo, bi, bk) per leaf so
+that, within ``env.VMEM_BUDGET``, the contraction and the columns span
+whole padded rows and ``bo`` is as large as fits (:func:`vmem` counts
+what a launch holds).  The rstore is N·bo·bi fp32, so a larger cohort
+takes a smaller ``bo``; one client's tile holds at most
+:data:`PAIR_TILE` elements.
 """
 from __future__ import annotations
 
@@ -45,7 +48,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.env import resolve
+from repro.kernels.env import compiler_params, resolve, vmem_bytes
+
+# Elements of one client's (bo, bi) residual tile at most.  Mosaic lays
+# the pair contraction of the N tiles out in full, and its compile time
+# grows faster than the tile: compiling the Gram for a described v5e
+# took 0.5 s at 128 × 128, 4.3 s at 608 × 896, 7.2 s at 896 × 896,
+# 13.4 s at 1216 × 896 and 32 s at 16 × 151936.
+PAIR_TILE = 2 ** 20
+
+
+def vmem(kind: str, n: int, bo: int, bi: int, bk: int) -> int:
+    """VMEM bytes of one Gram launch of ``kind`` ("full", "left" or
+    "diag") over ``n`` clients at blocks (bo, bi, bk)."""
+    if kind == "diag":
+        return vmem_bytes([(bo, bi), (n, bo, bi), (n, 1, bi), (n, n)],
+                          [(n, n)], [(n, bo, bi)] * 2)
+    operands = [(bo, bk), (bk, bi)] + ([(bo, bk)] if kind == "full" else [])
+    return vmem_bytes(operands + [(n, n)], [(bo, bi), (n, bo, bi), (n, n)],
+                      [(bo, bk), (bo, bi)])
 
 
 def _pair_gram(r):
@@ -243,6 +264,7 @@ def maecho_gram_stacked(W, V, P, *, bo: int = 128, bi: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
+        compiler_params=compiler_params(vmem("full", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(W, V, P)
 
@@ -279,6 +301,7 @@ def maecho_gram_left_stacked(A, UT, *, bo: int = 128, bi: int = 128,
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32),
                         pltpu.VMEM((N, bo, bi), jnp.float32),
                         pltpu.VMEM((N, N), jnp.float32)],
+        compiler_params=compiler_params(vmem("left", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(A, UT)
 
@@ -308,5 +331,6 @@ def maecho_gram_diag_stacked(W, V, p, *, bo: int = 128, bi: int = 128,
         out_specs=pl.BlockSpec((None, N, N), lambda l, o, j: (l, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((L, N, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
+        compiler_params=compiler_params(vmem("diag", N, bo, bi, 0)),
         interpret=resolve(interpret),
     )(W, V, p4)

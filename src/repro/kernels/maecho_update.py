@@ -13,7 +13,8 @@ in-register.
 Every kernel takes a stack of L layers (a 2-D leaf is L = 1) and α as
 the per-layer (L, N) stack.  Grid: (L, n_out, n_in, N, n_k), the layer
 axis outermost; scratch persists across the two inner axes.  Block
-shapes (bo, bk) / (bk, bi) / (bo, bi), 128-aligned.
+shapes (bo, bk) / (bk, bi) / (bo, bi), which ``core.plan`` derives per
+leaf (``maecho_gram``'s module docstring).
 
 Fast paths matching ``maecho_gram`` / ``maecho_v_update``:
   - ``maecho_update_left_stacked``: factored Pᵢ = Uᵢ·diag(sᵢ)·Uᵢᵀ —
@@ -32,7 +33,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.env import resolve
+from repro.kernels.env import compiler_params, resolve, vmem_bytes
+
+
+def vmem(kind: str, n: int, bo: int, bi: int, bk: int) -> int:
+    """VMEM bytes of one Eq. 7 launch of ``kind`` ("full", "left" or
+    "diag") over ``n`` clients at blocks (bo, bi, bk)."""
+    if kind == "diag":
+        return vmem_bytes([(bo, bi), (n, bo, bi), (n, 1, bi), (n, 1, 1),
+                           (bo, bi)], values=[(n, bo, bi)] * 2)
+    operands = [(bo, bk), (bk, bi)] + ([(bo, bk)] if kind == "full" else [])
+    return vmem_bytes(operands + [(bo, bi)] * 2, [(bo, bi)],
+                      [(bo, bk), (bo, bi)])
 
 
 def _kernel(alpha_ref, w_ref, v_ref, p_ref, wout_ref, out_ref, acc_ref,
@@ -111,6 +123,7 @@ def maecho_update_stacked(W, V, P, alpha, *, eta: float = 1.0,
                                lambda l, o, j, i, k: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
+        compiler_params=compiler_params(vmem("full", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(alpha, W, V, P, W)
 
@@ -150,6 +163,7 @@ def maecho_update_left_stacked(W, A, UT, alpha, *, eta: float = 1.0,
                                lambda l, o, j, i, k: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
+        compiler_params=compiler_params(vmem("left", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(alpha, A, UT, W)
 
@@ -184,6 +198,7 @@ def maecho_update_diag_stacked(W, V, p, alpha, *, eta: float = 1.0,
         ],
         out_specs=pl.BlockSpec((None, bo, bi), lambda l, o, j: (l, o, j)),
         out_shape=jax.ShapeDtypeStruct((L, out_d, in_d), W.dtype),
+        compiler_params=compiler_params(vmem("diag", N, bo, bi, 0)),
         interpret=resolve(interpret),
     )(W, V, p4, a4)
 

@@ -16,8 +16,8 @@ Every kernel takes a stack of L layers (a 2-D leaf is L = 1).  Grid:
 (L, N, n_out, n_in, n_k), the layer axis outermost; scratch persists
 across the innermost axis only (one tile's reduction).  With
 ``norm=True`` the row norm needs the full row resident, so callers must
-set bi = in_d (``ops.maecho_streaming_apply_stacked`` does; rows up to
-~16k fp32 fit VMEM comfortably).
+set bi = in_d (the ``ops`` apply halves do, and ``core.plan`` counts it
+in the leaf's VMEM).
 
 Fast paths mirror ``maecho_gram``:
   - ``maecho_v_update_factored_stacked``: Δᵢ Pᵢ = Bᵢ @ Uᵢᵀ with the
@@ -35,7 +35,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.env import resolve
+from repro.kernels.env import compiler_params, resolve, vmem_bytes
+
+
+def vmem(kind: str, n: int, bo: int, bi: int, bk: int) -> int:
+    """VMEM bytes of one Eq. 11 launch of ``kind`` ("full", "left" or
+    "diag") at blocks (bo, bi, bk); the grid walks the ``n`` clients,
+    so no buffer holds more than one."""
+    del n
+    if kind == "diag":
+        return vmem_bytes([(bo, bi), (bo, bi), (1, bi), (bo, bi)],
+                          values=[(bo, bi)] * 2)
+    operands = [(bo, bk), (bk, bi)] + ([(bo, bk)] if kind == "full" else [])
+    return vmem_bytes(operands + [(bo, bi)] * 3, [(bo, bi)],
+                      [(bo, bk), (bo, bi)])
 
 
 def _apply_norm(u, eps: float):
@@ -124,6 +137,7 @@ def maecho_v_update_stacked(W, V, P, *, frac: float, norm: bool = False,
                                lambda l, i, o, j, k: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
+        compiler_params=compiler_params(vmem("full", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(W, V, P, W, V)
 
@@ -170,6 +184,7 @@ def maecho_v_update_factored_stacked(W, V, U, s, *, frac: float,
                                lambda l, i, o, j, k: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
         scratch_shapes=[pltpu.VMEM((bo, bi), jnp.float32)],
+        compiler_params=compiler_params(vmem("left", N, bo, bi, bk)),
         interpret=resolve(interpret),
     )(B, UT, W, V)
 
@@ -206,6 +221,7 @@ def maecho_v_update_diag_stacked(W, V, p, *, frac: float,
         out_specs=pl.BlockSpec((None, None, bo, bi),
                                lambda l, i, o, j: (i, l, o, j)),
         out_shape=jax.ShapeDtypeStruct(V.shape, V.dtype),
+        compiler_params=compiler_params(vmem("diag", N, bo, bi, 0)),
         interpret=resolve(interpret),
     )(W, V, p4)
 

@@ -16,8 +16,11 @@ kind (scalar / diagonal / dense / factored ``{"U", "s"}``), zero-pad
 non-block-multiple shapes via ``_pad_to`` (zero padding is exact:
 padded residual tiles are identically zero), and the streaming halves
 fall back to the jnp oracles in ``ref.py`` for shapes too small to
-tile.  All of them assume the "oi" layout — ``core.maecho`` transposes
-"io" leaves before dispatch.
+tile.  The gram halves take the kernels' per-axis ``blocks`` (bo, bi,
+bk), which ``core.plan.kernel_blocks`` derives per leaf (the square
+``block`` edge where none are given), and hand them to the apply
+halves in their context.  All of them assume the "oi" layout —
+``core.maecho`` transposes "io" leaves before dispatch.
 """
 from __future__ import annotations
 
@@ -52,8 +55,9 @@ __all__ = [
     "live_window", "cache_write", "DEFAULT_BLOCK",
 ]
 
-# one tile edge: the streaming halves fall back to the jnp oracles
-# below this, and core.plan's routing keys off the same constant
+# one tile edge: the padding granularity, and the streaming halves fall
+# back to the jnp oracles below it; core.plan's routing keys off the
+# same constant
 DEFAULT_BLOCK = 128
 
 # re-exported from env.py (the raw kernel modules resolve their
@@ -125,6 +129,12 @@ rank_downdate = _ru.rank_downdate
 block_rls_update = _ru.block_rls_update
 
 
+def _tiles(blocks, block: int) -> tuple:
+    """(bo, bi, bk): the first three of the plan's ``blocks``, or the
+    square ``block`` edge where there are none."""
+    return tuple(blocks[:3]) if blocks else (block,) * 3
+
+
 def _eff_block(block: int, out_d: int, in_d: int,
                base: int = DEFAULT_BLOCK) -> int:
     """Clamp a requested streaming-pipeline tile edge to the leaf.
@@ -182,7 +192,7 @@ def _normalize_padded_stacked(W, V, P, block: int):
 
 
 def maecho_streaming_gram_stacked(W, V, P, *, block: int = DEFAULT_BLOCK,
-                                  interpret=None):
+                                  blocks=None, interpret=None):
     """Gram half of the fused leaf iteration: ``(G, ctx)``.
 
     W: (L, out, in); V: (N, L, out, in); P stacked per kind.  G is the
@@ -194,8 +204,10 @@ def maecho_streaming_gram_stacked(W, V, P, *, block: int = DEFAULT_BLOCK,
     compressed residual A shared with the Eq. 7 kernel (the dominant
     O(N·out·in·k) einsum is not recomputed).  Splitting gram from apply
     is what lets ``core.maecho`` stack every leaf's Gram into one batch
-    and run a single vmapped QP solve per outer iteration.  Shapes
-    below one tile fall back to the vmapped jnp oracle."""
+    and run a single vmapped QP solve per outer iteration.  Operands
+    pad to ``block`` multiples and the kernels take ``blocks``, which
+    divide them.  Shapes below one tile fall back to the vmapped jnp
+    oracle."""
     L, out_d, in_d = W.shape
     if out_d < DEFAULT_BLOCK or in_d < DEFAULT_BLOCK:
         fallback_warn(
@@ -203,41 +215,37 @@ def maecho_streaming_gram_stacked(W, V, P, *, block: int = DEFAULT_BLOCK,
             f"{DEFAULT_BLOCK}-tile: running the vmapped jnp oracle "
             f"instead of the stacked kernel grid")
         G = jax.vmap(ref.maecho_gram_ref, in_axes=(0, 1, 1))(W, V, P)
-        return G, ("ref", W, V, P, out_d, in_d)
+        return G, ("ref", W, V, P, out_d, in_d, None)
     block = _eff_block(block, out_d, in_d)
+    bo, bi, bk = tiles = _tiles(blocks, block)
     kind, Wp, Vp, Pk = _normalize_padded_stacked(W, V, P, block)
     if kind == "factored":
         Up, sp = Pk
         A = _mg.compressed_residual(Wp, Vp, Up, sp)     # (N, L, out, k)
         UT = jnp.swapaxes(Up, 2, 3).astype(jnp.float32)
-        G = _mg.maecho_gram_left_stacked(A, UT, bo=block, bi=block,
-                                         bk=block,
+        G = _mg.maecho_gram_left_stacked(A, UT, bo=bo, bi=bi, bk=bk,
                                          interpret=interpret)
-        return G, (kind, Wp, Vp, (Up, sp, A, UT), out_d, in_d)
+        return G, (kind, Wp, Vp, (Up, sp, A, UT), out_d, in_d, tiles)
     if kind == "full":
-        G = _mg.maecho_gram_stacked(Wp, Vp, Pk, bo=block, bi=block,
-                                    bk=block,
+        G = _mg.maecho_gram_stacked(Wp, Vp, Pk, bo=bo, bi=bi, bk=bk,
                                     interpret=interpret)
     else:
-        G = _mg.maecho_gram_diag_stacked(Wp, Vp, Pk, bo=block,
-                                         bi=block,
+        G = _mg.maecho_gram_diag_stacked(Wp, Vp, Pk, bo=bo, bi=bi,
                                          interpret=interpret)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
+    return G, (kind, Wp, Vp, Pk, out_d, in_d, tiles)
 
 
 def maecho_streaming_apply_stacked(alpha, ctx, *, eta: float = 1.0,
                                    frac: float = 0.5, norm: bool = False,
-                                   eps: float = 1e-12,
-                                   block: int = DEFAULT_BLOCK,
-                                   interpret=None):
+                                   eps: float = 1e-12, interpret=None):
     """Update half: per-layer Eq. 7 then Eq. 11 from one launch each.
     ``alpha`` is the (L, N) per-layer solve stack; ``ctx`` comes from
     :func:`maecho_streaming_gram_stacked` for the same leaf (same
-    padded operands — the pipeline stays in padded space; zero padding
-    is invariant under all three passes).  With ``norm=True`` the
-    Eq. 11 kernel takes whole rows (bi = padded in_d).  Returns
-    ``(W', V')`` cropped to the original shape."""
-    kind, Wp, Vp, Pk, out_d, in_d = ctx
+    padded operands and blocks — the pipeline stays in padded space;
+    zero padding is invariant under all three passes).  With
+    ``norm=True`` the Eq. 11 kernel takes whole rows (bi = padded
+    in_d).  Returns ``(W', V')`` cropped to the original shape."""
+    kind, Wp, Vp, Pk, out_d, in_d, tiles = ctx
     if kind == "ref":
         W_new = jax.vmap(
             lambda w, v, p, a: ref.maecho_update_ref_any(w, v, p, a,
@@ -248,31 +256,31 @@ def maecho_streaming_apply_stacked(alpha, ctx, *, eta: float = 1.0,
                                                     norm, eps),
             in_axes=(0, 1, 1), out_axes=1)(W_new, Vp, Pk)
         return W_new, V_new
-    block = _eff_block(block, out_d, in_d)   # same clamp as the gram
-    bi = Wp.shape[2] if norm else block
+    bo, bi, bk = tiles
+    bv = Wp.shape[2] if norm else bi       # Eq. 11's row norm: whole rows
     if kind == "factored":
         Up, sp, A, UT = Pk
         Wn = _mu.maecho_update_left_stacked(Wp, A, UT, alpha, eta=eta,
-                                            bo=block, bi=block,
-                                            bk=block, interpret=interpret)
+                                            bo=bo, bi=bi, bk=bk,
+                                            interpret=interpret)
         Vn = _mv.maecho_v_update_factored_stacked(
-            Wn, Vp, Up, sp, frac=frac, norm=norm, eps=eps, bo=block,
-            bi=bi, bk=block, interpret=interpret)
+            Wn, Vp, Up, sp, frac=frac, norm=norm, eps=eps, bo=bo, bi=bv,
+            bk=bk, interpret=interpret)
     elif kind == "full":
         Wn = _mu.maecho_update_stacked(Wp, Vp, Pk, alpha, eta=eta,
-                                       bo=block, bi=block, bk=block,
+                                       bo=bo, bi=bi, bk=bk,
                                        interpret=interpret)
         Vn = _mv.maecho_v_update_stacked(Wn, Vp, Pk, frac=frac,
-                                         norm=norm, eps=eps, bo=block,
-                                         bi=bi, bk=block,
+                                         norm=norm, eps=eps, bo=bo,
+                                         bi=bv, bk=bk,
                                          interpret=interpret)
     else:
         Wn = _mu.maecho_update_diag_stacked(Wp, Vp, Pk, alpha, eta=eta,
-                                            bo=block, bi=block,
+                                            bo=bo, bi=bi,
                                             interpret=interpret)
         Vn = _mv.maecho_v_update_diag_stacked(Wn, Vp, Pk, frac=frac,
                                               norm=norm, eps=eps,
-                                              bo=block, bi=bi,
+                                              bo=bo, bi=bv,
                                               interpret=interpret)
     return Wn[:, :out_d, :in_d], Vn[:, :, :out_d, :in_d]
 
@@ -321,7 +329,7 @@ def sharded_ok(out_d: int, in_d: int, axis_size: int,
 
 
 def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
-                                block: int = DEFAULT_BLOCK,
+                                block: int = DEFAULT_BLOCK, blocks=None,
                                 interpret=None):
     """Out-dim-sharded gram half of the streaming pipeline.
 
@@ -339,10 +347,12 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
     ``block × axis_size`` (even, block-tileable shards; zero padding is
     exact for all three passes) and the in-dim to ``block``.  On the
     factored path the (N, L, out, k) compressed residual is computed
-    *sharded* and carried in ``ctx`` for the Eq. 7 kernel.  Callers
+    *sharded* and carried in ``ctx`` for the Eq. 7 kernel.  The kernels
+    take ``blocks``, which divide each device's padded slab.  Callers
     gate eligibility with :func:`sharded_ok`; "oi" layout, like the
     rest of the kernel pipeline.
     """
+    bo, bi, bk = tiles = _tiles(blocks, block)
     names = _axis_names(axis)
     asz = axis_size_of(mesh, axis)
     L, out_d, in_d = W.shape
@@ -359,20 +369,22 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
         def body_f(Wl, Vl, U, s):
             A = _mg.compressed_residual(Wl, Vl, U, s)
             UT = jnp.swapaxes(U, 2, 3).astype(jnp.float32)
-            Gl = _mg.maecho_gram_left_stacked(A, UT, interpret=interpret)
+            Gl = _mg.maecho_gram_left_stacked(A, UT, bo=bo, bi=bi, bk=bk,
+                                              interpret=interpret)
             return jax.lax.psum(Gl, names), A
 
         G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep4, rep3),
                          out_specs=(rep3, crow),
                          check_vma=False)(Wp, Vp, Up, sp)
-        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
+        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d, tiles)
     if kind == "full":
         Pk = _pad_to(_pad_to(P, block, 2)[0], block, 3)[0]
 
         def body_d(Wl, Vl, Pl):
             return jax.lax.psum(
-                _mg.maecho_gram_stacked(Wl, Vl, Pl, interpret=interpret),
+                _mg.maecho_gram_stacked(Wl, Vl, Pl, bo=bo, bi=bi, bk=bk,
+                                        interpret=interpret),
                 names)
 
         G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, rep4),
@@ -384,18 +396,17 @@ def maecho_sharded_gram_stacked(W, V, P, *, mesh, axis="data",
 
         def body_g(Wl, Vl, pl_):
             return jax.lax.psum(
-                _mg.maecho_gram_diag_stacked(Wl, Vl, pl_,
+                _mg.maecho_gram_diag_stacked(Wl, Vl, pl_, bo=bo, bi=bi,
                                              interpret=interpret), names)
 
         G = jax.shard_map(body_g, mesh=mesh, in_specs=(row, crow, rep3),
                       out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
+    return G, (kind, Wp, Vp, Pk, out_d, in_d, tiles)
 
 
 def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
                                  eta: float = 1.0, frac: float = 0.5,
                                  norm: bool = False, eps: float = 1e-12,
-                                 block: int = DEFAULT_BLOCK,
                                  interpret=None):
     """Update half of the sharded pipeline: per-layer Eq. 7 then
     Eq. 11, row-local on each device's owned out-rows under the same
@@ -403,11 +414,12 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
     owned rows' residuals by the replicated α and Eq. 11's row
     normalisation runs along the unsharded in-axis — zero collectives
     (the gram psum is the iteration's only one).  ``alpha`` is the
-    replicated (L, N) per-layer solve stack.  Returns ``(W', V')``
-    cropped to the original shape."""
-    kind, Wp, Vp, Pk, out_d, in_d = ctx
+    replicated (L, N) per-layer solve stack; the kernels take the
+    gram's blocks from ``ctx``.  Returns ``(W', V')`` cropped to the
+    original shape."""
+    kind, Wp, Vp, Pk, out_d, in_d, (bo, bi, bk) = ctx
     names = _axis_names(axis)
-    bi = Wp.shape[2] if norm else block
+    bv = Wp.shape[2] if norm else bi       # Eq. 11's row norm: whole rows
     row = PartitionSpec(None, names, None)
     crow = PartitionSpec(None, None, names, None)
     rep2 = PartitionSpec(None, None)
@@ -419,10 +431,11 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
         def body_f(a, Wl, Vl, U, s, Al):
             UT = jnp.swapaxes(U, 2, 3).astype(jnp.float32)
             Wn = _mu.maecho_update_left_stacked(Wl, Al, UT, a, eta=eta,
+                                                bo=bo, bi=bi, bk=bk,
                                                 interpret=interpret)
             Vn = _mv.maecho_v_update_factored_stacked(
-                Wn, Vl, U, s, frac=frac, norm=norm, eps=eps, bi=bi,
-                interpret=interpret)
+                Wn, Vl, U, s, frac=frac, norm=norm, eps=eps, bo=bo, bi=bv,
+                bk=bk, interpret=interpret)
             return Wn, Vn
 
         Wn, Vn = jax.shard_map(
@@ -432,10 +445,12 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
             alpha, Wp, Vp, Up, sp, A)
     elif kind == "full":
         def body_d(a, Wl, Vl, Pl):
-            Wn = _mu.maecho_update_stacked(Wl, Vl, Pl, a, eta=eta,
+            Wn = _mu.maecho_update_stacked(Wl, Vl, Pl, a, eta=eta, bo=bo,
+                                           bi=bi, bk=bk,
                                            interpret=interpret)
             Vn = _mv.maecho_v_update_stacked(Wn, Vl, Pl, frac=frac,
-                                             norm=norm, eps=eps, bi=bi,
+                                             norm=norm, eps=eps, bo=bo,
+                                             bi=bv, bk=bk,
                                              interpret=interpret)
             return Wn, Vn
 
@@ -445,9 +460,10 @@ def maecho_sharded_apply_stacked(alpha, ctx, *, mesh, axis="data",
     else:                                   # scalar / diag
         def body_g(a, Wl, Vl, pl_):
             Wn = _mu.maecho_update_diag_stacked(Wl, Vl, pl_, a, eta=eta,
+                                                bo=bo, bi=bi,
                                                 interpret=interpret)
             Vn = _mv.maecho_v_update_diag_stacked(
-                Wn, Vl, pl_, frac=frac, norm=norm, eps=eps, bi=bi,
+                Wn, Vl, pl_, frac=frac, norm=norm, eps=eps, bo=bo, bi=bv,
                 interpret=interpret)
             return Wn, Vn
 
@@ -473,7 +489,7 @@ def _pin(mesh, *pairs):
 
 def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
                                   axis_in="model",
-                                  block: int = DEFAULT_BLOCK,
+                                  block: int = DEFAULT_BLOCK, blocks=None,
                                   interpret=None):
     """2-D-sharded gram half: out-rows over ``axis_out`` AND
     in-columns over ``axis_in``.
@@ -495,12 +511,14 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
     on pre-sliced operands.  Operands are zero-padded to
     ``block × axis_size`` multiples on each sharded dim (zero padding
     is exact for all three passes) and pinned to the apply's layout
-    (:func:`_pin`).
+    (:func:`_pin`).  The kernels take ``blocks``, whose ``bi`` the Gram
+    clips to its in-column shard.
 
     Returns ``(G, ctx)`` with ``ctx`` in the SAME format as
     :func:`maecho_sharded_gram_stacked` — the apply half reuses the
     1-D row-local kernels verbatim (:func:`maecho_sharded2d_apply_stacked`).
     """
+    bo, bi, bk = tiles = _tiles(blocks, block)
     no, ni = _axis_names(axis_out), _axis_names(axis_in)
     allnames = no + ni
     osz = axis_size_of(mesh, axis_out)
@@ -525,14 +543,15 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
 
         def body_f(Wl, Vl, U, s, UTl):
             A = _mg.compressed_residual(Wl, Vl, U, s)
-            Gl = _mg.maecho_gram_left_stacked(A, UTl, interpret=interpret)
+            Gl = _mg.maecho_gram_left_stacked(A, UTl, bo=bo, bi=bi, bk=bk,
+                                              interpret=interpret)
             return jax.lax.psum(Gl, allnames), A
 
         G, A = jax.shard_map(body_f, mesh=mesh,
                          in_specs=(row, crow, rep4, rep3, col4),
                          out_specs=(rep3, crow),
                          check_vma=False)(Wp, Vp, Up, sp, UTs)
-        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d)
+        return G, (kind, Wp, Vp, (Up, sp, A), out_d, in_d, tiles)
     if kind == "full":
         in_p = Wp.shape[2]
         Pk, = _pin(mesh, (_pad_to(_pad_to(P, in_p, 2)[0], in_p, 3)[0],
@@ -543,7 +562,8 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
             # Pl (N, L, in_p, in_sh) carries the owned output columns
             A = (Wl[None] - Vl).astype(jnp.float32)
             Gl = _mg.maecho_gram_left_stacked(
-                A, Pl.astype(jnp.float32), interpret=interpret)
+                A, Pl.astype(jnp.float32), bo=bo, bi=bi, bk=bk,
+                interpret=interpret)
             return jax.lax.psum(Gl, allnames)
 
         G = jax.shard_map(body_d, mesh=mesh, in_specs=(row, crow, col4),
@@ -555,7 +575,7 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
 
         def body_g(Wl, Vl, pl_):
             return jax.lax.psum(
-                _mg.maecho_gram_diag_stacked(Wl, Vl, pl_,
+                _mg.maecho_gram_diag_stacked(Wl, Vl, pl_, bo=bo, bi=bi,
                                              interpret=interpret), allnames)
 
         G = jax.shard_map(body_g, mesh=mesh,
@@ -563,7 +583,7 @@ def maecho_sharded2d_gram_stacked(W, V, P, *, mesh, axis_out="data",
                                 PartitionSpec(None, None, no, ni),
                                 PartitionSpec(None, None, ni)),
                       out_specs=rep3, check_vma=False)(Wp, Vp, Pk)
-    return G, (kind, Wp, Vp, Pk, out_d, in_d)
+    return G, (kind, Wp, Vp, Pk, out_d, in_d, tiles)
 
 
 def maecho_sharded2d_apply_stacked(alpha, ctx, *, mesh,
@@ -571,7 +591,6 @@ def maecho_sharded2d_apply_stacked(alpha, ctx, *, mesh,
                                    eta: float = 1.0, frac: float = 0.5,
                                    norm: bool = False,
                                    eps: float = 1e-12,
-                                   block: int = DEFAULT_BLOCK,
                                    interpret=None):
     """Update half of the 2-D pipeline: per-layer Eq. 7 then Eq. 11,
     row/col-local.
@@ -588,7 +607,7 @@ def maecho_sharded2d_apply_stacked(alpha, ctx, *, mesh,
     del axis_in  # rows-only: the in-group replicates the apply
     return maecho_sharded_apply_stacked(
         alpha, ctx, mesh=mesh, axis=axis_out, eta=eta, frac=frac,
-        norm=norm, eps=eps, block=block, interpret=interpret)
+        norm=norm, eps=eps, interpret=interpret)
 
 
 # --------------------------------------------------------------------------

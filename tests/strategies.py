@@ -23,6 +23,14 @@ CONVENTIONS = ("oi", "io")
 # (out_d, in_d) in "oi" terms: one direct-tiling shape, two padding
 # shapes, one below a 128-tile (the jnp-oracle ref fallback)
 SHAPES = ((128, 128), (256, 140), (200, 256), (48, 64))
+# (out_d, in_d) and the VMEM budget the block rule is held to (None: the
+# kernels' own) whose derived blocks are not powers of two: under 2 MiB
+# 4864 = 19 × 256 rows split into 152-row blocks and a 4480-row mesh
+# shard into 152- to 320-row ones; 600 × 300 pads to whole 640 × 384
+# blocks; under 4 MiB 640 × 1536 takes 384 to 768 columns, or 16 to 64
+# rows of whole ones where Eq. 11 normalises
+BLOCK_CASES = (((4864, 256), 2 ** 21), ((600, 300), None),
+               ((4480, 128), 2 ** 21), ((640, 1536), 2 ** 22))
 # leading stacked-layer axes: stack_levels 0 through 3
 LEADS = ((), (2,), (3,), (2, 2), (2, 1, 2))
 RANK = 16
@@ -50,6 +58,10 @@ def shapes():
 
 def leads():
     return st.sampled_from(LEADS)
+
+
+def block_cases():
+    return st.sampled_from(BLOCK_CASES)
 
 
 def masked():

@@ -98,14 +98,16 @@ def test_kernel_used_inside_algorithm_one():
     """One Algorithm-1 iteration stepped with the fused kernel matches
     the pure-jnp layer step (integration of kernel with core)."""
     from repro.core.maecho import MAEchoConfig, _leaf_sequential
-    from repro.core.plan import LeafPlan
+    from repro.core.plan import LeafPlan, kernel_blocks
     k = jax.random.PRNGKey(3)
     N, out_d, in_d = 2, 128, 128
     W = jax.random.normal(k, (out_d, in_d))
     V = jax.random.normal(jax.random.fold_in(k, 1), (N, out_d, in_d))
     P = jax.random.normal(jax.random.fold_in(k, 2), (N, in_d, in_d)) * 0.1
     cfg = MAEchoConfig(tau=1, eta=0.5, qp_iters=100)
-    lp = LeafPlan("W", 0, "kernel", "full", out_d, in_d, 128)
+    lp = LeafPlan("W", 0, "kernel", "full", out_d, in_d, 128,
+                  blocks=kernel_blocks("full", N, 1, out_d, in_d, in_d,
+                                       in_d, False))
     W1, _ = _leaf_sequential(W, V, P, lp, cfg, "oi")
     # recover alpha by construction: uniform when G symmetric-ish is
     # fine for this check — instead compare against ref with the same

@@ -130,6 +130,69 @@ def test_streaming_stacked_parity(seed, n, kind, shape, norm):
                                atol=1e-4)
 
 
+@given(strat.seeds(), strat.n_clients(), strat.kinds(),
+       strat.block_cases(), strat.bools())
+@settings(max_examples=8, deadline=None)
+def test_derived_blocks_parity(seed, n, kind, case, norm):
+    """The kernels at the blocks the plan derives — whole padded rows,
+    out-row blocks that are not powers of two, budgets small enough to
+    split rows and columns — match the jnp oracle, on the kernel route
+    and, on a one-device (1, 1) mesh, on the 2-D route."""
+    from jax.sharding import Mesh
+
+    from repro.core import plan as plan_mod
+    from repro.kernels import env
+
+    shape, budget = case
+    W, V, P = strat.build_layer(seed, n, kind, shape, lead=(2,))
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    route = ("kernel", "sharded2d")[seed % 2]
+    cfg = MAEchoConfig(norm=norm)
+    saved = env.VMEM_BUDGET
+    env.VMEM_BUDGET = budget or saved
+    plan_mod.plan_cache_clear()
+    try:
+        lp = plan_mod.compile_plan(W, P, 1, cfg, "oi", route,
+                                   mesh).leaves[0]
+    finally:
+        env.VMEM_BUDGET = saved
+        plan_mod.plan_cache_clear()
+    assert lp.route == route and not lp.blocks.fallback
+    alpha = jax.nn.softmax(jax.random.normal(
+        jax.random.PRNGKey(seed + 3), (2, n)), axis=-1)
+    kw = dict(eta=0.7, frac=0.5, norm=norm)
+
+    def step(W, V, P):
+        if route == "kernel":
+            G, ctx = ops.maecho_streaming_gram_stacked(W, V, P,
+                                                       blocks=lp.blocks)
+            return (G,) + ops.maecho_streaming_apply_stacked(alpha, ctx,
+                                                             **kw)
+        G, ctx = ops.maecho_sharded2d_gram_stacked(W, V, P, mesh=mesh,
+                                                   blocks=lp.blocks)
+        return (G,) + ops.maecho_sharded2d_apply_stacked(alpha, ctx,
+                                                         mesh=mesh, **kw)
+
+    G, Wn, Vn = jax.jit(step)(W, V, P)
+    # the oracle's residuals, contracted in float64: one f32 dot over a
+    # whole 4864 × 256 residual strays further from the exact Gram
+    # than the kernels' per-block sums do
+    R = np.asarray(jax.vmap(ref._residuals, in_axes=(0, 1, 1),
+                            out_axes=1)(W, V, P), np.float64)
+    Rf = R.reshape(R.shape[:2] + (-1,))
+    Gr = np.einsum("nlk,mlk->lnm", Rf, Rf)
+    Wr = jax.vmap(lambda w, v, p, a:
+                  ref.maecho_update_ref_any(w, v, p, a, 0.7),
+                  in_axes=(0, 1, 1, 0))(W, V, P, alpha)
+    Vr = jax.vmap(lambda w, v, p:
+                  ref.maecho_v_update_ref(w, v, p, 0.5, norm),
+                  in_axes=(0, 1, 1), out_axes=1)(Wr, V, P)
+    np.testing.assert_allclose(np.asarray(G), Gr, atol=1e-2, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(Wn), np.asarray(Wr), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(Vn), np.asarray(Vr), atol=1e-4)
+
+
 # --------------------------------------------------------------------------
 # full-aggregate property parity: oracle vs kernel/auto/sharded, with
 # stacked leaves, mixed trees, both conventions and ragged masks

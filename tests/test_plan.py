@@ -127,6 +127,151 @@ def test_plan_leaf_fields():
 
 
 # --------------------------------------------------------------------------
+# the kernels' blocks: derived per leaf from its extents, N and VMEM
+# --------------------------------------------------------------------------
+F32 = jnp.float32
+# kernel-layout ("oi") leaves at qwen2 widths, each a stack of 24 layers:
+# the 0.5b's w_gate and wq, a scalar-projected w_down, and odd shapes
+# that pad
+BLOCK_LEAVES = {"gate": ((4864, 896), "full"), "wq": ((896, 896), "full"),
+                "w_down": ((896, 4864), "scalar"),
+                "odd": ((600, 300), "full"), "odd_f": ((200, 300), "factored"),
+                "odd_d": ((1000, 136), "diag")}
+
+
+def _leaf(shape, kind, n, lead=(24,), rank=16):
+    sds = jax.ShapeDtypeStruct
+    out_d, in_d = shape
+    W = sds(lead + shape, F32)
+    if kind == "factored":
+        P = {"U": sds((n,) + lead + (in_d, rank), F32),
+             "s": sds((n,) + lead + (rank,), F32)}
+    else:
+        P = sds((n,) + lead + {"full": (in_d, in_d), "diag": (in_d,),
+                               "scalar": ()}[kind], F32)
+    return W, P
+
+
+def _round(x, mult):
+    return -(-x // mult) * mult
+
+
+@pytest.mark.parametrize("backend", ["kernel", "sharded", "sharded2d"])
+@pytest.mark.parametrize("leaf", sorted(BLOCK_LEAVES))
+def test_derived_blocks_divide_todays_padding(backend, leaf):
+    """The plan pads as before — 128 multiples on the kernel route,
+    128 × the axis size on the mesh routes — and the blocks it derives
+    divide the extents each kernel sees there; the Gram's columns are
+    the in-shard on the 2-D route."""
+    from repro.kernels import maecho_gram as mg
+
+    shape, kind = BLOCK_LEAVES[leaf]
+    W, P = _leaf(shape, kind, 2)
+    mesh = FakeMesh({"data": 2, "model": 2})
+    lp = compile_plan({"w": W}, {"w": P}, {"w": 1}, MAEchoConfig(), "oi",
+                      backend, mesh).leaves[0]
+    osz = 2 if lp.out_axes else 1
+    isz = 2 if lp.in_axes else 1
+    assert lp.block == 128 and not lp.blocks.fallback
+    out_ext = _round(shape[0], 128 * osz) // osz
+    in_ext = _round(shape[1], 128 * isz)
+    bo, bi, bk = lp.blocks[:3]
+    assert bo % 8 == 0 and out_ext % bo == 0
+    assert bi % 128 == 0 and in_ext % bi == 0
+    assert (in_ext // isz) % min(bi, in_ext // isz) == 0
+    kd = {"full": in_ext, "factored": 16}.get(kind, 0)
+    assert (bk == 0) if not kd else kd % bk == 0
+    assert bo * min(bi, in_ext // isz) <= mg.PAIR_TILE
+    if backend == "kernel":
+        # the streaming gram pads its operands exactly to 128 multiples
+        seen = {}
+
+        def gram(w, v, p):
+            G, ctx = ops.maecho_streaming_gram_stacked(w, v, p,
+                                                       blocks=lp.blocks)
+            seen["padded"], seen["tiles"] = ctx[1].shape, ctx[-1]
+            return G
+
+        jax.eval_shape(gram, W, jax.ShapeDtypeStruct((2,) + W.shape, F32),
+                       P)
+        assert seen == {"padded": (24, out_ext, in_ext),
+                        "tiles": (bo, bi, bk)}
+
+
+@pytest.mark.parametrize("norm", [False, True])
+@pytest.mark.parametrize("n,chunk", [(2, 0), (64, 0), (64, 16)])
+def test_block_rule_stays_within_vmem(n, chunk, norm):
+    """Every launch of a leaf fits ``env.VMEM_BUDGET`` at the blocks
+    the rule derives, for two silos, for 64 clients and for a client
+    chunk of 16 (the rule's n); more clients take fewer out-rows."""
+    from repro.kernels import env
+    from repro.kernels import maecho_gram as mg
+    from repro.kernels import maecho_update as mu
+    from repro.kernels import maecho_v_update as mv
+
+    cfg = MAEchoConfig(norm=norm, client_chunk=chunk)
+    for leaf, (shape, kind) in BLOCK_LEAVES.items():
+        W, P = _leaf(shape, kind, n)
+        lp = compile_plan({"w": W}, {"w": P}, {"w": 1}, cfg, "oi",
+                          "kernel").leaves[0]
+        bo, bi, bk = lp.blocks[:3]
+        op = {"full": "full", "factored": "left"}.get(kind, "diag")
+        need = max(mg.vmem(op, chunk or n, bo, bi, bk),
+                   mu.vmem(op, chunk or n, bo, bi, bk),
+                   mv.vmem(op, chunk or n, bo,
+                           _round(shape[1], 128) if norm else bi, bk))
+        assert need <= env.VMEM_BUDGET, (leaf, lp.blocks)
+        assert not lp.blocks.fallback, leaf
+    two = compile_plan({"w": _leaf((4864, 896), "full", 2)[0]},
+                       {"w": _leaf((4864, 896), "full", 2)[1]}, {"w": 1},
+                       cfg, "oi", "kernel").leaves[0].blocks
+    many = compile_plan({"w": _leaf((4864, 896), "full", 64)[0]},
+                        {"w": _leaf((4864, 896), "full", 64)[1]},
+                        {"w": 1}, MAEchoConfig(norm=norm), "oi",
+                        "kernel").leaves[0].blocks
+    assert many.bo < two.bo
+
+
+def test_block_rule_falls_back_to_the_cube_when_nothing_fits():
+    """A cohort too large for any block within the budget takes the 128
+    cube, and says so."""
+    got = plan_mod.kernel_blocks("full", 4096, 24, 4864, 896, 896, 896,
+                                 False)
+    assert got[:3] == (128, 128, 128) and got.fallback
+
+
+def test_explicit_kernel_block_is_honoured():
+    """``kernel_block`` > 0 overrides the derived blocks with its cube
+    on the kernel route, padding to it; the mesh routes derive theirs."""
+    W, P = _leaf((1000, 500), "full", 2)
+    cfg = MAEchoConfig(kernel_block=256)
+    lp = compile_plan({"w": W}, {"w": P}, {"w": 1}, cfg, "oi",
+                      "kernel").leaves[0]
+    assert lp.block == 256 and lp.blocks[:3] == (256, 256, 256)
+    assert not lp.blocks.fallback
+    mesh = FakeMesh({"data": 2, "model": 2})
+    lp2 = compile_plan({"w": W}, {"w": P}, {"w": 1}, cfg, "oi",
+                       "sharded2d", mesh).leaves[0]
+    assert lp2.route == "sharded2d" and lp2.block == 128
+    assert lp2.blocks[:3] == (512, 512, 512)
+
+
+def test_gate_leaf_takes_an_eighth_of_the_grid_steps():
+    """qwen2-0.5b's w_gate leaf (24 × 4864 × 896, two silos): the
+    derived blocks span whole 896-wide rows, and the leaf's largest
+    launch takes at most an eighth of the 128 cube's grid steps."""
+    W, P = _leaf((4864, 896), "full", 2)
+    derived = compile_plan({"w": W}, {"w": P}, {"w": 1}, MAEchoConfig(),
+                           "oi", "kernel").leaves[0].blocks
+    cube = compile_plan({"w": W}, {"w": P}, {"w": 1},
+                        MAEchoConfig(kernel_block=128), "oi",
+                        "kernel").leaves[0].blocks
+    assert derived.bi == derived.bk == 896
+    assert cube.steps == 24 * 38 * 7 * 2 * 7
+    assert derived.steps * 8 <= cube.steps
+
+
+# --------------------------------------------------------------------------
 # backend validation: unknown strings never fall through to a default
 # --------------------------------------------------------------------------
 def test_unknown_backend_rejected_with_choices():

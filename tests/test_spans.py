@@ -205,6 +205,34 @@ def test_weight_round_counter_names_the_rounding_path():
     assert paths(cfg) == ["astype"] * 7
 
 
+def test_kernel_blocks_counter_reports_the_plan():
+    """``maecho.kernel_blocks`` counts each trace of a leaf's kernels
+    with the blocks the plan chose for it: route, kind, (bo, bi, bk),
+    the grid steps of its largest launch and whether the rule fell
+    back to the 128 cube.  Oracle leaves run no kernel and count
+    nothing."""
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    n = 2
+    W0 = {"w": sds((3, 600, 300), f32), "e": sds((256, 1024), f32),
+          "b": sds((600,), f32)}
+    P = {"w": sds((n, 3, 300, 300), f32), "e": sds((n, 1024), f32),
+         "b": sds((n,), f32)}
+    V0 = jax.tree.map(lambda w: sds((n,) + w.shape, w.dtype), W0)
+    cfg = MAEchoConfig(tau=5, qp_iters=13)
+    plan = compile_plan(W0, P, {"w": 1, "e": 0, "b": 0}, cfg, "oi",
+                        "kernel")
+    t0 = time.perf_counter_ns()
+    jax.eval_shape(lambda W, V, P: maecho._maecho_jit(W, V, P, cfg, "oi",
+                                                      plan), W0, V0, P)
+    got = [r.attrs for r in _since(t0, "maecho.kernel_blocks")]
+    want = [dict(lp.blocks._asdict(), route=lp.route, kind=lp.kind, n=1)
+            for lp in plan.leaves if lp.route != "oracle"]
+    assert got == want
+    assert [(g["kind"], g["bo"], g["bi"], g["bk"]) for g in got] == [
+        ("diag", 256, 1024, 0), ("full", 640, 384, 384)]
+
+
 def test_spans_are_host_events_of_a_profile():
     from jax._src.lib import _profiler
     from jax.profiler import ProfileData

@@ -63,37 +63,107 @@ def _compile(fn, one_chip, *shapes):
 
 
 F32 = jnp.float32
-# the w_gate leaf in the kernels' "oi" layout: (L, out=d_ff, in=d_model)
-W_GATE = ((L, F, D), F32)
-V_GATE = ((N, L, F, D), F32)
-P_GATE = ((N, L, D, D), F32)
+# the dense leaves the kernels take, in their "oi" layout (layers, out,
+# in, Gram columns):
+# qwen2-0.5b's w_gate, wq and wk on one chip, and one chip's shard of
+# qwen2-1.5b's w_gate on the (2, 2) mesh — 4480 of its 8960 rows, whole
+# 1536-wide rows, the Gram's in-columns split to 768.  "gate-128" runs
+# the kernels' own 128 cube; the others the blocks the plan derives.
+DENSE = {"gate-128": (L, F, D, D), "gate": (L, F, D, D),
+         "wq": (L, D, D, D), "wk": (L, HKV * HD, D, D),
+         "mesh-gate": (12, 4480, 1536, 768)}
+# the diagonal leaves: the 0.5b's embedding as the stack L = 1, and one
+# chip's shard of the 1.5b's scalar-projected w_down (768 of 1536 rows,
+# 8960 columns, the Gram's split to 4480)
+DIAG = {"embed-128": (1, D, VOCAB, VOCAB), "embed": (1, D, VOCAB, VOCAB),
+        "mesh-w_down": (12, 768, 8960, 4480)}
 
 
-def test_gram_stacked_compiles(one_chip):
-    _compile(lambda W, V, P: mg.maecho_gram_stacked(W, V, P,
+def _blocks(name, leaves, kind):
+    """The kernels' block arguments for a case: none for the 128 cube,
+    else the plan's blocks, checked against the VMEM budget."""
+    if name.endswith("-128"):
+        return {}
+    from repro.core.plan import kernel_blocks
+    from repro.kernels import env
+
+    layers, out_d, in_d, in_gram = leaves[name]
+    kd = in_d if kind == "full" else 0
+    got = kernel_blocks(kind, N, layers, out_d, in_d, in_gram, kd, False)
+    assert not got.fallback
+    op = "diag" if kind == "diag" else "full"
+    need = max(mod.vmem(op, N, got.bo, b, got.bk)
+               for mod, b in ((mg, min(got.bi, in_gram)), (mu, got.bi),
+                              (mv, got.bi)))
+    assert need <= env.VMEM_BUDGET
+    return dict(bo=got.bo, bi=got.bi, **({"bk": got.bk} if kd else {}))
+
+
+def _dense(name):
+    layers, out_d, in_d, _ = DENSE[name]
+    return (((layers, out_d, in_d), F32), ((N, layers, out_d, in_d), F32),
+            ((N, layers, in_d, in_d), F32))
+
+
+@pytest.mark.parametrize("name", ["gate-128", "gate", "wq", "wk"])
+def test_gram_stacked_compiles(one_chip, name):
+    kw = _blocks(name, DENSE, "full")
+    _compile(lambda W, V, P: mg.maecho_gram_stacked(W, V, P, **kw,
                                                     interpret=False),
-             one_chip, W_GATE, V_GATE, P_GATE)
+             one_chip, *_dense(name))
 
 
-def test_gram_diag_compiles(one_chip):
+@pytest.mark.parametrize("name", ["mesh-gate"])
+def test_gram_left_stacked_compiles(one_chip, name):
+    """The 2-D route's Gram on one chip's shard: Δ's whole rows against
+    the projector's owned in-columns."""
+    layers, out_d, in_d, in_gram = DENSE[name]
+    kw = _blocks(name, DENSE, "full")
+    _compile(lambda A, UT: mg.maecho_gram_left_stacked(A, UT, **kw,
+                                                       interpret=False),
+             one_chip, ((N, layers, out_d, in_d), F32),
+             ((N, layers, in_d, in_gram), F32))
+
+
+@pytest.mark.parametrize("name", ["embed-128", "embed", "mesh-w_down"])
+def test_gram_diag_compiles(one_chip, name):
     # the embedding in "oi" layout: out = d_model, in = vocab, as the
     # stack L = 1 the executor hands the kernel
+    layers, out_d, _, in_gram = DIAG[name]
+    kw = _blocks(name, DIAG, "diag")
     _compile(lambda W, V, p: mg.maecho_gram_diag_stacked(
-        W, V, p, interpret=False),
-        one_chip, ((1, D, VOCAB), F32), ((N, 1, D, VOCAB), F32),
-        ((N, 1, VOCAB), F32))
+        W, V, p, **kw, interpret=False),
+        one_chip, ((layers, out_d, in_gram), F32),
+        ((N, layers, out_d, in_gram), F32), ((N, layers, in_gram), F32))
 
 
-def test_update_stacked_compiles(one_chip):
+@pytest.mark.parametrize("name", ["embed", "mesh-w_down"])
+def test_diag_apply_compiles(one_chip, name):
+    layers, out_d, in_d, _ = DIAG[name]
+    kw = _blocks(name, DIAG, "diag")
+    shapes = (((layers, out_d, in_d), F32),
+              ((N, layers, out_d, in_d), F32), ((N, layers, in_d), F32))
+    _compile(lambda W, V, p, a: mu.maecho_update_diag_stacked(
+        W, V, p, a, eta=0.5, **kw, interpret=False),
+        one_chip, *shapes, ((layers, N), F32))
+    _compile(lambda W, V, p: mv.maecho_v_update_diag_stacked(
+        W, V, p, frac=0.5, **kw, interpret=False), one_chip, *shapes)
+
+
+@pytest.mark.parametrize("name", sorted(DENSE))
+def test_update_stacked_compiles(one_chip, name):
+    kw = _blocks(name, DENSE, "full")
     _compile(lambda W, V, P, a: mu.maecho_update_stacked(
-        W, V, P, a, eta=0.5, interpret=False),
-        one_chip, W_GATE, V_GATE, P_GATE, ((L, N), F32))
+        W, V, P, a, eta=0.5, **kw, interpret=False),
+        one_chip, *_dense(name), ((DENSE[name][0], N), F32))
 
 
-def test_v_update_stacked_compiles(one_chip):
+@pytest.mark.parametrize("name", sorted(DENSE))
+def test_v_update_stacked_compiles(one_chip, name):
+    kw = _blocks(name, DENSE, "full")
     _compile(lambda W, V, P: mv.maecho_v_update_stacked(
-        W, V, P, frac=0.5, interpret=False),
-        one_chip, W_GATE, V_GATE, P_GATE)
+        W, V, P, frac=0.5, **kw, interpret=False),
+        one_chip, *_dense(name))
 
 
 def test_gram_cross_compiles(one_chip):
